@@ -13,9 +13,13 @@ and where the pre-vectorization per-observer Python loop was intractable.
 ``comm-sharded``): for N in {8, 16, 32, 64} simulated nodes it times the
 single-device dense matmul backend against the node-per-device shard_map
 backend and reports the HLO-measured collective bytes — the matmul-vs-
-ppermute crossover table. Each N runs in a CHILD process because
+ppermute crossover table. It is a CPU simulation: each N runs in a CHILD
+process with N forced host devices, because
 ``--xla_force_host_platform_device_count`` must be set before jax
-initializes (``--sharded-child`` below).
+initializes (``--sharded-child`` below). It refuses to start unless
+``JAX_PLATFORMS=cpu``: on a host with a chip, the parent would hold the
+chip and every child that reached for it would fail or hang. Its times are
+CPU times, never device times.
 """
 from __future__ import annotations
 
@@ -147,7 +151,18 @@ def _sharded_child(n: int, q=10, d=64, k=8, steps=60, seed=0) -> None:
 
 
 def sharded_scaling_sweep(sizes=(8, 16, 32, 64)) -> list[dict]:
-    """Spawn one forced-device child per N; return the measured records."""
+    """Spawn one forced-device child per N; return the measured records.
+
+    Decided from the environment alone, before any child starts: the sweep
+    simulates devices on the CPU and runs only with ``JAX_PLATFORMS=cpu``.
+    """
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "the sharded scaling sweep simulates N devices on the CPU in "
+            "child processes; run it with JAX_PLATFORMS=cpu (on a chip "
+            "host the parent holds the chip and the children cannot reach "
+            "it)"
+        )
     records = []
     for n in sizes:
         env = dict(os.environ)

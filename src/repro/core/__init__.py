@@ -10,8 +10,9 @@ from repro.launch.compile_cache import enable_persistent_cache
 
 # Persistent XLA compile cache: every entrypoint that imports repro.core
 # (tests, benchmarks, notebooks) shares on-disk compiled executables across
-# processes. Opt out with REPRO_NO_COMPILE_CACHE=1; relocate with
-# REPRO_COMPILE_CACHE_DIR. See launch/compile_cache.py for policy.
+# processes. Opt out with REPRO_NO_COMPILE_CACHE=1; JAX's own
+# JAX_COMPILATION_CACHE_DIR, where set, is where it goes. See
+# launch/compile_cache.py for policy.
 enable_persistent_cache()
 
 from repro.core.operators import OperatorSpec  # noqa: F401,E402

@@ -18,9 +18,6 @@ greedily edge-colored into matchings and each matching becomes ONE
 ``lax.ppermute`` carrying both directions, so a step moves O(deg) blocks
 per node — never O(N) — and the emitted ``collective-permute`` ops are
 measurable from HLO (``launch.hlo_analysis.collective_stats``).
-
-The ``shard_map`` import shim below is the compatibility machinery shared
-with ``core.gossip`` (jax >= 0.5 promotes it out of experimental).
 """
 from __future__ import annotations
 
@@ -32,11 +29,6 @@ import numpy as np
 from jax import lax
 
 from repro.core.mixing import Graph
-
-if hasattr(jax, "shard_map"):  # jax >= 0.5
-    shard_map = jax.shard_map
-else:  # jax 0.4.x keeps it under experimental
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 NODE_AXIS = "node"
 
@@ -91,9 +83,16 @@ class DenseComm:
         self.graph = graph
 
     def matvec(self, m: np.ndarray, dtype) -> Callable[[jax.Array], jax.Array]:
-        """``mix(X) = M @ X`` with ``M`` baked as a device constant."""
+        """``mix(X) = M @ X`` with ``M`` baked as a device constant.
+
+        The product runs at full precision: the TPU's default matmul
+        precision rounds f32 operands to bf16, which would make the dense
+        backend a lower-precision algorithm than the sparse relay and the
+        sharded backend (both mix with exact elementwise sums). The CPU
+        ignores the setting, so CPU results are unchanged.
+        """
         m_j = jnp.asarray(m, dtype)
-        return lambda x: m_j @ x
+        return lambda x: jnp.matmul(m_j, x, precision=lax.Precision.HIGHEST)
 
     def local(self, x: jax.Array) -> jax.Array:
         """Identity: the whole array is this (only) caller's block."""

@@ -49,8 +49,6 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.models.params import tree_pspecs, tree_sds
 
-# version-compat shard_map shim shared with the solver comm backends
-from repro.core.comm import shard_map as _shard_map
 from repro.optim.adam import adam_init, adam_update
 from repro.train.step import TrainConfig, local_grads
 
@@ -259,7 +257,7 @@ def make_dense_mix(mesh, gc: GossipConfig, leaf_specs):
     if mesh is None:
         return body
     full_specs = jax.tree_util.tree_map(lambda sp: P("pod", *sp), leaf_specs)
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(full_specs,), out_specs=full_specs
     )
 
@@ -328,7 +326,7 @@ def make_topk_exchange(mesh, gc: GossipConfig, leaf_specs):
         return body
     src_specs = jax.tree_util.tree_map(lambda sp: P("pod", *sp), leaf_specs)
     rec_specs = jax.tree_util.tree_map(lambda sp: P("pod", None, *sp), leaf_specs)
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(src_specs, rec_specs),
         out_specs=(src_specs, rec_specs),

@@ -49,7 +49,6 @@ from repro.core.comm import (
     FaultyDenseComm,
     FaultyShardedComm,
     ShardedComm,
-    shard_map as _shard_map,
 )
 from repro.core.dsba import (
     DSBAConfig,
@@ -828,21 +827,15 @@ def _get_sharded_runner(
             runner_cache.SHARDED.note_trace()
             return z_fn(state, hp_dyn)
 
-        # check_rep=False: the replication checker has no rule for `while`,
-        # and mudag's traced-trip-count fori_loop (the no-retrace K sweep)
-        # lowers to one. Nothing here relies on replication inference — all
-        # specs are explicit, and dense<->sharded parity is pinned at 1e-12
-        # by tests/multidevice/test_sharded_inner.py.
         chunk = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 run_chunk, mesh=mesh,
                 in_specs=(state_specs, P(None, "node"), hp_specs),
                 out_specs=state_specs,
-                check_rep=False,
             )
         )
         z_read = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 read, mesh=mesh,
                 in_specs=(state_specs, hp_specs),
                 out_specs=P("node", None),
@@ -1026,18 +1019,17 @@ def _get_sharded_fault_runner(
         # the mask is replicated: each device reads its own row inside
         # the matvec via comm.local (see FaultyShardedComm)
         chunk = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 run_chunk, mesh=mesh,
                 in_specs=(
                     state_specs, P(None, "node"), P(None, None, None),
                     hp_specs,
                 ),
                 out_specs=state_specs,
-                check_rep=False,
             )
         )
         z_read = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 read, mesh=mesh,
                 in_specs=(state_specs, hp_specs),
                 out_specs=P("node", None),

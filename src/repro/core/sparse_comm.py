@@ -48,8 +48,8 @@ pair, so the simulator batches it instead of looping in Python:
   ``doubles[t, u] = sum_l nnz[t - xi(u,l), l] + tail`` (+ the one-time dense
   z^1 flood of D doubles at ``t == xi``), instead of inside the hop loop.
 * **Pallas hot path.** Densifying the per-node sparse deltas is routed
-  through ``kernels.ops.saga_sparse_axpy`` (one-hot-matmul scatter on the
-  TPU MXU; ``interpret=True`` fallback off-TPU). The interpret-mode
+  through ``kernels.ops.saga_sparse_axpy`` (one-hot select scatter on the
+  TPU; ``interpret=True`` fallback off-TPU). The interpret-mode
   compute_dtype policy lives in kernels/ops.py — f64 runs stay bit-exact
   without this module re-deriving the dtype per call site.
 
@@ -203,9 +203,10 @@ def run_sparse(
     verify: vectorized engine only — check the availability invariant and
         compare every reconstruction against the truth (recon_max_err).
     use_pallas: "auto" routes delta densification through the Pallas kernel
-        (compiled on TPU, interpret=True fallback elsewhere); "on" forces the
-        compiled kernel, "interpret" forces interpret mode, and "off" uses a
-        plain jnp scatter (fastest to trace on CPU).
+        (compiled on TPU for f32/bf16 data, interpret=True fallback
+        elsewhere; f64 data on a TPU takes the jnp scatter, as Mosaic has no
+        f64); "on" forces the compiled kernel, "interpret" forces interpret
+        mode, and "off" uses a plain jnp scatter (fastest to trace on CPU).
     state0: carried DSBAState from a previous schedule segment. When given,
         the run is a RESTART on (possibly new) `graph`/`w`: the solver
         continues from state0 (its SAGA tables, deltas and step counter
@@ -346,8 +347,6 @@ def _build_sparse_scan(cfg, data, graph, w, *, verify, kernel_mode,
     else:
         wave_xs = None
 
-    interpret = kernel_mode == "interpret"
-
     def densify_delta(st) -> jax.Array:
         """(N, D) dense delta rows from the padded-CSR delta of this step."""
         base = jnp.zeros((n, D), dt)
@@ -359,7 +358,6 @@ def _build_sparse_scan(cfg, data, graph, w, *, verify, kernel_mode,
         return saga_sparse_axpy(
             base, st.didx_prev, st.dval_prev, st.dg_prev,
             jnp.ones((n,), dt), use_pallas=kernel_mode,
-            node_block=n if interpret else 1,
         )
 
     def neighborhood_sum(g_cur, g_prev, wts):
@@ -523,12 +521,20 @@ def _relay_carry0(cfg, data, z0, depth, verify, state0=None):
     )
 
 
-def _resolve_kernel_mode(use_pallas: str) -> str:
-    """Resolve the relay's ``use_pallas`` option to a concrete kernel mode."""
+def _resolve_kernel_mode(use_pallas: str, dtype) -> str:
+    """Resolve the relay's ``use_pallas`` option to a concrete kernel mode.
+
+    "auto" compiles the kernel on a TPU for the dtypes Mosaic has. It has
+    no float64, so f64 data on a TPU takes the jnp scatter ("off"), which
+    is bit-exact to the kernel's f64 interpret path (the relay's
+    delta-densification policy in kernels/ops.py).
+    """
     if use_pallas not in ("auto", "on", "interpret", "off"):
         raise ValueError(f"unknown use_pallas mode {use_pallas!r}")
     if use_pallas == "auto":
-        return "on" if jax.default_backend() == "tpu" else "interpret"
+        if jax.default_backend() != "tpu":
+            return "interpret"
+        return "off" if jnp.dtype(dtype) == jnp.float64 else "on"
     return use_pallas
 
 
@@ -579,7 +585,7 @@ def _run_vectorized(
     # (which falls back to the jnp oracle off-TPU): the relay's delta
     # densification stays on the Pallas kernel everywhere, interpret=True
     # being the CPU fallback. Resolve "auto" here, dispatch through ops.
-    kernel_mode = _resolve_kernel_mode(use_pallas)
+    kernel_mode = _resolve_kernel_mode(use_pallas, dt)
 
     key, guards = _sparse_scan_key(
         cfg, data, graph, w, verify, kernel_mode, faulty=faulty
@@ -720,7 +726,7 @@ def run_sparse_many(
             f"indices must be (B, >= steps, N) = ({B}, >={steps}, {n}), "
             f"got {indices.shape}"
         )
-    kernel_mode = _resolve_kernel_mode(use_pallas)
+    kernel_mode = _resolve_kernel_mode(use_pallas, dt)
 
     key, guards = _sparse_scan_key(cfg, data, graph, w, verify, kernel_mode)
     scan, tb = runner_cache.SPARSE.get_or_build(

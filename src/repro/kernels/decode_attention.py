@@ -12,19 +12,23 @@ The gather is expressed in the grid spec, not in kernel-body DMAs: the
 block table and per-sequence lengths ride in scalar-prefetch position
 (``pltpu.PrefetchScalarGridSpec``), so the K/V BlockSpec index maps can
 read ``table[b, i]`` and point page ``i`` of sequence ``b`` straight at its
-pool page. Pallas then pipelines one (block_size, D) tile per grid step —
-unreferenced pool pages are never touched.
+pool page. Pallas then pipelines one whole page per grid step — all its
+kv heads, viewed as one (block_size * Hkv, D) tile — and unreferenced pool
+pages are never touched.
 
-Grid: ``(B, Hkv, n_pages)`` with the page axis innermost and sequential;
-an online-softmax carry (m / l / acc) persists in VMEM scratch across the
+Grid: ``(B, n_pages)`` with the page axis innermost and sequential; an
+online-softmax carry (m / l / acc) persists in VMEM scratch across the
 page axis, exactly like the q-block carry in kernels/flash_attention.py.
 Pages past a sequence's length are skipped (``pl.when``); partial last
 pages are masked by position, never read out of bounds. Empty slots
 (length 0 — the scheduler's padding lanes) produce an all-zero output row
 via the ``max(l, eps)`` guard.
 
-GQA is free here: one program instance handles a kv head's whole query
-group, so the (group, block_size) score tile never replicates K/V.
+GQA is a mask: one program instance scores all Hq query heads against the
+page's block_size * Hkv rows in a single matmul and masks the pairs whose
+kv head is not the query's. Every block then spans whole array dimensions
+in its last two axes (the TPU's tiling rule), K/V are never replicated,
+and the extra score columns are free at decode's tiny query count.
 
 Validated against kernels/ref.py ``decode_attention_ref`` in interpret
 mode; dispatch and tolerance policy live in kernels/ops.py
@@ -40,25 +44,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_call
+
+
 NEG_INF = -1e30
 
 
 def _decode_kernel(
     table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     acc_ref, m_ref, l_ref, *,
-    block_size: int, n_pages: int, window: int | None,
+    block_size: int, n_kv: int, n_pages: int, window: int | None,
     softcap: float | None, scale: float,
 ):
-    """One (sequence, kv-head, page) program instance.
+    """One (sequence, page) program instance.
 
     table_ref/len_ref: scalar-prefetch refs (full (B, n_pages) / (B,));
-    q_ref: (1, group, D) — this kv head's query group;
-    k_ref/v_ref: (1, block_size, 1, D) — the pool page the index map
-    gathered through the block table; o_ref: (1, group, D);
+    q_ref: (Hq, D) — every query head of this sequence;
+    k_ref/v_ref: (block_size * Hkv, D) — the pool page the index map
+    gathered through the block table, row r holding token r // Hkv of kv
+    head r % Hkv; o_ref: (Hq, D);
     acc/m/l: VMEM online-softmax carry persisting across the page axis.
     """
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
     length = len_ref[b]
 
     @pl.when(i == 0)
@@ -71,28 +79,31 @@ def _decode_kernel(
     # matmul entirely (the index map already pointed them at page 0).
     @pl.when(i * block_size < length)
     def _update():
-        q = q_ref[0].astype(jnp.float32) * scale  # (group, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (block_size, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale  # (Hq, D)
+        k = k_ref[...].astype(jnp.float32)  # (block_size * Hkv, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (group, block_size)
+        )  # (Hq, block_size * Hkv)
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        pos = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1
-        )
-        mask = pos < length
+        n_q = q.shape[0]
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        q_head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        tok = row // n_kv
+        pos = i * block_size + tok
+        # GQA as a mask: query head h attends kv head h // group only
+        mask = (row - tok * n_kv == q_head // (n_q // n_kv)) & (pos < length)
         if window is not None:
             # the single query sits at position length - 1
             mask &= pos >= length - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]  # (group, 1)
+        m_prev = m_ref[...]  # (Hq, 1)
         m_cur = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
+        p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
         l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
@@ -103,7 +114,7 @@ def _decode_kernel(
     @pl.when(i == n_pages - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def _pad_last(x: jax.Array, to: int) -> jax.Array:
@@ -137,7 +148,6 @@ def decode_attention(
     B, Hq, D = q.shape
     n_blocks, block_size, Hkv, _ = k_pool.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
-    group = Hq // Hkv
     n_pages = table.shape[1]
     scale = 1.0 / math.sqrt(D)
 
@@ -145,35 +155,35 @@ def decode_attention(
     kp = _pad_last(k_pool, 128)
     vp = _pad_last(v_pool, 128)
     Dp = qp.shape[-1]
+    # a page's (block_size, Hkv) rows are contiguous: viewing them as one
+    # (block_size * Hkv, D) tile is free and makes each block's last two
+    # dimensions whole array dimensions, as the TPU's tiling requires
+    rows = block_size * Hkv
+    kp = kp.reshape(n_blocks, rows, Dp)
+    vp = vp.reshape(n_blocks, rows, Dp)
 
+    page_spec = pl.BlockSpec(
+        (pl.Squeezed(), rows, Dp), lambda b, i, t, le: (t[b, i], 0, 0)
+    )
+    seq_spec = pl.BlockSpec(
+        (pl.Squeezed(), Hq, Dp), lambda b, i, t, le: (b, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, group, Dp), lambda b, h, i, t, le: (b, h, 0)),
-            pl.BlockSpec(
-                (1, block_size, 1, Dp),
-                lambda b, h, i, t, le: (t[b, i], 0, h, 0),
-            ),
-            pl.BlockSpec(
-                (1, block_size, 1, Dp),
-                lambda b, h, i, t, le: (t[b, i], 0, h, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, group, Dp), lambda b, h, i, t, le: (b, h, 0)
-        ),
+        grid=(B, n_pages),
+        in_specs=[seq_spec, page_spec, page_spec],
+        out_specs=seq_spec,
         scratch_shapes=[
-            pltpu.VMEM((group, Dp), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((Hq, Dp), jnp.float32),
+            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hq, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, block_size=block_size, n_pages=n_pages,
+        _decode_kernel, block_size=block_size, n_kv=Hkv, n_pages=n_pages,
         window=window, softcap=softcap, scale=scale,
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dp), q.dtype),

@@ -44,6 +44,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_call
+
+
 NEG_INF = -1e30
 
 
@@ -55,12 +58,13 @@ def _attn_kernel(
     """One (batch, q-head, q-block) program instance.
 
     q_ref: (1, 1, block_q, D); k_ref/v_ref: (1, 1, seq_k_pad, D);
-    o_ref: (1, 1, block_q, D); lse_ref: (1, 1, block_q).
+    o_ref: (1, 1, block_q, D); lse_ref: (1, 1, block_q, 1).
+    Row statistics (m, l, lse) are (block_q, 1) columns throughout.
     """
     q_blk = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (block_q, D)
     D = q.shape[-1]
-    q_pos = q_blk * block_q + jax.lax.iota(jnp.int32, block_q)
+    q_pos = _positions(q_blk, block_q, 0)  # (block_q, 1)
 
     num_k_blocks = pl.cdiv(seq_k, block_k)
 
@@ -74,28 +78,24 @@ def _attn_kernel(
         v_tile = v_ref[0, 0, pl.ds(i * block_k, block_k), :].astype(
             jnp.float32
         )
-        k_pos = i * block_k + jax.lax.iota(jnp.int32, block_k)
-        s = q @ k_tile.T  # (block_q, block_k)
+        k_pos = _positions(i, block_k, 1)  # (1, block_k)
+        s = _dot_nt(q, k_tile)  # (block_q, block_k)
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        mask = jnp.ones((block_q, block_k), jnp.bool_)
-        if causal:
-            mask &= q_pos[:, None] >= k_pos[None, :]
-        if window is not None:
-            mask &= q_pos[:, None] - k_pos[None, :] < window
-        mask &= (k_pos < seq_k)[None, :]
+        mask = _mask(q_pos, k_pos, seq_q=None, seq_k=seq_k, causal=causal,
+                     window=window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_cur = jnp.maximum(m_prev, s.max(-1))
+        m_cur = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_cur = l_prev * alpha + p.sum(-1)
-        acc = acc * alpha[:, None] + p @ v_tile
+        p = jnp.exp(s - m_cur)
+        l_cur = l_prev * alpha + p.sum(-1, keepdims=True)
+        acc = acc * alpha + _dot(p, v_tile)
         return acc, m_cur, l_cur
 
     acc0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
+    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((block_q, 1), jnp.float32)
 
     if causal:
         # only stream kv blocks that intersect the causal/window band
@@ -109,8 +109,48 @@ def _attn_kernel(
         lo = jnp.maximum(0, (q_blk * block_q - window) // block_k)
     acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[0, 0] = m + jnp.log(l_safe)
+
+
+def _positions(blk, size: int, axis: int) -> jax.Array:
+    """Sequence positions of a block: a (size, 1) column (axis 0) or a
+    (1, size) row (axis 1). Kernel vectors stay 2-D, as the TPU needs."""
+    shape = (size, 1) if axis == 0 else (1, size)
+    return blk * size + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _mask(q_pos, k_pos, *, seq_q, seq_k, causal, window):
+    """(block_q, block_k) validity of score tiles from position vectors."""
+    mask = jnp.broadcast_to(k_pos < seq_k, (q_pos.shape[0], k_pos.shape[1]))
+    if seq_q is not None:
+        mask &= q_pos < seq_q
+    if causal:
+        mask &= q_pos >= k_pos
+    if window is not None:
+        mask &= q_pos - k_pos < window
+    return mask
+
+
+def _dot(a, b):
+    """a @ b with f32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _dot_nt(a, b):
+    """a @ b.T with f32 accumulation (no transposed copy of b)."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _dot_tn(a, b):
+    """a.T @ b with f32 accumulation (no transposed copy of a)."""
+    return jax.lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
 
 
 def _pad_seq(x: jax.Array, to: int) -> jax.Array:
@@ -133,7 +173,11 @@ def flash_attention_fwd(
     interpret: bool = False,
     return_lse: bool = False,
 ):
-    """Forward kernel launch. Returns o, or (o, lse (B, Hq, S) f32)."""
+    """Forward kernel launch. Returns o, or (o, lse (B, Hq, S) f32).
+
+    The kernel writes lse as (B, Hq, S, 1) so that its block's last two
+    dimensions are (block_q, whole axis), which the TPU's tiling accepts.
+    """
     B, Hq, S, D = q.shape
     _, Hkv, Sk, _ = k.shape
     assert Hq % Hkv == 0
@@ -155,7 +199,7 @@ def flash_attention_fwd(
         _attn_kernel, block_q=block_q, block_k=block_k, seq_k=Sk,
         causal=causal, window=window, softcap=softcap, scale=scale,
     )
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -165,17 +209,17 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Sp, D), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp, vp)
     o = o[:, :, :S]
     if return_lse:
-        return o, lse[:, :, :S]
+        return o, lse[:, :, :S, 0]
     return o
 
 
@@ -195,22 +239,19 @@ def _bwd_tile(q, k, v, do, lse, delta, q_pos, k_pos, *,
               seq_q, seq_k, causal, window, softcap):
     """Shared per-tile math: (p, ds) from one (block_q, block_k) tile.
 
-    q is pre-scaled; all operands f32. Invalid (masked / padded) pairs
+    q is pre-scaled; all operands f32; lse/delta/q_pos are (block_q, 1)
+    columns and k_pos a (1, block_k) row. Invalid (masked / padded) pairs
     yield p = ds = 0 exactly.
     """
-    s = q @ k.T  # (block_q, block_k), pre-softcap
+    s = _dot_nt(q, k)  # (block_q, block_k), pre-softcap
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    mask = jnp.ones(s.shape, jnp.bool_)
-    if causal:
-        mask &= q_pos[:, None] >= k_pos[None, :]
-    if window is not None:
-        mask &= q_pos[:, None] - k_pos[None, :] < window
-    mask &= (q_pos < seq_q)[:, None] & (k_pos < seq_k)[None, :]
+    mask = _mask(q_pos, k_pos, seq_q=seq_q, seq_k=seq_k, causal=causal,
+                 window=window)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])  # rebuilt from the residual, <= 1
-    dp = do @ v.T  # (block_q, block_k)
-    ds = p * (dp - delta[:, None])
+    p = jnp.exp(s - lse)  # rebuilt from the residual, <= 1
+    dp = _dot_nt(do, v)  # (block_q, block_k)
+    ds = p * (dp - delta)
     if softcap is not None:
         # d/dx softcap*tanh(x/softcap) = 1 - tanh^2 = 1 - (s/softcap)^2
         ds = ds * jnp.where(mask, 1.0 - jnp.square(s / softcap), 0.0)
@@ -225,7 +266,7 @@ def _attn_bwd_dq_kernel(
     """dq for one (batch, q-head, q-block): stream KV tiles, accumulate.
 
     q/do/dq refs: (1, 1, block_q, D); k/v refs: (1, 1, seq_k_pad, D);
-    lse/dl refs: (1, 1, block_q).
+    lse/dl refs: (1, 1, block_q, 1).
     """
     q_blk = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale
@@ -233,7 +274,7 @@ def _attn_bwd_dq_kernel(
     lse = lse_ref[0, 0].astype(jnp.float32)
     delta = dl_ref[0, 0].astype(jnp.float32)
     D = q.shape[-1]
-    q_pos = q_blk * block_q + jax.lax.iota(jnp.int32, block_q)
+    q_pos = _positions(q_blk, block_q, 0)
     num_k_blocks = pl.cdiv(seq_k, block_k)
 
     def body(i, acc):
@@ -243,13 +284,13 @@ def _attn_bwd_dq_kernel(
         v_tile = v_ref[0, 0, pl.ds(i * block_k, block_k), :].astype(
             jnp.float32
         )
-        k_pos = i * block_k + jax.lax.iota(jnp.int32, block_k)
+        k_pos = _positions(i, block_k, 1)
         _, ds = _bwd_tile(
             q, k_tile, v_tile, do, lse, delta, q_pos, k_pos,
             seq_q=seq_q, seq_k=seq_k, causal=causal, window=window,
             softcap=softcap,
         )
-        return acc + ds @ k_tile
+        return acc + _dot(ds, k_tile)
 
     if causal:
         hi = jnp.minimum(num_k_blocks, (q_blk + 1) * block_q // block_k + 1)
@@ -270,13 +311,14 @@ def _attn_bwd_dkv_kernel(
     """dk/dv (per q head) for one (batch, q-head, kv-block): stream Q tiles.
 
     k/v/dk/dv refs: (1, 1, block_k, D); q/do refs: (1, 1, seq_q_pad, D);
-    lse/dl refs: (1, 1, seq_q_pad). GQA group-sum happens in the wrapper.
+    lse/dl refs: (1, 1, seq_q_pad, 1). GQA group-sum happens in the
+    wrapper.
     """
     k_blk = pl.program_id(2)
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     D = k.shape[-1]
-    k_pos = k_blk * block_k + jax.lax.iota(jnp.int32, block_k)
+    k_pos = _positions(k_blk, block_k, 1)
     num_q_blocks = pl.cdiv(seq_q, block_q)
 
     def body(i, carry):
@@ -287,15 +329,16 @@ def _attn_bwd_dkv_kernel(
         do_tile = do_ref[0, 0, pl.ds(i * block_q, block_q), :].astype(
             jnp.float32
         )
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)].astype(jnp.float32)
-        delta = dl_ref[0, 0, pl.ds(i * block_q, block_q)].astype(jnp.float32)
-        q_pos = i * block_q + jax.lax.iota(jnp.int32, block_q)
+        rows = pl.ds(i * block_q, block_q)
+        lse = lse_ref[0, 0, rows, :].astype(jnp.float32)
+        delta = dl_ref[0, 0, rows, :].astype(jnp.float32)
+        q_pos = _positions(i, block_q, 0)
         p, ds = _bwd_tile(
             q_tile, k, v, do_tile, lse, delta, q_pos, k_pos,
             seq_q=seq_q, seq_k=seq_k, causal=causal, window=window,
             softcap=softcap,
         )
-        return dk_acc + ds.T @ q_tile, dv_acc + p.T @ do_tile
+        return dk_acc + _dot_tn(ds, q_tile), dv_acc + _dot_tn(p, do_tile)
 
     # only q blocks intersecting the causal/window band see this kv tile
     lo = k_blk * block_k // block_q if causal else 0
@@ -346,16 +389,17 @@ def flash_attention_bwd(
 
     qp, dop = _pad_seq(q, block_q), _pad_seq(do, block_q)
     kp, vp = _pad_seq(k, block_k), _pad_seq(v, block_k)
-    pad_q = qp.shape[2] - S
-    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))
-    deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))
+    # row statistics go in as (B, Hq, S_pad, 1) columns (see the forward)
+    pad_q = ((0, 0), (0, 0), (0, qp.shape[2] - S))
+    lsep = jnp.pad(lse, pad_q)[..., None]
+    deltap = jnp.pad(delta, pad_q)[..., None]
     Sp, Skp = qp.shape[2], kp.shape[2]
 
     statics = dict(
         block_q=block_q, block_k=block_k, seq_q=S, seq_k=Sk, causal=causal,
         window=window, softcap=softcap, scale=scale,
     )
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_attn_bwd_dq_kernel, **statics),
         grid=(B, Hq, Sp // block_q),
         in_specs=[
@@ -363,15 +407,15 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, Skp, D), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, Skp, D), lambda b, h, i: (b, h // group, 0, 0)),
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, h, i)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sp, D), jnp.float32),
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, deltap)
 
-    dkq, dvq = pl.pallas_call(
+    dkq, dvq = pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, **statics),
         grid=(B, Hq, Skp // block_k),
         in_specs=[
@@ -379,8 +423,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h // group, j, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h // group, j, 0)),
             pl.BlockSpec((1, 1, Sp, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Sp), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((1, 1, Sp), lambda b, h, j: (b, h, 0)),
+            pl.BlockSpec((1, 1, Sp, 1), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Sp, 1), lambda b, h, j: (b, h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),

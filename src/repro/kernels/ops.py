@@ -89,7 +89,7 @@ _BF16_GRAD_TOL = Tolerance(5e-2, 5e-2)
 
 
 def _strip_unknown_kwargs(fn: Callable) -> Callable:
-    """Drop kernel-only kwargs (node_block, compute_dtype, block_d, ...)
+    """Drop kernel-only kwargs (compute_dtype, block_d, ...)
     before calling a pure-jnp oracle, so one call site can dispatch to
     either backend with the kernel's full kwarg surface."""
     params = inspect.signature(fn).parameters.values()
@@ -474,18 +474,16 @@ def saga_sparse_dot(psi, idx, val, *, use_pallas: str = "auto"):
     return dispatch("sparse_dot", psi, idx, val, use_pallas=use_pallas)
 
 
-@partial(
-    jax.jit, static_argnames=("use_pallas", "compute_dtype", "node_block")
-)
+@partial(jax.jit, static_argnames=("use_pallas", "compute_dtype"))
 def saga_sparse_axpy(psi, idx, val, coef, rho, *, use_pallas: str = "auto",
-                     compute_dtype=None, node_block: int = 1):
+                     compute_dtype=None):
     """Registry-dispatched sparse AXPY row update (the DSBA-s relay's
     densification hot path)."""
     # compute_dtype=None -> the registry adapter's central policy
     # (_resolve_compute_dtype); the ref backend strips kernel-only kwargs
     return dispatch(
         "sparse_axpy", psi, idx, val, coef, rho, use_pallas=use_pallas,
-        compute_dtype=compute_dtype, node_block=node_block,
+        compute_dtype=compute_dtype,
     )
 
 

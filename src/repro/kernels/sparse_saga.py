@@ -7,14 +7,14 @@ The paper's per-node hot loop with linear predictors is:
 
 GPUs do (1)/(3) with native gather/scatter; TPUs have no efficient VMEM
 gather, so the TPU-native adaptation processes the d-dimensional model row
-in VMEM blocks and expresses gather/scatter as ONE-HOT MATMULS against the
-in-block index match — turning irregular memory access into MXU contractions
-(DESIGN.md §5). Cost per node: O(k * d_block) per block, O(k * d) total —
-the same O(rho d) as the paper.
+in VMEM blocks and matches indices against the block's columns: the gather
+is a ONE-HOT MATMUL on the MXU (DESIGN.md §5), the scatter a one-hot select
+summed over k on the VPU. Cost per node: O(k * d_block) per block,
+O(k * d) total — the same O(rho d) as the paper.
 
-Grid: (N nodes, d blocks). sparse_dot accumulates per-node partial dots via
-an output block revisited across the d grid axis; sparse_axpy is elementwise
-per block.
+sparse_dot's grid is (N nodes, d blocks) and accumulates per-node partial
+dots in an output block revisited across the d axis; sparse_axpy's grid is
+the d blocks alone, each cell covering every node.
 """
 from __future__ import annotations
 
@@ -23,6 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import pallas_call
 
 
 def _dot_kernel(psi_ref, idx_ref, val_ref, out_ref, *, block_d: int, d: int,
@@ -75,7 +77,7 @@ def sparse_dot(
     kernel = functools.partial(
         _dot_kernel, block_d=block_d, d=D, compute_dtype=compute_dtype
     )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -93,30 +95,31 @@ def _axpy_kernel(psi_ref, idx_ref, val_ref, coef_ref, rho_ref, out_ref, *,
                  block_d: int, compute_dtype):
     """out_block = rho * psi_block + coef * scatter(val at idx) in-block.
 
-    Handles a (node_block, block_d) tile: the one-hot match is batched over
-    the node axis, so a single grid cell can cover several nodes (node_block
-    > 1 keeps the interpret-mode grid tiny on CPU).
+    One grid cell holds every node's (N, block_d) tile; a loop over the
+    node rows builds each row's scatter as a (k, block_d) one-hot select
+    reduced over k on the VPU. The index and value rows arrive as (k, 1)
+    columns, so the one-hot compares a column against a lane iota and no
+    in-kernel transpose, batched dot or MXU rounding is involved: the sum
+    has at most one nonzero term per column (distinct indices per row), so
+    it is exact in any dtype.
     """
-    j = pl.program_id(1)
-    psi = psi_ref[...].astype(compute_dtype)  # (nb, block_d)
-    idx = idx_ref[...]  # (nb, k)
-    val = val_ref[...].astype(compute_dtype)
-    coef = coef_ref[...].astype(compute_dtype)  # (nb,)
-    rho = rho_ref[...].astype(compute_dtype)
-    lo = j * block_d
-    local = idx - lo
-    in_blk = (local >= 0) & (local < block_d)
-    onehot = (
-        local[:, :, None]
-        == jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_d), 2)
-    ) & in_blk[:, :, None]
-    # batched gather-as-matmul: (nb, k) x (nb, k, block_d) -> (nb, block_d)
-    scat = jnp.einsum(
-        "nk,nkb->nb", val, onehot.astype(compute_dtype),
-        preferred_element_type=compute_dtype,
-    )
-    out = rho[:, None] * psi + coef[:, None] * scat
-    out_ref[...] = out.astype(out_ref.dtype)
+    j = pl.program_id(0)
+    k = idx_ref.shape[1]
+    cols = j * block_d + jax.lax.broadcasted_iota(jnp.int32, (k, block_d), 1)
+
+    def row(r, carry):
+        idx = idx_ref[r]  # (k, 1)
+        val = val_ref[r].astype(compute_dtype)  # (k, 1)
+        hit = idx == cols  # (k, block_d); out-of-block indices never match
+        scat = jnp.sum(jnp.where(hit, val, 0.0), axis=0, keepdims=True)
+        rows = pl.ds(r, 1)
+        psi = psi_ref[rows, :].astype(compute_dtype)  # (1, block_d)
+        rho = rho_ref[rows, :].astype(compute_dtype)  # (1, 1)
+        coef = coef_ref[rows, :].astype(compute_dtype)
+        out_ref[rows, :] = (rho * psi + coef * scat).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, idx_ref.shape[0], row, 0)
 
 
 def sparse_axpy(
@@ -129,38 +132,45 @@ def sparse_axpy(
     block_d: int = 512,
     interpret: bool = False,
     compute_dtype=jnp.float32,
-    node_block: int = 1,
 ) -> jax.Array:
     """out[n] = rho[n] * psi[n] + coef[n] * x_n (sparse row scatter).
 
     compute_dtype: in-kernel arithmetic dtype (see sparse_dot). The output
     keeps psi.dtype either way.
-    node_block: nodes per grid cell. 1 (default) is the TPU layout; CPU
-    interpret-mode callers pass node_block=N to collapse the grid to a
-    single cell (the emulated grid is a compile-time loop, so a small grid
-    keeps trace/compile time flat).
+
+    The grid runs over d blocks only; each cell covers all N nodes, so every
+    block's second-minor dimension is the whole node axis (legal on the TPU
+    for any N) and the emulated grid stays small in interpret mode. The
+    per-node operands are laid out as columns: idx/val as (N, k, 1),
+    coef/rho as (N, 1).
     """
     N, D = psi.shape
     k = idx.shape[1]
+    if not interpret and jnp.float64 in (psi.dtype, jnp.dtype(compute_dtype)):
+        raise ValueError(
+            "the compiled TPU kernel has no float64 (Mosaic lacks it): pass "
+            "float32 data, or use the jnp reference (use_pallas='off')"
+        )
     block_d = min(block_d, D)
-    node_block = min(node_block, N)
-    if N % node_block:
-        raise ValueError(f"node_block={node_block} must divide N={N}")
-    grid = (N // node_block, pl.cdiv(D, block_d))
+    if block_d < D and block_d % 128:
+        raise ValueError(f"block_d={block_d} must be a multiple of 128")
     kernel = functools.partial(
         _axpy_kernel, block_d=block_d, compute_dtype=compute_dtype
     )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
-        grid=grid,
+        grid=(pl.cdiv(D, block_d),),
         in_specs=[
-            pl.BlockSpec((node_block, block_d), lambda n, j: (n, j)),
-            pl.BlockSpec((node_block, k), lambda n, j: (n, 0)),
-            pl.BlockSpec((node_block, k), lambda n, j: (n, 0)),
-            pl.BlockSpec((node_block,), lambda n, j: (n,)),
-            pl.BlockSpec((node_block,), lambda n, j: (n,)),
+            pl.BlockSpec((N, block_d), lambda j: (0, j)),
+            pl.BlockSpec((N, k, 1), lambda j: (0, 0, 0)),
+            pl.BlockSpec((N, k, 1), lambda j: (0, 0, 0)),
+            pl.BlockSpec((N, 1), lambda j: (0, 0)),
+            pl.BlockSpec((N, 1), lambda j: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((node_block, block_d), lambda n, j: (n, j)),
+        out_specs=pl.BlockSpec((N, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((N, D), psi.dtype),
         interpret=interpret,
-    )(psi, idx.astype(jnp.int32), val, coef, rho)
+    )(
+        psi, idx.astype(jnp.int32)[..., None], val[..., None],
+        coef.reshape(N, 1), rho.reshape(N, 1),
+    )

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_call
+
 
 def _ssd_chunk_kernel(xdt_ref, cum_ref, b_ref, c_ref, y_ref, st_ref, *,
                       head_block: int):
@@ -87,7 +89,7 @@ def ssd_chunk_fwd(
 
     kernel = functools.partial(_ssd_chunk_kernel, head_block=head_block)
     grid = (B, nc, hb_count)
-    y, st = pl.pallas_call(
+    y, st = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -194,7 +196,7 @@ def ssd_chunk_bwd(
 
     kernel = functools.partial(_ssd_bwd_kernel, head_block=head_block)
     grid = (B, nc, hb_count)
-    dxdt, dcum, dB, dC = pl.pallas_call(
+    dxdt, dcum, dB, dC = pallas_call(
         kernel,
         grid=grid,
         in_specs=[

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import pallas_call
+
 
 def _topk_kernel(x_ref, vals_ref, idx_ref, *, k: int, block: int):
     x = x_ref[0].astype(jnp.float32)  # (block,)
@@ -43,7 +45,7 @@ def block_topk(
     """Per-block top-k by |value|: (vals (nb, k), local idx (nb, k) int32)."""
     nb, block = x.shape
     kernel = functools.partial(_topk_kernel, k=k, block=block)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],

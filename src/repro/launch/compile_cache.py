@@ -12,9 +12,12 @@ this module turns it on with repo-appropriate defaults.
 every entrypoint (tests, benchmarks, notebooks) gets it without
 ceremony. Policy:
 
-* Default location is ``<repo root>/.jax_compile_cache`` (git-ignored)
-  when the source tree is recognizable, else ``~/.cache/repro_jax``.
-* ``REPRO_COMPILE_CACHE_DIR`` overrides the location.
+* Where JAX's own ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
+  it and this module sets no directory: the cache lands there and nowhere
+  else.
+* Otherwise the cache lives at the fixed ``<checkout>/.jax_compile_cache``
+  (git-ignored). The path is part of what makes a later run hit, so it
+  never moves; outside a source checkout no cache is enabled.
 * ``REPRO_NO_COMPILE_CACHE`` (any non-empty value) disables the cache —
   the escape hatch for cold-start benchmarks and cache-behavior tests.
 * Thresholds are zeroed (``min_compile_time_secs``/``min_entry_size``)
@@ -32,46 +35,48 @@ from pathlib import Path
 _ENABLED: str | None = None  # cache dir once enabled, for introspection
 
 
-def default_cache_dir() -> Path:
-    """Repo-local ``.jax_compile_cache`` if we can find the repo root.
+def default_cache_dir() -> Path | None:
+    """``<checkout>/.jax_compile_cache``, or None outside a source checkout.
 
-    Walks up from this file looking for ``pyproject.toml``; falls back to
-    ``~/.cache/repro_jax`` for installed-package deployments.
+    The checkout is the nearest parent of this file holding
+    ``pyproject.toml``.
     """
     here = Path(__file__).resolve()
     for parent in here.parents:
         if (parent / "pyproject.toml").exists():
             return parent / ".jax_compile_cache"
-    return Path.home() / ".cache" / "repro_jax"
+    return None
 
 
 def enable_persistent_cache() -> str | None:
-    """Point JAX at the on-disk compilation cache. Returns the dir, or None.
+    """Turn on JAX's on-disk compilation cache. Returns the dir, or None.
 
     Safe to call any number of times and before/after the first JAX
     computation (config updates apply to subsequent compiles). Honors
-    ``REPRO_NO_COMPILE_CACHE`` / ``REPRO_COMPILE_CACHE_DIR``.
+    ``JAX_COMPILATION_CACHE_DIR`` and ``REPRO_NO_COMPILE_CACHE``.
     """
     global _ENABLED
     if os.environ.get("REPRO_NO_COMPILE_CACHE"):
         return None
     if _ENABLED is not None:
         return _ENABLED
-    cache_dir = os.environ.get("REPRO_COMPILE_CACHE_DIR") or str(
-        default_cache_dir()
-    )
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    default = None if env_dir else default_cache_dir()
+    if not env_dir and default is None:
+        return None
     try:
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if default is not None:
+            jax.config.update("jax_compilation_cache_dir", str(default))
         # This repo compiles many small programs; the stock 1 s /
         # non-zero-size floors would skip nearly all of them.
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     except Exception:  # pragma: no cover - never block import on cache setup
         return None
-    _ENABLED = cache_dir
-    return cache_dir
+    _ENABLED = env_dir or str(default)
+    return _ENABLED
 
 
 def enabled_dir() -> str | None:

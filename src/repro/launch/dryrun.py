@@ -167,7 +167,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             t2 = time.time()
             mem = compiled.memory_analysis()
             print(compiled.memory_analysis())  # proves it fits
-            cost = H.xla_cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             print({k: cost[k] for k in ("flops", "bytes accessed") if k in cost})
             hlo_text = compiled.as_text()
             if hlo_path is not None:

@@ -18,10 +18,8 @@ math on scanned programs (tests/test_hlo_analysis.py).
 Contract: `program_costs(hlo_text)` is pure text analysis — it never
 executes the program, tolerates unknown ops (counted as zero-cost), and
 weights every instruction by the product of the trip counts of the while
-loops enclosing it. `xla_cost_analysis(compiled)` is the only function
-that touches a live executable, and only to normalize the dict/list API
-drift. Hardware model (TPU v5e target): 197 TFLOP/s bf16 per chip,
-819 GB/s HBM, ~50 GB/s/link ICI.
+loops enclosing it. Hardware model (TPU v5e target): 197 TFLOP/s bf16
+per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
 """
 from __future__ import annotations
 
@@ -63,21 +61,10 @@ _NO_BYTES = {
     "opt-barrier", "domain", "partition-id", "replica-id", "iota",
 }
 
-def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across the JAX API drift.
-
-    Older jaxlibs return a dict; current ones (>= 0.4.34) return a list with
-    one properties dict per executable program. Callers always want the
-    entry program's dict — indexing the list with a string key was the
-    failure mode this wraps.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+# a shape's layout, e.g. f32[1,512]{1,0:T(1,128)S(1)} on the TPU: its tile
+# parentheses would break the instruction pattern, and it carries no size
+_LAYOUT_RE = re.compile(r"(\w+\[[\d,]*\])\{[^{}]*\}")
 # tuple types contain /*index=N*/ comments (with '=') but never nested
 # parens, so the tuple branch is "anything but parens"
 _INSTR_RE = re.compile(
@@ -130,6 +117,7 @@ def _parse_computations(text: str) -> dict[str, _CompCost]:
     cur: _CompCost | None = None
     shapes: dict[str, str] = {}
     for line in text.splitlines():
+        line = _LAYOUT_RE.sub(r"\1", line)
         hdr = _COMP_HDR_RE.match(line)
         if hdr and ("{" in line or line.rstrip().endswith("->") or "->" in line):
             cur = _CompCost()

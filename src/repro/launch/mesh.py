@@ -10,11 +10,23 @@ placing one graph node per device, the substrate of the ``comm="sharded"``
 backend (``core.comm.ShardedComm``). On CPU, simulate N devices with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set BEFORE jax is
 imported — tests spawn a subprocess tier for this, see tests/conftest.py).
+
+Every mesh here has Auto axes: the model code places activations with
+``with_sharding_constraint``, which refuses the Explicit axes that
+``jax.make_mesh`` makes by default.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
+
+
+def _auto_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis Auto (see the module docstring)."""
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_node_mesh(n: int, devices=None) -> jax.sharding.Mesh:
@@ -31,7 +43,7 @@ def make_node_mesh(n: int, devices=None) -> jax.sharding.Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
             "importing jax"
         )
-    return jax.make_mesh((n,), ("node",), devices=np.asarray(devs[:n]))
+    return _auto_mesh((n,), ("node",), np.asarray(devs[:n]))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -46,7 +58,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     avail = len(jax.devices())
     if avail == n:
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     if avail < n:
         raise ValueError(
             f"production mesh {dict(zip(axes, shape))} needs {n} devices, "
@@ -54,10 +66,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n}"
         )
     # host-device dry-run with a surplus: the first n placeholders back it
-    return jax.make_mesh(shape, axes, devices=np.asarray(jax.devices()[:n]))
+    return _auto_mesh(shape, axes, np.asarray(jax.devices()[:n]))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over however many host devices a test configured."""
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=np.asarray(jax.devices()[:n]))
+    return _auto_mesh(shape, axes, np.asarray(jax.devices()[:n]))

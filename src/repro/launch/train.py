@@ -16,13 +16,18 @@ import argparse
 import os
 import time
 
-# collective/compute overlap (no-ops on CPU; the TPU deployment flags)
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
+# collective/compute overlap (no-ops on CPU; the TPU deployment flags).
+# Appended to whatever LIBTPU_INIT_ARGS already holds, never replacing it;
+# a flag the environment already names keeps the environment's value.
+_OVERLAP_FLAGS = (
+    "--xla_tpu_enable_async_collective_fusion=true",
+    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
     "--xla_tpu_overlap_compute_collective_tc=true",
 )
+_libtpu_args = os.environ.get("LIBTPU_INIT_ARGS", "")
+os.environ["LIBTPU_INIT_ARGS"] = " ".join([_libtpu_args] + [
+    f for f in _OVERLAP_FLAGS if f.split("=")[0] + "=" not in _libtpu_args
+]).strip()
 
 import jax
 import numpy as np

@@ -139,11 +139,19 @@ def softcap(x: jax.Array, cap: float | None) -> jax.Array:
 
 def attention_defs(cfg: ModelConfig, cross: bool = False) -> dict:
     d, qd, kvd, hd = cfg.d_model, cfg.q_dim, cfg.kv_dim, cfg.head_dim
+    # explicit fan-in scales: these projections contract over d (q/k/v) and
+    # over heads x head_dim (o), which the default (the second-to-last dim)
+    # would miss — leaving attention scores ~d/heads times too large
+    proj_in, proj_out = d**-0.5, (cfg.n_heads * hd) ** -0.5
     defs = {
-        "wq": ParamDef((d, cfg.n_heads, hd), ("embed", "heads", None)),
-        "wk": ParamDef((d, cfg.n_kv_heads, hd), ("embed", "kv_heads", None)),
-        "wv": ParamDef((d, cfg.n_kv_heads, hd), ("embed", "kv_heads", None)),
-        "wo": ParamDef((cfg.n_heads, hd, d), ("heads", None, "embed")),
+        "wq": ParamDef((d, cfg.n_heads, hd), ("embed", "heads", None),
+                       scale=proj_in),
+        "wk": ParamDef((d, cfg.n_kv_heads, hd), ("embed", "kv_heads", None),
+                       scale=proj_in),
+        "wv": ParamDef((d, cfg.n_kv_heads, hd), ("embed", "kv_heads", None),
+                       scale=proj_in),
+        "wo": ParamDef((cfg.n_heads, hd, d), ("heads", None, "embed"),
+                       scale=proj_out),
     }
     if cfg.qkv_bias:
         defs["bq"] = ParamDef((cfg.n_heads, hd), ("heads", None), init="zeros")
@@ -203,20 +211,30 @@ def multi_head_attention(
         q = rope(q, positions, cfg.rope_theta)
 
     new_cache = None
+    # a cache whose position is the STATIC 0 is being filled from empty
+    # (transformer.prefill): the new tokens attend to themselves only, so
+    # they take the full-sequence path, kernel routing included
+    fresh = (
+        cache is not None and kv_x is None
+        and isinstance(cache["pos"], int) and cache["pos"] == 0
+    )
     if cache is not None and kv_x is None:
         # self-attention decode: insert current K/V at position `pos`
         pos = cache["pos"]  # scalar int
         ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(dt), pos, 1)
         cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(dt), pos, 1)
-        k, v = ck, cv
         new_cache = {"k": ck, "v": cv, "pos": pos + S}
-        kv_pos = jnp.broadcast_to(jnp.arange(ck.shape[1])[None], (B, ck.shape[1]))
+        if not fresh:
+            k, v = ck, cv
+            kv_pos = jnp.broadcast_to(
+                jnp.arange(ck.shape[1])[None], (B, ck.shape[1])
+            )
     elif cache is not None:
         new_cache = cache
 
     use_kernel = (
-        cfg.attention_kernel != "jnp" and cache is None and kv_x is None
-        and not cfg.blockwise_attention
+        cfg.attention_kernel != "jnp" and (cache is None or fresh)
+        and kv_x is None and not cfg.blockwise_attention
     )
     if not use_kernel:
         # GQA grouping

@@ -541,10 +541,16 @@ def prefill(
     exactly valid_len tokens. Attention K/V *at pad positions* hold
     garbage; the serving layer only copies the valid blocks into the pool,
     and the contiguous cache's 'pos' advances by the PADDED S.
+
+    `cache` must be empty (fresh from ``init_cache``): its position is
+    replaced by a static 0, which tells the attention layers that the
+    prompt attends to itself only and routes it through the full-sequence
+    attention path (the flash kernel under ``cfg.attention_kernel``).
     """
     new_cache, x = _stack_apply(
-        cfg, params, tokens, cache, enc_embeds, valid_len
+        cfg, params, tokens, _with_pos(cfg, cache, 0), enc_embeds, valid_len
     )
+    new_cache = _with_pos(cfg, new_cache, jnp.int32(tokens.shape[1]))
     if valid_len is None:
         xl = x[:, -1:]
     else:
@@ -552,6 +558,17 @@ def prefill(
         xl = jnp.take_along_axis(x, idx[:, None, None], axis=1)
     logits = _unembed(cfg, params, xl)
     return new_cache, logits[:, 0]
+
+
+def _with_pos(cfg, cache, pos):
+    """Shallow copy of a decode cache with its attention position set."""
+    if cfg.family in ("dense", "moe"):
+        return {**cache, "pos": pos}
+    if cfg.family == "hybrid":
+        return {**cache, "attn": {**cache["attn"], "pos": pos}}
+    if cfg.family == "encdec":
+        return {**cache, "self": {**cache["self"], "pos": pos}}
+    return cache  # ssm caches carry no position
 
 
 def _cache_pos(cfg, cache):

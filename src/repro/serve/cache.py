@@ -245,8 +245,11 @@ class CachePool:
 
     def _refresh(self) -> None:
         if self._dirty or self._table_dev is None:
-            self._table_dev = jnp.asarray(self.table)
-            self._lengths_dev = jnp.asarray(self.lengths)
+            # copies: on the CPU jnp.asarray may alias the numpy buffers,
+            # which the host mutates in place while dispatched work that
+            # read them can still be running
+            self._table_dev = jnp.array(self.table)
+            self._lengths_dev = jnp.array(self.lengths)
             self._dirty = False
 
     # -- landing prefill results --------------------------------------------

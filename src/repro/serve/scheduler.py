@@ -283,7 +283,7 @@ class Scheduler:
         if self.active:
             pools, logits = self._decode(
                 self.params,
-                jnp.asarray(self._cur_tok),
+                jnp.array(self._cur_tok),  # a copy: mutated below
                 self.pool.pools,
                 self.pool.device_table(),
                 self.pool.device_lengths(),

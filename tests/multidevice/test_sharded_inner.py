@@ -195,7 +195,9 @@ def test_gossip_dense_mix_spmd_matches_local():
     from repro.core.gossip import GossipConfig, make_dense_mix
 
     gc = GossipConfig(n_pods=8, topology="ring")
-    mesh = jax.make_mesh((8,), ("pod",))
+    from repro.launch.mesh import make_test_mesh
+
+    mesh = make_test_mesh((8,), ("pod",))
     leaf_specs = {"a": P(), "b": P()}
     rng = np.random.default_rng(3)
     tree = {

@@ -3,7 +3,8 @@
 The cache is enabled on ``import repro.core`` (launch/compile_cache.py).
 Cross-process behavior can only be observed from fresh interpreters, so
 the hit test runs the same tiny solve in two subprocesses against a
-private cache dir: the first populates it, the second must add nothing.
+private cache dir (JAX's own ``JAX_COMPILATION_CACHE_DIR``): the first
+populates it, the second must add nothing.
 """
 import os
 import subprocess
@@ -28,7 +29,7 @@ def _run_child(cache_env):
         str(REPO / "src") + os.pathsep + env.get("PYTHONPATH", "")
     )
     env.pop("REPRO_NO_COMPILE_CACHE", None)
-    env.pop("REPRO_COMPILE_CACHE_DIR", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(cache_env)
     proc = subprocess.run(
         [sys.executable, "-c", SOLVE_SNIPPET],
@@ -45,7 +46,7 @@ def _entries(cache_dir: Path) -> set[str]:
 
 def test_second_process_hits_the_cache(tmp_path):
     cache = tmp_path / "xla_cache"
-    env = {"REPRO_COMPILE_CACHE_DIR": str(cache)}
+    env = {"JAX_COMPILATION_CACHE_DIR": str(cache)}
     _run_child(env)
     first = _entries(cache)
     assert first, "first process should populate the compile cache"
@@ -58,7 +59,7 @@ def test_second_process_hits_the_cache(tmp_path):
 def test_opt_out_env_disables_the_cache(tmp_path):
     cache = tmp_path / "xla_cache"
     _run_child({
-        "REPRO_COMPILE_CACHE_DIR": str(cache),
+        "JAX_COMPILATION_CACHE_DIR": str(cache),
         "REPRO_NO_COMPILE_CACHE": "1",
     })
     assert not _entries(cache)
@@ -70,3 +71,29 @@ def test_default_dir_is_repo_local_and_ignored():
     d = default_cache_dir()
     assert d == REPO / ".jax_compile_cache"
     assert ".jax_compile_cache" in (REPO / ".gitignore").read_text()
+
+
+def test_standard_env_var_wins_and_code_sets_no_directory(
+    tmp_path, monkeypatch
+):
+    import jax
+
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(compile_cache, "_ENABLED", None)
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, val: updates.append((name, val))
+    )
+    monkeypatch.delenv("REPRO_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_persistent_cache() == str(tmp_path)
+    assert "jax_compilation_cache_dir" not in {name for name, _ in updates}
+
+    # without the variable, the fixed checkout-local directory is set
+    updates.clear()
+    monkeypatch.setattr(compile_cache, "_ENABLED", None)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(REPO / ".jax_compile_cache")
+    assert compile_cache.enable_persistent_cache() == want
+    assert ("jax_compilation_cache_dir", want) in updates

@@ -27,7 +27,8 @@ SCRIPT = textwrap.dedent(
     arch, shape, multi = sys.argv[1], sys.argv[2], sys.argv[3] == "multi"
     mesh_shape = (2, 2, 2) if multi else (2, 4)
     axes = ("pod", "data", "model") if multi else ("data", "model")
-    mesh = jax.make_mesh(mesh_shape, axes, devices=np.asarray(jax.devices()))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh(mesh_shape, axes)
 
     cfg = get_reduced(arch)
     # shrink the shape grid to smoke scale
@@ -41,7 +42,7 @@ SCRIPT = textwrap.dedent(
     with mesh, use_constraint_mesh(mesh):
         fn, sds = build_cell(cfg, shape, mesh, multi)
         compiled = fn.lower(*sds).compile()
-        cost = H.xla_cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         colls = H.collective_stats(compiled.as_text())
     print(json.dumps({
         "flops": float(cost.get("flops", 0)),

@@ -16,7 +16,7 @@ def test_dot_flops_match_cost_analysis_no_loops():
     w = jax.ShapeDtypeStruct((256, 512), jnp.float32)
 
     comp = _compile(lambda a, b: a @ b, x, w)
-    want = H.xla_cost_analysis(comp)["flops"]
+    want = comp.cost_analysis()["flops"]
     got = H.program_costs(comp.as_text()).flops
     assert abs(got - want) / want < 0.05, (got, want)
 
@@ -34,7 +34,7 @@ def test_scan_flops_multiplied_by_trip_count():
         return y
 
     comp = _compile(scanned, x, ws)
-    xla_flops = H.xla_cost_analysis(comp)["flops"]
+    xla_flops = comp.cost_analysis()["flops"]
     ours = H.program_costs(comp.as_text()).flops
     one_matmul = 2 * M * M * M
     # XLA reports ~1 matmul; we must report ~L matmuls
@@ -86,7 +86,8 @@ def test_collective_bytes_inside_loops_are_multiplied():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch import hlo_analysis as H
 
-        mesh = jax.make_mesh((4,), ("data",), devices=np.asarray(jax.devices()))
+        from repro.launch.mesh import make_test_mesh
+        mesh = make_test_mesh((4,), ("data",))
         L, M = 8, 64
 
         def f(x, ws):
@@ -117,3 +118,22 @@ def test_collective_bytes_inside_loops_are_multiplied():
                          text=True, timeout=300, env=env)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK True" in out.stdout, out.stdout
+
+
+def test_collectives_parse_through_tpu_tiled_layouts():
+    """TPU HLO writes tiled layouts (``{1,0:T(1,128)S(1)}``) after shapes;
+    their parentheses must not hide the instruction from the parser."""
+    text = "\n".join([
+        "HloModule m",
+        "",
+        "ENTRY %main (p: f32[1,512]) -> f32[1,512] {",
+        "  %p = f32[1,512]{1,0:T(1,128)} parameter(0)",
+        "  %cp = (f32[1,512]{1,0:T(1,128)S(1)}, f32[1,512]{1,0:T(1,128)S(1)},"
+        " u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%p),"
+        " channel_id=1, source_target_pairs={{0,1},{1,0}}",
+        "  ROOT %d = f32[1,512]{1,0:T(1,128)} collective-permute-done(%cp)",
+        "}",
+    ])
+    stats = H.collective_stats(text)
+    assert stats.count_by_op == {"collective-permute": 1}
+    assert stats.bytes_by_op == {"collective-permute": 512 * 4}

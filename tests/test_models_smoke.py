@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_reduced
+from repro.kernels import ops as KO
 from repro.models import transformer as T
 from repro.models.params import tree_materialize, tree_num_params
 
@@ -147,8 +148,14 @@ def test_attention_kernel_routing_matches_jnp(arch):
     cfg, params = _make(arch)
     tokens, kwargs = _inputs(cfg, batch=1, seq=12)
     # at f32 compute dtype the registry's oracle path and the in-layer
-    # einsum path are the same math in the same dtype: exact agreement
-    # (bf16 differs legitimately — the kernel path keeps attention in f32)
+    # einsum path are the same math in the same dtype, but not the same
+    # reduction order (grouped-head einsum vs the oracle's own contraction
+    # and softmax fusion), so they agree to f32 rounding rather than
+    # bitwise: a few ulps per op, compounded through the layers' residual
+    # adds. The bound is the registry's f32 attention tolerance, the one
+    # every attention backend is held to for ONE attention output, scaled
+    # by the number of routed self-attention layers the logits pass through
+    # (the same rule as the paged-decode parity in tests/test_serve.py).
     # base must be the in-layer einsum EXPLICITLY: the config default is
     # 'auto' now, which on CPU already resolves to the registry oracle
     cfg32 = dataclasses.replace(
@@ -159,8 +166,11 @@ def test_attention_kernel_routing_matches_jnp(arch):
         dataclasses.replace(cfg32, attention_kernel="off"), params, tokens,
         **kwargs,
     )
+    tol = KO.get_kernel("flash_attention").tolerance(jnp.float32)
+    depth = cfg.n_layers + cfg.n_encoder_layers
     np.testing.assert_allclose(
-        np.asarray(ref), np.asarray(base), rtol=1e-6, atol=1e-6
+        np.asarray(ref), np.asarray(base),
+        rtol=tol.rtol * depth, atol=tol.atol * depth,
     )
     # oracle vs the Pallas interpreter through the same routing
     interp = T.forward(

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.kernels import ops
 
@@ -152,7 +151,7 @@ def test_sparse_axpy_f64_interpret_is_bit_exact():
     """
     tol = ops.get_kernel("sparse_axpy").tolerance(jnp.float64)
     assert (tol.rtol, tol.atol) == (0.0, 0.0)
-    with enable_x64():
+    with jax.enable_x64(True):
         args, kw = _example_args(
             "sparse_axpy", jax.random.PRNGKey(1), jnp.float64, small=False
         )
@@ -165,7 +164,7 @@ def test_sparse_axpy_f64_interpret_is_bit_exact():
 def test_sparse_dot_f64_interpret_meets_policy_with_kernel_kwargs():
     """f64 oracle stays f64 (1e-12 policy is meetable), and kernel-only
     kwargs (block_d) are stripped before the oracle leg runs."""
-    with enable_x64():
+    with jax.enable_x64(True):
         args, _ = _example_args(
             "sparse_dot", jax.random.PRNGKey(5), jnp.float64, small=False
         )
@@ -190,7 +189,7 @@ def test_wrapper_axpy_interpret_defaults_to_input_dtype():
     """compute_dtype is resolved in ONE place (the registry adapter):
     interpret -> psi.dtype, so f64 inputs give bit-exact oracles without
     call sites re-deriving the dtype."""
-    with enable_x64():
+    with jax.enable_x64(True):
         args, _ = _example_args(
             "sparse_axpy", jax.random.PRNGKey(2), jnp.float64
         )

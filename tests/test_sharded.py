@@ -97,9 +97,9 @@ def test_sharded_comm_rejects_off_graph_matrix():
 
 
 def test_sharded_comm_requires_node_axis_mesh():
-    import jax
+    from repro.launch.mesh import make_test_mesh
 
     graph = mixing.ring_graph(4)
-    mesh = jax.make_mesh((1,), ("pod",), devices=np.asarray(jax.devices()[:1]))
+    mesh = make_test_mesh((1,), ("pod",))
     with pytest.raises(ValueError, match="'node' mesh axis"):
         ShardedComm(graph, mesh)
