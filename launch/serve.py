@@ -73,6 +73,9 @@ def main():
     print(f"peak active slots: {stats.peak_active}/{args.max_batch}  "
           f"peak pool occupancy: {stats.peak_occupancy:.2f}  "
           f"preemptions: {stats.preemptions}")
+    waits = [r.admitted_s - r.submitted_s for r in stats.requests.values()]
+    print(f"queue wait (submit to admission) median: "
+          f"{1e3 * float(np.median(waits)):.1f} ms")
     print(f"jit traces (frozen after warmup): {sch.trace_counts}")
     for r in reqs[:2]:
         print(f"  request[{r.rid}] generated ids: {results[r.rid][:12]} ...")

@@ -34,6 +34,7 @@ in docs/solvers.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Mapping
 
@@ -43,6 +44,7 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import reference, runner_cache
 from repro.core.comm import (
     DenseComm,
@@ -1522,6 +1524,18 @@ def _ckpt_meta(method: str, comm: str, record_every: int, rec) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _spanned(fn):
+    """Wrap ``solve`` in the profiler span ``repro.solve``."""
+
+    @functools.wraps(fn)
+    def solve(problem, method="dsba", comm="dense", **kwargs):
+        with obs.span("solve", method=method, comm=comm):
+            return fn(problem, method, comm, **kwargs)
+
+    return solve
+
+
+@_spanned
 def solve(
     problem: Problem,
     method: str = "dsba",
@@ -1758,8 +1772,9 @@ def solve(
         t0 = time.perf_counter()
         sres = spec.sparse_run(problem, hp, steps, indices, z0, opts)
         wall = time.perf_counter() - t0
-        for pt in pts:
-            rec.push(pt, sres.z_trace[pt])
+        with obs.span("solve.readout"):
+            for pt in pts:
+                rec.push(pt, sres.z_trace[pt])
         iters, dist2, cons, zs = rec.arrays()
         sel = np.asarray(pts) - 1
         extras = {
@@ -1834,10 +1849,12 @@ def solve(
             prev = 0
             z_final = None
             for pt in pts:
-                state = runner.chunk(state, idx_j[prev:pt], hp_dyn)
+                with obs.span("solve.run"):
+                    state = runner.chunk(state, idx_j[prev:pt], hp_dyn)
                 prev = pt
-                z_final = runner.z_read(state, hp_dyn)
-                rec.push(pt, z_final)
+                with obs.span("solve.readout"):
+                    z_final = runner.z_read(state, hp_dyn)
+                    rec.push(pt, z_final)
             wall = time.perf_counter() - t0
             iters, dist2, cons, zs = rec.arrays()
             per_node = dense_doubles_per_iter(problem.graph, D)  # (N,)
@@ -1961,10 +1978,12 @@ def solve(
     for pt in pts:
         if pt <= start:
             continue  # already covered by the restored checkpoint
-        state = runner.chunk(state, idx_j[prev:pt], hp_dyn)
+        with obs.span("solve.run"):
+            state = runner.chunk(state, idx_j[prev:pt], hp_dyn)
         prev = pt
-        z_final = runner.z_read(state, hp_dyn)
-        rec.push(pt, z_final)
+        with obs.span("solve.readout"):
+            z_final = runner.z_read(state, hp_dyn)
+            rec.push(pt, z_final)
         if mgr is not None and pt % checkpoint.every == 0:
             mgr.save(
                 pt, {"state": state},

@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import runner_cache
 from repro.core.dsba import DSBAConfig, init_state, make_step_fn
 from repro.core.mixing import Graph, w_tilde
@@ -627,8 +628,10 @@ def _run_vectorized(
         return xs
 
     if ckpt_every is None and resume is None:
-        carry_f, (zs, nnzs) = scan(carry0, seg_xs(0, steps), mix0, hp)
-        zs, nnzs = np.asarray(zs), np.asarray(nnzs)
+        with obs.span("solve.run"):
+            carry_f, (zs, nnzs) = scan(carry0, seg_xs(0, steps), mix0, hp)
+        with obs.span("solve.readout"):
+            zs, nnzs = np.asarray(zs), np.asarray(nnzs)
     else:
         # chunked execution of the SAME cached scan: absolute iteration
         # numbers ride in the xs, so chunk boundaries are invisible to
@@ -671,7 +674,8 @@ def _run_vectorized(
         raise ProtocolViolation(
             "relay schedule consumed a value before its arrival"
         )
-    z_trace = np.concatenate([np.asarray(z0)[None], zs])
+    with obs.span("solve.readout"):
+        z_trace = np.concatenate([np.asarray(z0)[None], zs])
     doubles, ints = _closed_form_costs(
         nnzs, tb.dist, tail, D, restart=restart, sent=sent_mask
     )

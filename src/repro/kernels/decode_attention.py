@@ -187,6 +187,7 @@ def decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dp), q.dtype),
+        name="decode_attention",
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), qp, kp, vp)
     return out[..., :D]

@@ -215,6 +215,7 @@ def flash_attention_fwd(
             jax.ShapeDtypeStruct((B, Hq, Sp, D), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, Sp, 1), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qp, kp, vp)
     o = o[:, :, :S]
@@ -412,6 +413,7 @@ def flash_attention_bwd(
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sp, D), jnp.float32),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, deltap)
 
@@ -434,6 +436,7 @@ def flash_attention_bwd(
             jax.ShapeDtypeStruct((B, Hq, Skp, D), jnp.float32),
             jax.ShapeDtypeStruct((B, Hq, Skp, D), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(qp, kp, vp, dop, lsep, deltap)
 

@@ -87,6 +87,7 @@ def sparse_dot(
         ],
         out_specs=pl.BlockSpec((1,), lambda n, j: (n,)),
         out_shape=jax.ShapeDtypeStruct((N,), compute_dtype),
+        name="saga_sparse_dot",
         interpret=interpret,
     )(psi, idx.astype(jnp.int32), val)
 
@@ -169,6 +170,7 @@ def sparse_axpy(
         ],
         out_specs=pl.BlockSpec((N, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((N, D), psi.dtype),
+        name="saga_sparse_axpy",
         interpret=interpret,
     )(
         psi, idx.astype(jnp.int32)[..., None], val[..., None],

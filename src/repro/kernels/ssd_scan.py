@@ -109,6 +109,7 @@ def ssd_chunk_fwd(
             jax.ShapeDtypeStruct((B, nc, Q, nh, hd), jnp.float32),
             jax.ShapeDtypeStruct((B, nc, nh, ds, hd), jnp.float32),
         ],
+        name="ssd_chunk",
         interpret=interpret,
     )(xdt, cum, Bc, Cc)
     return y, st
@@ -223,6 +224,7 @@ def ssd_chunk_bwd(
             jax.ShapeDtypeStruct((B, nc, Q, ds), jnp.float32),
             jax.ShapeDtypeStruct((B, nc, Q, ds), jnp.float32),
         ],
+        name="ssd_chunk_bwd",
         interpret=interpret,
     )(xdt, cum, Bc, Cc, dy, dst)
     return (

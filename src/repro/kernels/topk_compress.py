@@ -57,5 +57,6 @@ def block_topk(
             jax.ShapeDtypeStruct((nb, k), x.dtype),
             jax.ShapeDtypeStruct((nb, k), jnp.int32),
         ],
+        name="block_topk",
         interpret=interpret,
     )(x)

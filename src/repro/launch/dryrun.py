@@ -13,8 +13,7 @@ production mesh, and record:
 
 Single-pod mesh = (16, 16) ('data','model'); multi-pod = (2, 16, 16) with
 the 'pod' axis running the paper's decentralized gossip step (train) or
-pod-sharded batch (serve). Results land in experiments/dryrun/*.json;
-benchmarks/roofline.py renders EXPERIMENTS.md tables from them.
+pod-sharded batch (serve). Results land in experiments/dryrun/*.json.
 
 Usage:
   python -m repro.launch.dryrun --arch minitron-8b --shape train_4k --mesh single

@@ -39,7 +39,10 @@ class TracedJit:
 
     The counter increments inside the traced function — a Python side
     effect that only fires at trace time — so ``traces`` is exactly the
-    number of compilations this instance has triggered.
+    number of compilations this instance has triggered. The compiled
+    program is named after ``fn`` (``jit_prefill``, ``jit_decode_step_paged``;
+    a ``functools.partial`` by the function it wraps), so a profile shows
+    which program ran.
     """
 
     def __init__(self, fn, **jit_kwargs):
@@ -49,6 +52,7 @@ class TracedJit:
             self.traces += 1
             return fn(*args, **kwargs)
 
+        counted.__name__ = getattr(fn, "__name__", None) or fn.func.__name__
         self._fn = jax.jit(counted, **jit_kwargs)
 
     def __call__(self, *args, **kwargs):

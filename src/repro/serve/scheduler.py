@@ -19,17 +19,22 @@ thrashes forever.
 
 Per-step counters (queue depth, active slots, pool occupancy,
 admissions/evictions/preemptions, tokens generated) accumulate in a
-``ServeStats`` record for benchmarks and tests.
+``ServeStats`` record, beside one ``RequestRecord`` per request (when it
+was submitted, admitted, produced its first token and finished).
+``step()`` and each admission's prefill are wrapped in profiler spans
+(``repro.obs``; docs/serving.md).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from collections import deque
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.serve.cache import CachePool, PoolConfig, TracedJit
@@ -64,17 +69,38 @@ class StepStats:
 
 
 @dataclasses.dataclass
+class RequestRecord:
+    """One request's life, in ``time.perf_counter()`` seconds.
+
+    ``admitted_s`` is the start of the first prefill that admitted the
+    request and ``first_token_s`` the moment its first token was sampled
+    then: a preempted request keeps both and counts ``preemptions`` (its
+    regenerated tokens are the same under greedy sampling). ``None``
+    until the event happens.
+    """
+
+    submitted_s: float
+    admitted_s: float | None = None
+    first_token_s: float | None = None
+    finished_s: float | None = None
+    preemptions: int = 0
+
+
+@dataclasses.dataclass
 class ServeStats:
     """Per-step counter trace for a scheduler run.
 
     ``preempt_counts`` maps request id -> how many times that request was
     preempted over the run (the starvation-guard witness: no entry may
     exceed ``Scheduler.max_preempts`` unless the oldest-first fallback had
-    no non-exempt victim left).
+    no non-exempt victim left). ``requests`` maps request id -> its
+    ``RequestRecord``; queue wait is ``admitted_s - submitted_s``.
     """
 
     steps: list[StepStats] = dataclasses.field(default_factory=list)
     preempt_counts: dict[int, int] = dataclasses.field(default_factory=dict)
+    requests: dict[int, RequestRecord] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def total_tokens(self) -> int:
@@ -129,7 +155,7 @@ class Scheduler:
         self._prefill = TracedJit(functools.partial(T.prefill, cfg))
         self._decode = TracedJit(functools.partial(T.decode_step_paged, cfg))
         self._encode = TracedJit(
-            lambda p, e: T.encode_cross_cache(cfg, p, e, 1)
+            functools.partial(T.encode_cross_cache, cfg, batch=1)
         )
 
     @property
@@ -156,6 +182,7 @@ class Scheduler:
         if self.cfg.family == "encdec" and req.enc_embeds is None:
             raise ValueError("encdec requests need enc_embeds")
         self.queue.append(req)
+        self.stats.requests[req.rid] = RequestRecord(time.perf_counter())
 
     # -- sampling -----------------------------------------------------------
 
@@ -171,6 +198,7 @@ class Scheduler:
         st = self.active.pop(slot)
         self._admit_order.remove(slot)
         self.results[st.req.rid] = np.asarray(st.generated, np.int32)
+        self.stats.requests[st.req.rid].finished_s = time.perf_counter()
         self.pool.release(slot)
 
     def _admit_one(self) -> bool:
@@ -184,28 +212,35 @@ class Scheduler:
             return False
         self.queue.popleft()
         pc = self.pool.pc
+        record = self.stats.requests[req.rid]
+        if record.admitted_s is None:
+            record.admitted_s = time.perf_counter()
 
-        padded = np.zeros((1, pc.prompt_pad), np.int64)
-        padded[0, :plen] = np.asarray(req.tokens)
-        cache = T.init_cache(self.cfg, 1, pc.prompt_pad)
-        if self.cfg.family == "encdec":
-            cache["cross"] = self._encode(
-                self.params, jnp.asarray(req.enc_embeds)[None]
+        with obs.span("serve.prefill", rid=req.rid, prompt_len=plen):
+            padded = np.zeros((1, pc.prompt_pad), np.int64)
+            padded[0, :plen] = np.asarray(req.tokens)
+            cache = T.init_cache(self.cfg, 1, pc.prompt_pad)
+            if self.cfg.family == "encdec":
+                cache["cross"] = self._encode(
+                    self.params, jnp.asarray(req.enc_embeds)[None]
+                )
+            cache, logits = self._prefill(
+                self.params, jnp.asarray(padded), cache,
+                valid_len=jnp.asarray([plen], jnp.int32),
             )
-        cache, logits = self._prefill(
-            self.params, jnp.asarray(padded), cache,
-            valid_len=jnp.asarray([plen], jnp.int32),
-        )
-        self.pool.write_prefill(slot, cache)
-        self.pool.set_length(slot, plen)
+            self.pool.write_prefill(slot, cache)
+            self.pool.set_length(slot, plen)
 
-        # the prefill logits already yield the first generated token: a
-        # decode step per NEW token, not per request token
-        g0 = self._sample(np.asarray(logits)[0])
+            # the prefill logits already yield the first generated token:
+            # a decode step per NEW token, not per request token
+            g0 = self._sample(np.asarray(logits)[0])
+        if record.first_token_s is None:
+            record.first_token_s = time.perf_counter()
         target = min(req.max_new_tokens, pc.max_len - plen + 1)
         st = _Active(req, [g0], target)
         if target <= 1:
             self.results[req.rid] = np.asarray(st.generated, np.int32)
+            record.finished_s = time.perf_counter()
             self.pool.release(slot)
             return True
         self.active[slot] = st
@@ -250,6 +285,7 @@ class Scheduler:
         self.stats.preempt_counts[rid] = (
             self.stats.preempt_counts.get(rid, 0) + 1
         )
+        self.stats.requests[rid].preemptions += 1
         return True
 
     def _ensure_capacity(self) -> int:
@@ -275,32 +311,39 @@ class Scheduler:
     def step(self) -> StepStats:
         """Admit, ensure capacity (preempting if needed), decode one
         token for every active slot, evict finished sequences."""
+        with obs.span("serve.step", step=self._step_idx):
+            return self._step()
+
+    def _step(self) -> StepStats:
         admitted = self._admit()
         preempted = self._ensure_capacity()
         finished = 0
         tokens_generated = 0
 
         if self.active:
-            pools, logits = self._decode(
-                self.params,
-                jnp.array(self._cur_tok),  # a copy: mutated below
-                self.pool.pools,
-                self.pool.device_table(),
-                self.pool.device_lengths(),
-            )
-            self.pool.pools = pools
-            logits_np = np.asarray(logits)
-            slots = list(self._admit_order)
-            self.pool.bump_lengths(slots)
-            for slot in slots:
-                st = self.active[slot]
-                nxt = self._sample(logits_np[slot])
-                st.generated.append(nxt)
-                self._cur_tok[slot, 0] = nxt
-                tokens_generated += 1
-                if len(st.generated) >= st.target:
-                    self._finish(slot)
-                    finished += 1
+            with obs.span("serve.decode"):
+                pools, logits = self._decode(
+                    self.params,
+                    jnp.array(self._cur_tok),  # a copy: mutated below
+                    self.pool.pools,
+                    self.pool.device_table(),
+                    self.pool.device_lengths(),
+                )
+                self.pool.pools = pools
+            with obs.span("serve.fetch"):
+                logits_np = np.asarray(logits)
+            with obs.span("serve.sample"):
+                slots = list(self._admit_order)
+                self.pool.bump_lengths(slots)
+                for slot in slots:
+                    st = self.active[slot]
+                    nxt = self._sample(logits_np[slot])
+                    st.generated.append(nxt)
+                    self._cur_tok[slot, 0] = nxt
+                    tokens_generated += 1
+                    if len(st.generated) >= st.target:
+                        self._finish(slot)
+                        finished += 1
 
         stats = StepStats(
             step=self._step_idx,

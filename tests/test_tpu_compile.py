@@ -4,7 +4,9 @@ Nothing runs: each kernel is lowered at real widths against a *described*
 v5e chip (``jax.experimental.topologies``) and compiled by the TPU's own
 compiler, which refuses what interpret mode accepts — block shapes off the
 (8, 128) tiling, dtypes Mosaic lacks, dot forms it cannot lower. Each test
-asserts the compiled program holds the kernel as a ``tpu_custom_call``.
+asserts the compiled program holds the kernel as a ``tpu_custom_call``
+named as the kernel's ``pallas_call`` names it: a profile of the chip shows
+that name, whichever function wraps the kernel.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -59,6 +61,16 @@ def _compiled_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_names(text: str) -> set[str]:
+    """Instruction names, without their instance numbers, of the kernel
+    custom calls in compiled HLO text."""
+    return {
+        line.split(" = ", 1)[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+        for line in text.splitlines()
+        if " custom-call(" in line and KERNEL_MARK in line
+    }
+
+
 def test_decode_attention_compiles_at_minitron_8b_widths(one_chip):
     # minitron_8b: 32 query heads over 8 kv heads, head_dim 128; the paged
     # pool at the serving smoke's page size, 8 slots of 33 pages each
@@ -73,6 +85,7 @@ def test_decode_attention_compiles_at_minitron_8b_widths(one_chip):
         ((B,), jnp.int32),
     )
     assert KERNEL_MARK in text
+    assert _kernel_names(text) == {"decode_attention"}
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -81,12 +94,15 @@ def test_flash_attention_compiles_at_seq_2048(one_chip, direction):
     kv = ((1, 8, 2048, 128), jnp.bfloat16)
     if direction == "fwd":
         text = _compiled_text(flash_attention_fwd, one_chip, q, kv, kv)
+        names = {"flash_attention"}
     else:
         lse = ((1, 32, 2048), jnp.float32)
         text = _compiled_text(
             flash_attention_bwd, one_chip, q, kv, kv, q, lse, q
         )
+        names = {"flash_bwd_dq", "flash_bwd_dkv"}
     assert KERNEL_MARK in text
+    assert _kernel_names(text) == names
 
 
 def test_sparse_axpy_compiles_at_rcv1_shape(one_chip):
@@ -102,6 +118,7 @@ def test_sparse_axpy_compiles_at_rcv1_shape(one_chip):
         ((N,), jnp.float32),
     )
     assert KERNEL_MARK in text
+    assert _kernel_names(text) == {"saga_sparse_axpy"}
 
 
 def test_sharded_solver_chunk_measures_permutes_on_four_chips(topo):
