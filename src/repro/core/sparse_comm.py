@@ -31,15 +31,25 @@ The eq. 28 recursion is the SAME affine map for every (observer, source)
 pair, so the simulator batches it instead of looping in Python:
 
 * **Ring-buffer reconstruction.** Per-pair stores keep only the last
-  ``diameter + 2`` reconstructed iterates, ``R[s % depth, u, l] =`` node u's
-  copy of ``z_l^s`` — O(N^2 * diam * d) memory instead of the previous
-  O(N^2 * T * d) NaN-filled array. Dense per-source deltas live in a matching
-  ``(depth, N, D)`` ring.
+  ``diameter + 2`` reconstructed iterates — O(N^2 * diam * d) memory
+  instead of the previous O(N^2 * T * d) NaN-filled array. Each (observer,
+  source) pair owns one row per slot: the N self pairs first (row u holds
+  node u's own iterate), then one block per distance level xi = 1..dmax
+  with exactly that level's pairs (``_Tables.row``/``start``). Slots follow
+  one another along the ring's one row axis, ``R[(s % depth) * n_rows +
+  row(u, l)] =`` node u's copy of ``z_l^s``, and a row is the vector as
+  whole (8, 128) tiles (``_to_rows``), so every read is a row gather in the
+  ring's own layout with only the slot traced, and every write is one
+  contiguous block in place. Dense per-source deltas live in a matching
+  ``depth * N``-row ring.
 * **Distance waves.** At iteration t, pair (u, l) at distance xi advances by
-  exactly one state, ``s = t + 1 - xi``. Pairs are grouped by distance and
-  advanced farthest-first (the paper's V_j ordering) so a distance-xi pair
-  can consume the value its distance-(xi+1) neighbor produced this same
-  iteration. Each wave is one batched gather + fused AXPY over all its pairs.
+  exactly one state, ``s = t + 1 - xi``. The levels are unrolled (dmax is
+  static) and advanced farthest-first (the paper's V_j ordering) so a
+  distance-xi pair can consume the value its distance-(xi+1) neighbor
+  produced this same iteration. Each level gathers its pairs' neighbour
+  rows at slots s-1 and s-2, applies the fused AXPY and writes its block
+  at slot s; the one-time dense z^1 flood is the block's value at
+  t == xi, and before it (warm-up) the block keeps its old rows.
 * **Single XLA program.** The whole run — warm-up flood, waves, mixing rows,
   and the shared local update (core.dsba.make_step_fn) — is one jitted
   ``lax.scan``; per-iteration state never round-trips through NumPy.
@@ -109,15 +119,33 @@ class SparseRunResult:
 class _Tables:
     """Static per-graph tables for the vectorized engine (the reference
     engine keeps its own inline dist/neighbor bookkeeping, verbatim from the
-    original loop, so the parity oracle stays independent)."""
+    original loop, so the parity oracle stays independent).
 
-    dist: np.ndarray  # (N, N) BFS distances xi
+    The reconstruction ring gives each (observer, source) pair one row per
+    slot: the N self pairs first (row u is pair (u, u)), then one block
+    per distance level xi = 1..dmax, holding that level's pairs in
+    ``pairs[xi]`` order from row ``start[xi]`` on.
+    """
+
+    dist: np.ndarray  # (N, N) BFS distances xi (-1: unreachable)
     nbr_pad: np.ndarray  # (N, A) sorted neighbors + self, padded with self
     wt_pad: np.ndarray  # (N, A) matching W~ weights (0 on padding)
     pad_mask: np.ndarray  # (N, A) True on real entries
     pairs: dict[int, tuple[np.ndarray, np.ndarray]]  # xi -> (obs, src)
+    row: np.ndarray  # (N, N) ring row of pair (u, l); -1 if unreachable
+    start: dict[int, int]  # xi -> first ring row of its block
+    n_rows: int  # ring rows per slot: N self rows + every level's pairs
     dmax: int
     depth: int  # ring-buffer depth = diameter + 2
+
+    @property
+    def row_src(self) -> np.ndarray:
+        """(n_rows,) the source node of each ring row."""
+        src = np.empty(self.n_rows, np.int32)
+        src[: len(self.dist)] = np.arange(len(self.dist))
+        for xi, (_, l_xi) in self.pairs.items():
+            src[self.start[xi]: self.start[xi] + len(l_xi)] = l_xi
+        return src
 
 
 def _protocol_tables(graph: Graph, wt: np.ndarray) -> _Tables:
@@ -137,8 +165,15 @@ def _protocol_tables(graph: Graph, wt: np.ndarray) -> _Tables:
     pairs = {
         xi: tuple(np.nonzero(dist == xi)) for xi in range(1, dmax + 1)
     }
-    return _Tables(dist, nbr_pad, wt_pad, pad_mask, pairs, dmax,
-                   depth=max(3, dmax + 2))
+    row = np.full((n, n), -1, dtype=np.int32)
+    row[np.arange(n), np.arange(n)] = np.arange(n)
+    start, nxt = {}, n
+    for xi, (u_xi, l_xi) in pairs.items():
+        start[xi] = nxt
+        row[u_xi, l_xi] = nxt + np.arange(len(u_xi))
+        nxt += len(u_xi)
+    return _Tables(dist, nbr_pad, wt_pad, pad_mask, pairs, row, start,
+                   n_rows=nxt, dmax=dmax, depth=max(3, dmax + 2))
 
 
 def _closed_form_costs(
@@ -294,6 +329,27 @@ def _sparse_scan_key(cfg, data, graph, w, verify, kernel_mode,
     return key, (data,)
 
 
+_LANES = 128
+
+
+def _to_rows(x):
+    """(M, D) vectors -> (M, C, 128) ring rows, zero-padded to C * 128.
+
+    A row of whole (8, 128) TPU tiles lies contiguous in memory, so the
+    scan gathers rows straight from the ring and writes blocks in place;
+    a (rows, D) ring would be staged or re-laid-out for every gather. The
+    padding columns stay zero and meet no real column.
+    """
+    m, d = x.shape
+    c = -(-d // _LANES)
+    return jnp.pad(x, ((0, 0), (0, c * _LANES - d))).reshape(m, c, _LANES)
+
+
+def _from_rows(x, d):
+    """(M, C, 128) ring rows -> (M, d) vectors; inverse of ``_to_rows``."""
+    return x.reshape(x.shape[0], -1)[:, :d]
+
+
 def _build_sparse_scan(cfg, data, graph, w, *, verify, kernel_mode,
                        faulty=False):
     """Compile the whole-run relay scan with (alpha, lam) traced.
@@ -318,35 +374,44 @@ def _build_sparse_scan(cfg, data, graph, w, *, verify, kernel_mode,
     step = make_step_fn(cfg, data, w)
 
     # constants baked into the compiled scan
-    dist_j = jnp.asarray(tb.dist, jnp.int32)
-    nbr_j = jnp.asarray(tb.nbr_pad)
-    wtn_j = jnp.asarray(tb.wt_pad, dt)
-    padm_j = jnp.asarray(tb.pad_mask)
-    iu = jnp.arange(n)
+    n_rows = tb.n_rows
     width = tb.nbr_pad.shape[1]
+    wtn_j = jnp.asarray(tb.wt_pad, dt)
+    # ring rows each read gathers, per slot: the mixing rows read node u's
+    # copies of its neighbours; a level-xi pair (u, l) reads u's copies of
+    # l's neighbours. Only the slot is traced.
+    mix_rows_idx = tb.row[np.arange(n)[:, None], tb.nbr_pad]  # (N, A)
+    levels = []  # farthest-first (paper's V_j ordering)
+    for xi in range(dmax, 0, -1):
+        u_xi, l_xi = tb.pairs[xi]
+        levels.append((
+            xi,
+            tb.start[xi],
+            len(u_xi),
+            tb.row[u_xi[:, None], tb.nbr_pad[l_xi]],  # (P, A)
+            l_xi,
+        ))
 
-    # padded per-distance pair tables for the wave scan: row i holds the
-    # (observer, source) pairs at distance xi = dmax - i, padded to the
-    # widest level with masked (0, 0) entries.
-    if dmax > 0:
-        pmax = max(len(u) for u, _ in tb.pairs.values())
-        xis = np.arange(dmax, 0, -1, dtype=np.int32)
-        up_t = np.zeros((dmax, pmax), np.int32)
-        lp_t = np.zeros((dmax, pmax), np.int32)
-        real_t = np.zeros((dmax, pmax), bool)
-        for i, xi in enumerate(xis):
-            u_xi, l_xi = tb.pairs[int(xi)]
-            up_t[i, : len(u_xi)] = u_xi
-            lp_t[i, : len(l_xi)] = l_xi
-            real_t[i, : len(u_xi)] = True
-        wave_xs = (
-            jnp.asarray(xis),
-            jnp.asarray(up_t),
-            jnp.asarray(lp_t),
-            jnp.asarray(real_t),
+    def rows(ring, slot, idx, per_slot):
+        """Rows ``idx`` (static) of ring slot ``slot`` (traced)."""
+        return ring.at[slot * per_slot + jnp.asarray(idx, jnp.int32)].get(
+            mode="promise_in_bounds"
         )
-    else:
-        wave_xs = None
+
+    def lead(ring, row):
+        return (row,) + (jnp.int32(0),) * (ring.ndim - 1)
+
+    def block(ring, slot, first, size):
+        """The ``size`` contiguous rows of slot ``slot`` from ``first``."""
+        return jax.lax.dynamic_slice(
+            ring, lead(ring, slot * n_rows + first), (size,) + ring.shape[1:]
+        )
+
+    def put(ring, slot, first, new, per_slot=n_rows):
+        """Write ``new`` over rows ``first..`` of ring slot ``slot``."""
+        return jax.lax.dynamic_update_slice(
+            ring, new, lead(ring, slot * per_slot + first)
+        )
 
     def densify_delta(st) -> jax.Array:
         """(N, D) dense delta rows from the padded-CSR delta of this step."""
@@ -363,9 +428,11 @@ def _build_sparse_scan(cfg, data, graph, w, *, verify, kernel_mode,
 
     def neighborhood_sum(g_cur, g_prev, wts):
         """sum_m wt[.,m] * (2 z_m^s - z_m^{s-1}), reference add order."""
-        acc = jnp.zeros(g_cur.shape[::2], dt)  # (P, D)
+        acc = jnp.zeros(g_cur.shape[:1] + g_cur.shape[2:], dt)  # (P, C, L)
         for a in range(width):
-            acc = acc + wts[:, a, None] * (2.0 * g_cur[:, a] - g_prev[:, a])
+            acc = acc + wts[:, a, None, None] * (
+                2.0 * g_cur[:, a] - g_prev[:, a]
+            )
         return acc
 
     def scan_all(carry0, xs, mix0, hp):
@@ -384,89 +451,64 @@ def _build_sparse_scan(cfg, data, graph, w, *, verify, kernel_mode,
             t, i_t = xs
         jt = t % depth
         jtm1 = (t - 1) % depth
-        z_t = state.z
+        z_t = _to_rows(state.z)
 
         # -- own history: z^t is exact and free (computed locally last step)
-        R = R.at[jt, iu, iu].set(z_t)
+        R = put(R, jt, 0, z_t)
         if verify:
-            SR = SR.at[jt, iu, iu].set(t)
-            Z = Z.at[jt].set(z_t)
+            SR = put(SR, jt, 0, jnp.full((n,), t, jnp.int32))
+            Z = put(Z, jt, 0, z_t, per_slot=n)
         z1 = jnp.where(t == 1, z_t, z1)
 
-        # -- one-time dense z^1 warm-up flood arrives at t == xi ------------
-        def flood(ops):
-            R_, SR_ = ops
-            mask = dist_j == t
-            R_ = R_.at[1].set(
-                jnp.where(mask[:, :, None], z1[None, :, :], R_[1])
-            )
-            if verify:
-                SR_ = SR_.at[1].set(jnp.where(mask, 1, SR_[1]))
-            return R_, SR_
-
-        R, SR = jax.lax.cond(
-            (t >= 1) & (t <= dmax), flood, lambda ops: ops, (R, SR)
-        )
-
         # -- reconstruction waves, farthest-first (paper's V_j ordering) ----
-        # One inner scan over distance levels xi = dmax..1: every pair at
-        # distance xi advances by exactly one reconstructed state,
-        # s = t + 1 - xi. Warm-up (t <= xi) and row padding are handled by
-        # masking the write: reads of not-yet-valid slots hit
-        # zero-initialized memory (finite), and the value is discarded.
-        def wave(wc, wx):
-            R_, SR_, err_, ok_ = wc
-            xi, up, lp, real = wx
+        # Every pair at distance xi advances by exactly one reconstructed
+        # state, s = t + 1 - xi, written as one block of the ring. The
+        # one-time dense z^1 flood is that block's value at t == xi
+        # (s == 1); before it (warm-up) the block keeps its old value.
+        # Reads of not-yet-valid slots hit zero-initialized memory
+        # (finite), and the value is discarded.
+        for xi, first, size, nb_idx, lp in levels:
             s = t + 1 - xi
             j1, j2, jn = (s - 1) % depth, (s - 2) % depth, s % depth
-            m_idx = nbr_j[lp]  # (P, A)
-            G1 = R_[j1, up[:, None], m_idx]  # (P, A, D) one fused gather
-            G2 = R_[j2, up[:, None], m_idx]
+            G1 = rows(R, j1, nb_idx, n_rows)  # (P, A, C, L)
+            G2 = rows(R, j2, nb_idx, n_rows)
             mix = neighborhood_sum(G1, G2, wtn_j[lp])
-            corr = alpha * (scale * DD[j2, lp] - DD[j1, lp])
-            self1 = R_[j1, up, lp]
+            corr = alpha * (scale * rows(DD, j2, lp, n) - rows(DD, j1, lp, n))
+            self1 = block(R, j1, first, size)
             if cfg.method == "dsba":
                 new = (mix + alpha * lam * self1 + corr) / (1.0 + alpha * lam)
             else:  # dsa
-                self2 = R_[j2, up, lp]
+                self2 = block(R, j2, first, size)
                 new = mix + corr - alpha * lam * (self1 - self2)
-            write = real & (t >= xi + 1)  # (P,)
-            new = jnp.where(write[:, None], new, R_[jn, up, lp])
-            R_ = R_.at[jn, up, lp].set(new)
+            new = jnp.where(t == xi, z1[lp], new)
+            new = jnp.where(t >= xi, new, block(R, jn, first, size))
+            R = put(R, jn, first, new)
             if verify:
-                S1 = SR_[j1, up[:, None], m_idx]
-                S2 = SR_[j2, up[:, None], m_idx]
+                S1 = rows(SR, j1, nb_idx, n_rows)
+                S2 = rows(SR, j2, nb_idx, n_rows)
                 reads = (S1 == s - 1) & (S2 == s - 2)
-                checked = padm_j[lp] & write[:, None]
-                ok_ &= jnp.all(jnp.where(checked, reads, True))
-                SR_ = SR_.at[jn, up, lp].set(
-                    jnp.where(write, s, SR_[jn, up, lp])
-                )
-                err_ = jnp.maximum(
-                    err_,
-                    jnp.max(
-                        jnp.where(
-                            write[:, None], jnp.abs(new - Z[jn, lp]), 0.0
-                        )
-                    ),
-                )
-            return (R_, SR_, err_, ok_), None
-
-        if dmax > 0:
-            (R, SR, err, ok), _ = jax.lax.scan(
-                wave, (R, SR, err, ok), wave_xs
-            )
+                checked = tb.pad_mask[lp] & (t >= xi + 1)
+                ok &= jnp.all(jnp.where(checked, reads, True))
+                SR = put(SR, jn, first, jnp.where(
+                    t >= xi, jnp.full((size,), s, jnp.int32),
+                    block(SR, jn, first, size),
+                ))
+                err = jnp.maximum(err, jnp.where(
+                    t >= xi + 1,
+                    jnp.max(jnp.abs(new - rows(Z, jn, lp, n))),
+                    0.0,
+                ))
 
         # -- mixing rows from each node's OWN reconstruction store ----------
-        g_cur = R[jt, iu[:, None], nbr_j]  # (N, A, D)
-        g_prev = R[jtm1, iu[:, None], nbr_j]
-        mix_rows = neighborhood_sum(g_cur, g_prev, wtn_j)
+        g_cur = rows(R, jt, mix_rows_idx, n_rows)  # (N, A, C, L)
+        g_prev = rows(R, jtm1, mix_rows_idx, n_rows)
+        mix_rows = _from_rows(neighborhood_sum(g_cur, g_prev, wtn_j), D)
         mix_rows = jnp.where(t == 0, mix0, mix_rows)
         if verify:
-            s_cur = SR[jt, iu[:, None], nbr_j]
-            s_prev = SR[jtm1, iu[:, None], nbr_j]
+            s_cur = rows(SR, jt, mix_rows_idx, n_rows)
+            s_prev = rows(SR, jtm1, mix_rows_idx, n_rows)
             ok &= (t == 0) | jnp.all(
-                jnp.where(padm_j, (s_cur == t) & (s_prev == t - 1), True)
+                jnp.where(tb.pad_mask, (s_cur == t) & (s_prev == t - 1), True)
             )
 
         # -- advance all nodes with the shared local update -----------------
@@ -480,39 +522,46 @@ def _build_sparse_scan(cfg, data, graph, w, *, verify, kernel_mode,
             # own row of R stays exact — a node always has its own state.
             dd = jnp.where(sent_t[:, None], dd, jnp.zeros_like(dd))
             nnz_t = jnp.where(sent_t, nnz_t, 0)
-        DD = DD.at[jt].set(dd)
+        DD = put(DD, jt, 0, _to_rows(dd), per_slot=n)
         return (state, z1, R, DD, SR, Z, err, ok), (state.z, nnz_t)
 
     return jax.jit(scan_all), tb
 
 
-def _relay_carry0(cfg, data, z0, depth, verify, state0=None):
+def _relay_carry0(cfg, data, z0, tb, verify, state0=None):
     """The relay scan's initial carry at the shared starting point ``z0``.
 
     With ``state0`` (a schedule-segment restart) the carried solver state is
     used as-is and the reconstruction ring is seeded with its iterates: the
     segment-entry z^0 := state0.z is flooded at segment start (see
     _closed_form_costs), so every observer's store legitimately holds it.
+    Rings hold one slot after another, one ring row per vector (see
+    ``_to_rows``): ``depth * tb.n_rows`` rows of reconstructions, in
+    ``tb.row`` order, and ``depth * N`` rows of dense deltas.
     """
     n = data.n_nodes
-    D = data.d + cfg.spec.tail_dim
     dt = data.val.dtype
+    depth, n_rows = tb.depth, tb.n_rows
     if state0 is not None:
         z0 = state0.z
     else:
         state0 = init_state(cfg, data, jnp.asarray(z0))
-    R0 = jnp.zeros((depth, n, n, D), dt)
-    R0 = R0.at[0].set(jnp.broadcast_to(jnp.asarray(z0, dt), (n, n, D)))
-    DD0 = jnp.zeros((depth, n, D), dt)
+    z0 = _to_rows(jnp.asarray(z0, dt))
+    row = z0.shape[1:]
+    R0 = jnp.zeros((depth * n_rows, *row), dt).at[:n_rows].set(
+        z0[tb.row_src]
+    )
+    DD0 = jnp.zeros((depth * n, *row), dt)
     if verify:
-        SR0 = jnp.full((depth, n, n), -(2**30), jnp.int32).at[0].set(0)
-        Z0 = jnp.zeros((depth, n, D), dt).at[0].set(jnp.asarray(z0, dt))
+        SR0 = jnp.full((depth * n_rows,), -(2**30), jnp.int32)
+        SR0 = SR0.at[:n_rows].set(0)
+        Z0 = jnp.zeros((depth * n, *row), dt).at[:n].set(z0)
     else:  # zero-size placeholders keep the carry structure uniform
         SR0 = jnp.zeros((0,), jnp.int32)
         Z0 = jnp.zeros((0,), dt)
     return (
         state0,
-        jnp.zeros((n, D), dt),  # z^1, captured at t == 1
+        jnp.zeros((n, *row), dt),  # z^1, captured at t == 1
         R0,
         DD0,
         SR0,
@@ -553,6 +602,12 @@ def _carry_from_leaves(carry0, leaves):
     for p, like in zip(paths, tleaves):
         if p not in leaves:
             raise ValueError(f"checkpoint is missing carry leaf {p!r}")
+        if np.shape(leaves[p]) != np.shape(like):
+            raise ValueError(
+                f"checkpoint carry leaf {p!r} has shape "
+                f"{np.shape(leaves[p])}, expected {np.shape(like)}: it was "
+                "written by a relay with another ring layout"
+            )
         new.append(jnp.asarray(leaves[p], getattr(like, "dtype", None)))
     return jax.tree_util.tree_unflatten(treedef, new)["carry"]
 
@@ -598,9 +653,8 @@ def _run_vectorized(
             faulty=faulty,
         ),
     )
-    depth, dmax = tb.depth, tb.dmax
 
-    carry0 = _relay_carry0(cfg, data, z0, depth, verify, state0=state0)
+    carry0 = _relay_carry0(cfg, data, z0, tb, verify, state0=state0)
     ts = jnp.arange(steps, dtype=jnp.int32)
     idx_j = jnp.asarray(indices[:steps], jnp.int32)
     if reanchored:
@@ -748,7 +802,7 @@ def run_sparse_many(
         )),
     )
 
-    carry0 = _relay_carry0(cfg, data, z0, tb.depth, verify)
+    carry0 = _relay_carry0(cfg, data, z0, tb, verify)
     carry0_b = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (B,) + x.shape), carry0
     )

@@ -9,12 +9,14 @@ Pallas-routed delta path), and the legacy reference loop. The `slow`-marked
 sweeps extend the same claims to every task x method x graph combination;
 run them with `pytest -m ""`.
 """
+import re
+
 import numpy as np
 import pytest
 
 from repro.core import mixing
 from repro.core.dsba import draw_indices
-from repro.core.solvers import make_problem, solve
+from repro.core.solvers import make_problem, solve, solve_many
 from repro.core.sparse_comm import (
     dense_doubles_per_iter,
     sparse_doubles_per_iter,
@@ -36,10 +38,41 @@ def _setup(task, n_nodes=6, q=8, d=24, k=4, seed=0, lam=None):
     return make_problem(task, data, graph, lam=lam)
 
 
-def _graph(name, n):
-    return mixing.ring_graph(n) if name == "ring" else mixing.erdos_renyi_graph(
-        n, 0.4, seed=2
+GRAPHS = {
+    "ring": mixing.ring_graph,
+    "erdos_renyi": lambda n: mixing.erdos_renyi_graph(n, 0.4, seed=2),
+    # distance levels of widely different sizes, so each level's block of
+    # the reconstruction ring has its own length: on 7 nodes a path holds
+    # 12, 10, 8, 6, 4 and 2 (observer, source) pairs at distances 1..6, a
+    # star 12 at distance 1 and 30 at distance 2
+    "path": lambda n: mixing.Graph(n, tuple((i, i + 1) for i in range(n - 1))),
+    "star": lambda n: mixing.Graph(n, tuple((0, i) for i in range(1, n))),
+}
+
+
+def _multi_hop_problem(task, gname, n_nodes=7):
+    base = _setup(task, n_nodes=n_nodes, lam=1e-3)
+    return make_problem(task, base.data, GRAPHS[gname](n_nodes), lam=1e-3)
+
+
+def _assert_engines_agree(problem, method):
+    """Vectorized (verified) == reference loop: z_trace, doubles, ints and
+    recon error, over 40 steps."""
+    steps = 40
+    indices = draw_indices(steps, problem.data.n_nodes, problem.data.q,
+                           seed=3)
+    kw = dict(steps=steps, record_every=1, indices=indices, alpha=0.3)
+    ref = solve(problem, method, comm="sparse",
+                comm_options={"engine": "reference"}, **kw)
+    vec = solve(problem, method, comm="sparse",
+                comm_options={"verify": True}, **kw)
+    np.testing.assert_allclose(
+        vec.extras["z_trace"], ref.extras["z_trace"], rtol=0, atol=1e-12
     )
+    assert (vec.doubles_received == ref.doubles_received).all()
+    assert (vec.ints_received == ref.ints_received).all()
+    assert vec.extras["recon_max_err"] < 1e-9
+    assert ref.extras["recon_max_err"] < 1e-9
 
 
 @pytest.fixture(scope="module")
@@ -148,6 +181,61 @@ def test_verify_mode_catches_protocol_violations(shared, monkeypatch):
               alpha=0.3, comm_options={"verify": True, "use_pallas": "off"})
 
 
+@pytest.mark.parametrize("gname", ["path", "star"])
+def test_vectorized_matches_reference_on_uneven_levels(gname):
+    """Engine parity where every distance level is a ring block of its own
+    length (the slow matrix below covers ring and Erdős–Rényi graphs)."""
+    _assert_engines_agree(_multi_hop_problem("ridge", gname), "dsba")
+
+
+def test_batched_relay_verified_on_uneven_levels():
+    """The vmapped relay sweep (run_sparse_many) over the per-level ring
+    blocks, verified: each run bit-equal to its sequential solve."""
+    problem = _multi_hop_problem("ridge", "path")
+    grid = [{"alpha": 0.3}, {"alpha": 0.6}]
+    seeds = [3, 4]
+    kw = dict(steps=24, record_every=8, comm_options={"verify": True})
+    many = solve_many(problem, "dsba", comm="sparse", grid=grid,
+                      seeds=seeds, **kw)
+    assert many.extras["batched"] is True
+    for b, hp in enumerate(grid):
+        seq = solve(problem, "dsba", comm="sparse", seed=seeds[b], **kw,
+                    **hp)
+        run = many.extras["per_run_extras"][b]
+        assert np.array_equal(run["z_trace"], seq.extras["z_trace"])
+        assert np.array_equal(many.doubles_received[b], seq.doubles_received)
+        assert run["recon_max_err"] < 1e-9
+
+
+def test_resume_refuses_a_ring_of_another_layout(tmp_path):
+    """A relay checkpoint whose ring leaf has the (depth, N, N, D) shape of
+    the earlier per-observer layout is refused up front, naming the leaf
+    and the shape the scan carries."""
+    import json
+
+    import repro.core.sparse_comm as sc
+    from repro.ckpt import CheckpointSpec
+
+    problem = _setup("ridge")
+    kw = dict(record_every=5, seed=3, alpha=0.3,
+              comm_options={"use_pallas": "off"})
+    solve(problem, "dsba", comm="sparse", steps=10,
+          checkpoint=CheckpointSpec(tmp_path, every=10), **kw)
+    n, dim = problem.data.n_nodes, problem.dim
+    tb = sc._protocol_tables(problem.graph, mixing.w_tilde(problem.w))
+    ring = "['carry']/[2]"
+    expected = (tb.depth * tb.n_rows, 1, 128)  # D = 24: one 128-lane row
+    saved = tmp_path / "step_10"
+    leaves = json.loads((saved / "manifest.json").read_text())["leaves"]
+    entry = next(e for e in leaves if e["path"] == ring)
+    assert tuple(entry["shape"]) == expected
+    np.save(saved / entry["file"], np.zeros((tb.depth, n, n, dim)))
+    with pytest.raises(ValueError, match=re.escape(ring) + ".*" + re.escape(
+            str(expected))):
+        solve(problem, "dsba", comm="sparse", steps=20, resume=str(tmp_path),
+              **kw)
+
+
 def test_fast_path_reports_nan_recon_err(shared):
     """Without verify= the engine skips truth checking (allocation-lean)."""
     problem = _setup("ridge")
@@ -186,23 +274,7 @@ def test_sparse_comm_trajectory_equals_dense_matrix(task, method):
 @pytest.mark.parametrize("method", ["dsba", "dsa"])
 def test_vectorized_matches_reference_matrix(gname, task, method):
     """Parity on multi-hop topologies: z_trace, doubles, ints, recon err."""
-    base = _setup(task, n_nodes=7, lam=1e-3)
-    graph = _graph(gname, 7)
-    problem = make_problem(task, base.data, graph, lam=1e-3)
-    steps = 40
-    indices = draw_indices(steps, 7, problem.data.q, seed=3)
-    kw = dict(steps=steps, record_every=1, indices=indices, alpha=0.3)
-    ref = solve(problem, method, comm="sparse",
-                comm_options={"engine": "reference"}, **kw)
-    vec = solve(problem, method, comm="sparse",
-                comm_options={"verify": True}, **kw)
-    np.testing.assert_allclose(
-        vec.extras["z_trace"], ref.extras["z_trace"], rtol=0, atol=1e-12
-    )
-    assert (vec.doubles_received == ref.doubles_received).all()
-    assert (vec.ints_received == ref.ints_received).all()
-    assert vec.extras["recon_max_err"] < 1e-9
-    assert ref.extras["recon_max_err"] < 1e-9
+    _assert_engines_agree(_multi_hop_problem(task, gname), method)
 
 
 @pytest.mark.slow

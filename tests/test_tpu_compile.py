@@ -12,6 +12,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file. Where it cannot be described, the fixture skips.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -119,6 +121,103 @@ def test_sparse_axpy_compiles_at_rcv1_shape(one_chip):
     )
     assert KERNEL_MARK in text
     assert _kernel_names(text) == {"saga_sparse_axpy"}
+
+
+_INSTR = re.compile(
+    r"^\s*(ROOT\s+)?%(\S+) = \w+\[([\d,]*)\](?:\{[^}]*\})?\s+([\w-]+)"
+    r"\(([^)]*)\)(?:.*calls=%([\w.-]+))?"
+)
+
+
+def _hlo_ops(text: str) -> dict[str, tuple[bool, list[dict]]]:
+    """Compiled HLO text -> {computation: (is_entry, instructions)}, each
+    instruction with its opcode, result elements, root flag, the largest
+    operand's elements and the computation a fusion calls. Loops are kept
+    too (their result is the carry tuple)."""
+    comps, ops, size = {}, None, {}
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            head = line.split(" (", 1)[0].split()
+            ops, size = [], {}
+            comps[head[-1].lstrip("%")] = (head[0] == "ENTRY", ops)
+        elif ops is None:
+            continue
+        elif m := _INSTR.match(line):
+            root, name, shape, op, args, called = m.groups()
+            size[name] = int(np.prod([int(x) for x in shape.split(",") if x]))
+            operand = max((size.get(a.strip().lstrip("%"), 0)
+                           for a in args.split(",")), default=0)
+            ops.append({"op": op, "elems": size[name], "root": bool(root),
+                        "operand": operand, "calls": called})
+        elif " while(%" in line:
+            ops.append({"op": "while", "elems": 0, "root": False,
+                        "operand": 0, "calls": None})
+    return comps
+
+
+def test_relay_scan_keeps_its_ring_in_place_at_rcv1_shape(one_chip):
+    """The DSBA-s relay scan at ridge_rcv1's shape (N=10 on ER(0.4) with
+    graph seed 0, d=47,236, k=74, float32, the kernel compiled). Outside
+    the entry computation (which seeds the scan once a call) no
+    instruction copies, slices or re-lays-out the reconstruction ring and
+    no loop runs inside the scan: the only results as large as the ring
+    are its block writes, each a dynamic-update-slice in place."""
+    from repro.core import mixing
+    from repro.core import sparse_comm as sc
+    from repro.core.dsba import DSBAConfig
+    from repro.core.solvers import make_problem
+    from repro.data.synthetic import make_regression
+
+    n, d, k, q, steps = 10, 47236, 74, 100, 16
+    data = make_regression(n, q, d, k, seed=0, dtype=np.float32)
+    graph = mixing.erdos_renyi_graph(n, 0.4, seed=0)
+    w = mixing.laplacian_mixing(graph)
+    problem = make_problem("ridge", data, graph, lam=1e-4)
+    cfg = DSBAConfig(spec=problem.spec, alpha=0.5, lam=1e-4)
+    scan, tb = sc._build_sparse_scan(
+        cfg, data, graph, w, verify=False, kernel_mode="on"
+    )
+    carry = jax.eval_shape(lambda: sc._relay_carry0(
+        cfg, data, np.zeros((n, d), np.float32), tb, False
+    ))
+    ring = int(np.prod(carry[2].shape))
+
+    def sds(shape, dtype, weak=False):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip,
+                                    weak_type=weak)
+
+    text = scan.lower(
+        jax.tree.map(lambda x: sds(x.shape, x.dtype), carry),
+        (sds((steps,), jnp.int32), sds((steps, n), jnp.int32)),
+        sds((n, d), jnp.float32),
+        {"alpha": sds((), jnp.float32, True),
+         "lam": sds((), jnp.float32, True)},
+    ).compile().as_text()
+    assert _kernel_names(text) == {"saga_sparse_axpy"}
+
+    comps = _hlo_ops(text)
+    root_op = {c: next((i["op"] for i in ops if i["root"]), None)
+               for c, (_, ops) in comps.items()}
+    loops = [c for c, (_, ops) in comps.items() for i in ops
+             if i["op"] == "while"]
+    assert len(loops) == 1 and comps[loops[0]][0], loops  # the scan's own
+    bad = []
+    for c, (entry, ops) in comps.items():
+        if entry:
+            continue
+        for i in ops:
+            op = i["op"]
+            in_place = op == "dynamic-update-slice" or (
+                op == "fusion"
+                and root_op.get(i["calls"]) == "dynamic-update-slice"
+            )
+            if op in ("copy", "slice", "transpose") and i["operand"] == ring:
+                bad.append((c, op, i["elems"]))  # the ring copied or staged
+            elif i["elems"] == ring and not in_place and op not in (
+                "parameter", "get-tuple-element", "bitcast"
+            ):
+                bad.append((c, op, i["elems"]))  # the ring rebuilt
+    assert not bad, bad
 
 
 def test_sharded_solver_chunk_measures_permutes_on_four_chips(topo):
