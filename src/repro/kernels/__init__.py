@@ -5,6 +5,10 @@
                    tiles recomputed from the saved log-sum-exp)
   ssd_scan         Mamba2/SSD within-chunk compute (MXU blocking),
                    custom_vjp with a chunked backward kernel
+  decode_attention paged single-query decode (GQA), and its latent (MLA)
+                   mode over a paged latent pool
+  expert_matmul    grouped matmul over the experts a chip holds (the
+                   dropless MoE layer)
   sparse_saga      DSBA per-node sparse row update (one-hot gather and
                    scatter — the TPU adaptation, DESIGN.md §5)
   topk_compress    block-local top-k for gossip delta streams

@@ -191,3 +191,142 @@ def decode_attention(
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), qp, kp, vp)
     return out[..., :D]
+
+
+# ---------------------------------------------------------------------------
+# latent mode (MLA decode against a paged latent pool)
+# ---------------------------------------------------------------------------
+
+def _mla_decode_kernel(
+    pair_b_ref, pair_c_ref, table_ref, len_ref, layer_ref, q_ref, *refs,
+    block_size: int, pages_per_step: int, value_dim: int, scale: float,
+):
+    """One (slot, chunk of pages_per_step pages) program instance.
+
+    q_ref (H, R): every head's absorbed query of the slot; refs: the
+    chunk's pages_per_step latent pages (block_size, R) each, then o_ref
+    (H, value_dim), then the online-softmax carry acc/m/l in VMEM. Keys
+    are whole latent rows, values their first value_dim columns: each
+    page is read once."""
+    del table_ref, layer_ref  # read by the index maps
+    pages, o_ref = refs[:pages_per_step], refs[pages_per_step]
+    acc_ref, m_ref, l_ref = refs[pages_per_step + 1:]
+    g = pl.program_id(0)
+    b, c = pair_b_ref[g], pair_c_ref[g]
+    length = len_ref[b]
+    chunk = block_size * pages_per_step
+    last = jnp.maximum((length + chunk - 1) // chunk, 1) - 1
+
+    @pl.when(c == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    q = q_ref[...]
+    for j, page in enumerate(pages):
+        start = c * chunk + j * block_size
+
+        @pl.when(start < length)
+        def _update():
+            kv = page[...]  # (block_size, R)
+            s = jax.lax.dot_general(
+                q, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (H, block_size)
+            pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = pos < length
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_ref[...]
+            m_cur = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+            l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p.astype(kv.dtype), kv[:, :value_dim],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[...] = m_cur
+
+    @pl.when(c == last)
+    def _finalize():
+        l_safe = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+def mla_decode_attention(
+    q: jax.Array,  # (B, H, R) — absorbed queries, one token per slot
+    pool: jax.Array,  # (n_layers, n_blocks, block_size, R) latent pool
+    layer,  # int32 scalar — the layer's index into the pool
+    table: jax.Array,  # (B, n_pages) int32 — pool page ids per slot
+    lengths: jax.Array,  # (B,) int32 — valid tokens incl. the current one
+    *,
+    scale: float,
+    value_dim: int,
+    pages_per_step: int = 4,
+    interpret: bool = False,
+) -> jax.Array:
+    """Paged single-query latent attention -> (B, H, value_dim) in q.dtype.
+
+    Scores are ``scale * q . row`` over every cached latent row of the
+    slot; the output is the softmax-weighted sum of the rows' first
+    ``value_dim`` columns. The grid runs over (slot, chunk) pairs that
+    hold tokens — ``max(ceil(length / chunk), 1)`` per slot, a dynamic
+    count — so a step's work follows the live context, not max_len; an
+    empty slot gets one pass that writes zeros. Each pass reads
+    ``pages_per_step`` pages through the block table, one operand each
+    (the same pool, no copy), and the layer's pages are found in place in
+    the whole pool by the layer index.
+    """
+    B, H, R = q.shape
+    _, _, block_size, R2 = pool.shape
+    assert R == R2, (q.shape, pool.shape)
+    n_pages = table.shape[1]
+    P = pages_per_step
+    chunk = block_size * P
+    n_chunks = -(-n_pages // P)
+    lengths = lengths.astype(jnp.int32)
+    per_slot = jnp.maximum((lengths + chunk - 1) // chunk, 1)
+    ends = jnp.cumsum(per_slot, dtype=jnp.int32)
+    g = jnp.arange(B * n_chunks, dtype=jnp.int32)
+    pair_b = jnp.minimum(jnp.searchsorted(ends, g, side="right"), B - 1)
+    pair_c = g - (ends - per_slot)[pair_b]
+    pair_b, pair_c = pair_b.astype(jnp.int32), pair_c.astype(jnp.int32)
+
+    def page_spec(j):
+        def index(g, pb, pc, t, le, ly):
+            i = jnp.minimum(pc[g] * P + j, n_pages - 1)
+            return ly[0], t[pb[g], i], 0, 0
+        return pl.BlockSpec(
+            (pl.Squeezed(), pl.Squeezed(), block_size, R), index)
+
+    slot_spec = lambda width: pl.BlockSpec(
+        (pl.Squeezed(), H, width), lambda g, pb, pc, t, le, ly: (pb[g], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(ends[-1],),
+        in_specs=[slot_spec(R)] + [page_spec(j) for j in range(P)],
+        out_specs=slot_spec(value_dim),
+        scratch_shapes=[
+            pltpu.VMEM((H, value_dim), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _mla_decode_kernel, block_size=block_size, pages_per_step=P,
+        value_dim=value_dim, scale=scale,
+    )
+    return pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="mla_decode_attention",
+        interpret=interpret,
+    )(pair_b, pair_c, table.astype(jnp.int32), lengths,
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+      q.astype(pool.dtype), *([pool] * P))
+

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import decode_attention as DA
+from repro.kernels import expert_matmul as EM
 from repro.kernels import flash_attention as FA
 from repro.kernels import ref as R
 from repro.kernels import ssd_scan as SSD
@@ -357,6 +358,37 @@ register_kernel(KernelSpec(
 ))
 
 
+def _mla_decode_pallas(q, pool, layer, table, lengths, *, scale, value_dim,
+                      interpret=False):
+    """Registry adapter: the paged latent (MLA) decode launch."""
+    return DA.mla_decode_attention(
+        q, pool, layer, table, lengths, scale=scale, value_dim=value_dim,
+        interpret=interpret,
+    )
+
+
+register_kernel(KernelSpec(
+    name="mla_decode_attention",
+    pallas=_mla_decode_pallas,
+    ref=R.mla_decode_attention_ref,
+    tol={"float32": _F32_TOL, "bfloat16": _BF16_TOL},
+))
+
+
+def _expert_matmul_pallas(x, w, tile_group, n_active, *, tm, interpret=False):
+    """Registry adapter: the held experts' grouped matmul."""
+    return EM.expert_matmul(x, w, tile_group, n_active, tm=tm,
+                            interpret=interpret)
+
+
+register_kernel(KernelSpec(
+    name="expert_matmul",
+    pallas=_expert_matmul_pallas,
+    ref=R.expert_matmul_ref,
+    tol={"float32": _F32_TOL, "bfloat16": _BF16_TOL},
+))
+
+
 def _ssd_pallas(xdt, cum, Bc, Cc, *, head_block=None, interpret=False):
     """Registry adapter: the ssd_chunk custom_vjp wrapper (within-chunk
     forward kernel + chunked backward kernel over the saved residuals).
@@ -458,6 +490,24 @@ def decode_attention(q, k_pool, v_pool, table, lengths, *, window=None,
         "decode_attention", q, k_pool, v_pool, table, lengths,
         window=window, softcap=softcap, use_pallas=use_pallas,
     )
+
+
+@partial(jax.jit, static_argnames=("scale", "value_dim", "use_pallas"))
+def mla_decode_attention(q, pool, layer, table, lengths, *, scale: float,
+                         value_dim: int, use_pallas: str = "auto"):
+    """Registry-dispatched paged latent (MLA) decode attention."""
+    return dispatch(
+        "mla_decode_attention", q, pool, layer, table, lengths,
+        scale=scale, value_dim=value_dim, use_pallas=use_pallas,
+    )
+
+
+@partial(jax.jit, static_argnames=("tm", "use_pallas"))
+def expert_matmul(x, w, tile_group, n_active, *, tm: int,
+                  use_pallas: str = "auto"):
+    """Registry-dispatched grouped matmul over held experts' row tiles."""
+    return dispatch("expert_matmul", x, w, tile_group, n_active, tm=tm,
+                    use_pallas=use_pallas)
 
 
 @partial(jax.jit, static_argnames=("use_pallas",))

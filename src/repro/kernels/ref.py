@@ -101,6 +101,42 @@ def decode_attention_ref(
     return o.reshape(B, Hq, D).astype(q.dtype)
 
 
+def mla_decode_attention_ref(
+    q: jax.Array,  # (B, H, R) absorbed queries
+    pool: jax.Array,  # (n_layers, n_blocks, block_size, R)
+    layer,  # the layer's index into the pool
+    table: jax.Array,  # (B, n_pages) int32
+    lengths: jax.Array,  # (B,) int32 — valid tokens incl. the current one
+    *,
+    scale: float,
+    value_dim: int,
+) -> jax.Array:
+    """Paged latent attention oracle: the layer's pages gathered through
+    the table; keys are whole latent rows, values their first value_dim
+    columns. Empty slots return zeros, as the kernel does."""
+    B = q.shape[0]
+    block_size, R = pool.shape[2], pool.shape[3]
+    L = table.shape[1] * block_size
+    rows = pool[layer][table].reshape(B, L, R).astype(jnp.float32)
+    s = jnp.einsum("bhr,btr->bht", q.astype(jnp.float32), rows) * scale
+    mask = jnp.arange(L)[None, :] < lengths[:, None]
+    s = jnp.where(mask[:, None, :], s, -1e30)
+    p = jnp.where(mask[:, None, :], jax.nn.softmax(s, axis=-1), 0.0)
+    o = jnp.einsum("bht,btc->bhc", p, rows[..., :value_dim])
+    return o.astype(q.dtype)
+
+
+def expert_matmul_ref(x, w, tile_group, n_active, *, tm: int):
+    """Grouped matmul oracle: each tm-row tile of x times its group's
+    matrix; tiles at or past n_active give zeros."""
+    M, K = x.shape
+    nt = M // tm
+    xt = x.reshape(nt, tm, K).astype(jnp.float32)
+    out = jnp.einsum("tmk,tkn->tmn", xt, w[tile_group].astype(jnp.float32))
+    live = (jnp.arange(nt) < n_active)[:, None, None]
+    return jnp.where(live, out, 0.0).reshape(M, -1).astype(x.dtype)
+
+
 def ssd_chunk_ref(xdt, cum, Bc, Cc):
     """Within-chunk SSD: (y_intra, chunk states). Shapes as ssd_chunk_fwd."""
     Q = xdt.shape[2]

@@ -24,7 +24,7 @@ from typing import Any
 import jax.numpy as jnp
 
 
-def _kernel_default() -> str:
+def kernel_mode() -> str:
     """Default use_pallas mode for the kernel-routing knobs.
 
     'auto' (Pallas on TPU, jnp oracle on CPU) unless the REPRO_KERNEL_MODE
@@ -54,7 +54,41 @@ class ModelConfig:
     attn_softcap: float | None = None  # gemma2: 50.0
     final_softcap: float | None = None  # gemma2: 30.0
 
+    # --- latent attention (MLA, DeepSeek-V3 sec. 2.1) -------------------------
+    # kv_lora_rank > 0 turns every layer's attention into MLA: queries from
+    # a q_lora_rank-wide normed latent, keys and values from a
+    # kv_lora_rank-wide normed latent plus one rope key of qk_rope_head_dim
+    # shared by all heads. The serving cache holds that latent row
+    # (kv_lora_rank + qk_rope_head_dim values a token a layer).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (factor 1 = plain rope): frequencies ramp from the
+    # base ones to base / factor between the correction range of
+    # beta_fast / beta_slow rotations over the original context; attention
+    # logits scale by (0.1 * mscale * ln(factor) + 1)^2 (mscale =
+    # mscale_all_dim, so cos and sin are unscaled)
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+
     # --- MoE options ------------------------------------------------------
+    n_dense_layers: int = 0  # leading dense (d_ff) layers of an MoE stack
+    # 'softmax': top-k of softmax scores, renormalized. 'sigmoid': top-k of
+    # sigmoid scores plus a per-expert selection bias (noaux_tc); the
+    # weights are the chosen unbiased scores, renormalized
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0  # routed experts' weights scale by this
+    # expert share: n_experts_held > 0 means this chip holds experts
+    # [expert_offset, expert_offset + n_experts_held) of n_experts; the
+    # router spans all of them and the layer computes, without dropping a
+    # token, the part of the result its own experts give
+    n_experts_held: int = 0
+    expert_offset: int = 0
     n_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0
@@ -104,19 +138,19 @@ class ModelConfig:
     # Decode/cross paths stay on 'jnp'. The kernel is a custom_vjp, so
     # training gradients route through the blocked Pallas backward under
     # the same mode.
-    attention_kernel: str = dataclasses.field(default_factory=_kernel_default)
+    attention_kernel: str = dataclasses.field(default_factory=kernel_mode)
     # route the SSD within-chunk compute (train/prefill) through the
     # registry's ssd_chunk custom_vjp kernel: 'jnp' = the inline einsum
     # path in models/ssm.py, otherwise a use_pallas mode (default 'auto';
     # REPRO_KERNEL_MODE overrides). The O(1) recurrent decode step stays
     # on 'jnp' (no chunk structure).
-    ssm_kernel: str = dataclasses.field(default_factory=_kernel_default)
+    ssm_kernel: str = dataclasses.field(default_factory=kernel_mode)
     # route paged-cache serving decode (src/repro/serve/) through the
     # registry's decode_attention kernel: a use_pallas mode (default
     # 'auto'; REPRO_KERNEL_MODE overrides). 'jnp' degrades to 'off' (the
     # jnp-gather oracle) — unlike train/prefill there is no separate
     # inline path, the oracle IS the reference implementation.
-    decode_kernel: str = dataclasses.field(default_factory=_kernel_default)
+    decode_kernel: str = dataclasses.field(default_factory=kernel_mode)
     # shard attention compute by Q heads (n_heads) instead of KV heads:
     # GQA models with kv_heads < mesh 'model' size otherwise replicate the
     # whole attention computation across the model axis. Expands K/V per
@@ -142,6 +176,39 @@ class ModelConfig:
         return self.n_kv_heads * self.head_dim
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached MLA row: the kv latent and the rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.n_experts_held or self.n_experts
+
+    def attn_param_count(self) -> int:
+        d = self.d_model
+        if self.is_mla:
+            h = self.n_heads
+            return (d * self.q_lora_rank + self.q_lora_rank * h * self.qk_head_dim
+                    + d * self.latent_dim
+                    + self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                               + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        return d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+
+    @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -157,9 +224,9 @@ class ModelConfig:
         """Analytic parameter count (for 6ND model-flops accounting)."""
         d, ff, v = self.d_model, self.d_ff, self.vocab_size
         emb = v * d * (1 if self.tie_embeddings else 2)
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        attn = self.attn_param_count()
         dense_mlp = 3 * d * ff
-        moe_mlp = self.n_experts * 3 * d * self.moe_d_ff + (
+        moe_mlp = self.experts_here * 3 * d * self.moe_d_ff + (
             3 * d * self.shared_expert_d_ff if self.shared_expert_d_ff else 0
         ) + d * self.n_experts  # router
         di, st, hd = self.ssm_d_inner, self.ssm_state, self.ssm_head_dim
@@ -178,6 +245,8 @@ class ModelConfig:
             "encdec": attn + dense_mlp,
         }[self.family]
         total = emb + self.n_layers * per
+        if self.family == "moe":
+            total += self.n_dense_layers * (dense_mlp - moe_mlp)
         if self.family == "hybrid":
             total += attn + dense_mlp  # one shared attention+mlp block
         if self.family == "encdec":
@@ -191,9 +260,10 @@ class ModelConfig:
         if self.family != "moe":
             return self.param_count()
         d = self.d_model
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        attn = self.attn_param_count()
         act_mlp = self.experts_per_token * 3 * d * self.moe_d_ff + (
             3 * d * self.shared_expert_d_ff if self.shared_expert_d_ff else 0
         ) + d * self.n_experts
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return int(emb + self.n_layers * (attn + act_mlp))
+        dense = self.n_dense_layers * (3 * d * self.d_ff - act_mlp)
+        return int(emb + self.n_layers * (attn + act_mlp) + dense)

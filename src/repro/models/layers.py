@@ -1,4 +1,4 @@
-"""Core transformer layers: norm, RoPE, GQA attention, (gated) MLP, MoE.
+"""Core transformer layers: norm, RoPE, GQA and latent attention, MLP, MoE.
 
 Pure-functional: every layer is (cfg, params_subtree, activations) -> out.
 Forward math runs in cfg.compute_dtype; softmax/norm statistics in fp32.
@@ -15,12 +15,14 @@ either direction.
 """
 from __future__ import annotations
 
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.models.config import ModelConfig
+from repro.models.config import ModelConfig, kernel_mode
 from repro.models.params import ParamDef
 
 # ---------------------------------------------------------------------------
@@ -112,11 +114,18 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (out * scale.astype(jnp.float32)).astype(dt)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, Dh) rotated pairwise; positions: (..., S)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq: np.ndarray | None = None) -> jax.Array:
+    """x: (..., S, H, Dh) rotated pairwise; positions: (..., S).
+
+    The two halves of each head rotate against each other at ``theta``'s
+    frequencies, or at ``inv_freq`` (Dh // 2 of them) where given."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
@@ -125,6 +134,40 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
     return out.astype(x.dtype)
+
+
+def rope_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """The dim // 2 rotation frequencies of a rope of width ``dim``.
+
+    Without YaRN (``yarn_factor`` 1) they are theta^(-2j/dim). With it,
+    DeepSeek's form: f/factor * r + f * (1 - r), where r ramps linearly
+    from 0 to 1 between the pair indices at which the original context
+    holds ``yarn_beta_fast`` and ``yarn_beta_slow`` rotations."""
+    base = cfg.rope_theta
+    f = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if cfg.yarn_factor == 1.0:
+        return f
+
+    def corr(rotations):
+        return dim * math.log(
+            cfg.yarn_original_len / (rotations * 2 * math.pi)
+        ) / (2 * math.log(base))
+
+    low = max(math.floor(corr(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return f / cfg.yarn_factor * ramp + f * (1.0 - ramp)
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of latent attention: qk_head_dim^-1/2, times m^2 with
+    YaRN's m = 0.1 * mscale * ln(factor) + 1."""
+    m = 1.0
+    if cfg.yarn_factor > 1.0:
+        m = 0.1 * cfg.yarn_mscale * math.log(cfg.yarn_factor) + 1.0
+    return cfg.qk_head_dim ** -0.5 * m * m
 
 
 def softcap(x: jax.Array, cap: float | None) -> jax.Array:
@@ -364,6 +407,150 @@ def paged_attention(
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (MLA, DeepSeek-V3 sec. 2.1)
+# ---------------------------------------------------------------------------
+
+def mla_defs(cfg: ModelConfig) -> dict:
+    d, h, ql, kl = cfg.d_model, cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+    return {
+        "wq_a": ParamDef((d, ql), ("embed", None), scale=d**-0.5),
+        "q_norm": rms_norm_def(ql),
+        "wq_b": ParamDef((ql, h, cfg.qk_head_dim), (None, "heads", None),
+                         scale=ql**-0.5),
+        "wkv_a": ParamDef((d, cfg.latent_dim), ("embed", None),
+                          scale=d**-0.5),
+        "kv_norm": rms_norm_def(kl),
+        "wkv_b": ParamDef((kl, h, cfg.qk_nope_head_dim + cfg.v_head_dim),
+                          (None, "heads", None), scale=kl**-0.5),
+        "wo": ParamDef((h, cfg.v_head_dim, d), ("heads", None, "embed"),
+                       scale=(h * cfg.v_head_dim) ** -0.5),
+    }
+
+
+def mla_project(cfg: ModelConfig, p: dict, x: jax.Array, positions):
+    """x (B, S, d) -> q_nope (B, S, H, nope), rotated q_pe (B, S, H, rope)
+    and the latent row (B, S, kv_lora_rank + rope) a cache holds:
+    RMSNorm(a) | rotated k_pe, from [a | k_pe] = x W_DKV."""
+    dt = cfg.compute_dtype
+    nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    inv = rope_inv_freq(cfg, cfg.qk_rope_head_dim)
+    xc = x.astype(dt)
+    c_q = rms_norm(xc @ p["wq_a"].astype(dt), p["q_norm"], cfg.norm_eps)
+    q = jnp.einsum("bsr,rhk->bshk", c_q, p["wq_b"].astype(dt))
+    q = shard_act(q, "batch", "seq", "heads", None)
+    q_pe = rope(q[..., nope:], positions, cfg.rope_theta, inv)
+    kv = xc @ p["wkv_a"].astype(dt)
+    c_kv = rms_norm(kv[..., :kl], p["kv_norm"], cfg.norm_eps)
+    k_pe = rope(kv[..., None, kl:], positions, cfg.rope_theta, inv)[..., 0, :]
+    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], -1)
+
+
+def mla_attention(
+    cfg: ModelConfig,
+    p: dict,
+    x: jax.Array,  # (B, S, d)
+    positions: jax.Array,  # (B, S)
+    *,
+    cache: dict | None = None,  # {'latent': (B, L, R), 'pos': ()} decode
+) -> tuple[jax.Array, dict | None]:
+    """Causal latent attention over a whole sequence (train, prefill and the
+    contiguous decode cache), in the expanded form: each head's keys and
+    values are rebuilt from the latent rows, k = [c_kv W_UK | k_pe]."""
+    dt = cfg.compute_dtype
+    B, S, _ = x.shape
+    nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q_nope, q_pe, lat = mla_project(cfg, p, x, positions)
+    fresh = cache is not None and isinstance(cache["pos"], int) \
+        and cache["pos"] == 0
+    new_cache, keys, valid = None, lat, None
+    if cache is not None:
+        pos = cache["pos"]
+        full = jax.lax.dynamic_update_slice_in_dim(
+            cache["latent"], lat.astype(cache["latent"].dtype), pos, 1)
+        new_cache = {"latent": full, "pos": pos + S}
+        if not fresh:
+            keys, valid = full.astype(dt), pos + S
+    kv = jnp.einsum("btc,chk->bthk", keys[..., :kl], p["wkv_b"].astype(dt))
+    kv = shard_act(kv, "batch", "seq", "heads", None)
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    k_pe = keys[..., kl:]
+    scale = mla_scale(cfg)
+    if cfg.attention_kernel != "jnp" and valid is None:
+        # the flash kernel takes one head width and scales by its
+        # 1/sqrt: q carries the remaining m^2, v is zero-padded to the
+        # query/key width and sliced back
+        from repro.kernels import ops as KO
+
+        H, dq, dv = cfg.n_heads, cfg.qk_head_dim, cfg.v_head_dim
+        q = jnp.concatenate([q_nope, q_pe], -1) * jnp.asarray(
+            scale * dq**0.5, dt)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe[:, :, None], (B, S, H, dq - nope))],
+            -1)
+        vp = jnp.pad(v, ((0, 0),) * 3 + ((0, dq - dv),))
+        o = KO.flash_attention(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(vp, 1, 2), causal=True,
+            use_pallas=cfg.attention_kernel,
+        )
+        out = jnp.swapaxes(o, 1, 2)[..., :dv].astype(dt)
+    else:
+        scores = (jnp.einsum("bshk,bthk->bhst", q_nope, k_nope)
+                  + jnp.einsum("bshk,btk->bhst", q_pe, k_pe))
+        scores = scores.astype(jnp.float32) * scale
+        q_pos = positions[0]
+        k_pos = jnp.arange(keys.shape[1])
+        mask = q_pos[:, None] >= k_pos[None, :]
+        if valid is not None:
+            mask &= (k_pos < valid)[None, :]
+        probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), -1).astype(dt)
+        out = jnp.einsum("bhst,bthv->bshv", probs, v)
+    y = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
+    return shard_act(y, "batch", "seq", "embed"), new_cache
+
+
+def mla_paged_attention(
+    cfg: ModelConfig,
+    p: dict,
+    x: jax.Array,  # (B, 1, d) one new token per slot
+    positions: jax.Array,  # (B, 1)
+    pool: jax.Array,  # (n_layers, n_blocks, block_size, >= R) latent pool
+    layer,  # this layer's index into the pool
+    table: jax.Array,  # (B, n_pages)
+    lengths: jax.Array,  # (B,) tokens already cached per slot
+) -> tuple[jax.Array, jax.Array]:
+    """Single-token latent attention against the paged latent pool, in the
+    absorbed form: W_UK folds into the query and W_UV into the output, so
+    every head attends to the same 576-wide latent row (keys) and its
+    first kv_lora_rank columns (values), read once per page by the
+    registry's mla_decode_attention kernel. Exact algebra, not an
+    approximation. The new token's row is written in place at page
+    ``table[b, len // bs]``, offset ``len % bs``. Returns (y, pool)."""
+    from repro.kernels import ops as KO
+
+    dt = cfg.compute_dtype
+    B = x.shape[0]
+    nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q_nope, q_pe, lat = mla_project(cfg, p, x, positions)
+    bs, pad = pool.shape[2], pool.shape[3] - cfg.latent_dim
+    page = table[jnp.arange(B), lengths // bs]
+    pool = pool.at[layer, page, lengths % bs].set(
+        jnp.pad(lat[:, 0], ((0, 0), (0, pad))).astype(pool.dtype))
+    wkv_b = p["wkv_b"].astype(dt)
+    q_lat = jnp.einsum("bhk,chk->bhc", q_nope[:, 0], wkv_b[..., :nope])
+    q = jnp.pad(jnp.concatenate([q_lat, q_pe[:, 0]], -1),
+                ((0, 0), (0, 0), (0, pad)))  # the pool's zero columns
+    mode = "off" if cfg.decode_kernel == "jnp" else cfg.decode_kernel
+    o = KO.mla_decode_attention(
+        q, pool, jnp.asarray(layer, jnp.int32), table, lengths + 1,
+        scale=mla_scale(cfg), value_dim=kl, use_pallas=mode,
+    )  # (B, H, kv_lora_rank)
+    o = jnp.einsum("bhc,chv->bhv", o.astype(dt), wkv_b[..., nope:])
+    y = jnp.einsum("bhv,hvd->bd", o, p["wo"].astype(dt))
+    return y[:, None], pool
+
+
+# ---------------------------------------------------------------------------
 # blockwise attention (online softmax over KV blocks — the jnp twin of
 # kernels/flash_attention.py). No (S_q x S_k) buffer ever materializes:
 # the working set is one KV block per scan step. This is the §Perf
@@ -468,9 +655,41 @@ def moe_defs(cfg: ModelConfig) -> dict:
         "wu": ParamDef((e, d, f), ("expert", "embed", None)),
         "wd": ParamDef((e, f, d), ("expert", None, "embed")),
     }
+    if cfg.router_scoring == "sigmoid":
+        defs["router_bias"] = ParamDef((e,), (None,), scale=0.1)
+    if cfg.n_experts_held:
+        for k in ("wg", "wu", "wd"):
+            d_ = defs[k]
+            defs[k] = ParamDef((cfg.n_experts_held, *d_.shape[1:]), d_.axes)
     if cfg.shared_expert_d_ff:
         defs["shared"] = mlp_defs(cfg, cfg.shared_expert_d_ff)
     return defs
+
+
+def route(cfg: ModelConfig, p: dict, xt: jax.Array):
+    """xt (T, d) -> (weights (T, K) f32, experts (T, K) int32) over all
+    ``n_experts``.
+
+    'softmax': the top-k of the softmax scores, renormalized. 'sigmoid'
+    (noaux_tc): s = sigmoid(xt W_r) in float32, the experts chosen by
+    top-k of s + b (the selection bias), weighted by s_top / sum(s_top).
+    Either is then scaled by ``routed_scaling``."""
+    K = cfg.experts_per_token
+    if cfg.router_scoring == "sigmoid":
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, top_i = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), K)
+        top_p = jnp.take_along_axis(s, top_i, -1)
+    else:
+        logits = (xt @ p["router"].astype(cfg.compute_dtype)).astype(
+            jnp.float32)
+        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    if cfg.routed_scaling != 1.0:
+        top_p = top_p * cfg.routed_scaling
+    return top_p, top_i
 
 
 def moe(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
@@ -498,10 +717,8 @@ def _moe_grouped_einsum(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
 
     xt = x.reshape(G, Tg, d)
     xt = shard_act(xt, "batch", None, "embed")
-    logits = (xt @ p["router"].astype(dt)).astype(jnp.float32)  # (G, Tg, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, K)  # (G, Tg, K)
-    top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    top_p, top_i = route(cfg, p, xt.reshape(T, d))
+    top_p, top_i = top_p.reshape(G, Tg, K), top_i.reshape(G, Tg, K)
 
     oh_e = jax.nn.one_hot(top_i, E, dtype=jnp.int32)  # (G, Tg, K, E)
     # position of (token, slot) within its expert, PER GROUP
@@ -549,10 +766,7 @@ def _moe_scatter(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     C = -(-C // 128) * 128 if C > 128 else C  # 128-align: MXU + shardable
 
     xt = x.reshape(T, d)
-    logits = (xt @ p["router"].astype(dt)).astype(jnp.float32)  # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, K)  # (T, K)
-    top_p = top_p / jnp.sum(top_p, -1, keepdims=True)  # renormalize
+    top_p, top_i = route(cfg, p, xt)  # (T, K)
 
     flat_e = top_i.reshape(-1)  # (T*K,)
     flat_w = top_p.reshape(-1).astype(dt)
@@ -585,3 +799,81 @@ def _moe_scatter(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     if cfg.shared_expert_d_ff:
         y = y + mlp(cfg, p["shared"], xt.reshape(B, S, d)).reshape(T, d)
     return shard_act(y.reshape(B, S, d), "batch", "seq", "embed")
+
+
+# ---------------------------------------------------------------------------
+# the held experts' share of an MoE layer (dropless, one grouped matmul)
+# ---------------------------------------------------------------------------
+
+def _row_tile(T: int, K: int, E: int) -> int:
+    """Rows per tile of the grouped matmul: about twice the rows an expert
+    expects under even routing, 16 to 128."""
+    tm = 16
+    while tm < 128 and tm < 2 * T * K / E:
+        tm *= 2
+    return tm
+
+
+def held_experts(cfg: ModelConfig, p: dict, experts: dict, layer,
+                 x: jax.Array, valid: jax.Array | None = None):
+    """This chip's part of an MoE layer: x (B, S, d) -> (y, routes).
+
+    The router spans all ``n_experts``; of each token's top-k routes, those
+    to the held experts [expert_offset, + n_experts_held) are computed and
+    none is dropped: the routed rows are laid out expert by expert, each
+    expert's rows padded to whole tiles of ``_row_tile`` rows, in a buffer
+    sized for every token routing to min(k, held) held experts, and run as
+    one grouped matmul (the registry's expert_matmul) per projection.
+    ``experts`` holds every MoE layer's held-expert weights stacked
+    (n_moe_layers, held, ...) and ``layer`` picks this layer's, so the
+    kernel reads them in place. Rows where ``valid`` (B, S) is False
+    (padding, empty slots) are routed nowhere. The shared expert adds to
+    every token. ``routes`` (held,) int32 counts the routes to each held
+    expert. The grouped matmul runs in the default kernel mode
+    (``config.kernel_mode``: Pallas on a TPU, REPRO_KERNEL_MODE overrides)."""
+    from repro.kernels import ops as KO
+
+    dt = cfg.compute_dtype
+    B, S, d = x.shape
+    T, K, E = B * S, cfg.experts_per_token, cfg.n_experts_held
+    xt = x.reshape(T, d).astype(dt)
+    w, idx = route(cfg, p, xt)
+    local = idx - cfg.expert_offset
+    sel = (local >= 0) & (local < E)
+    if valid is not None:
+        sel &= valid.reshape(T, 1)
+    flat = jnp.where(sel, local, E).reshape(-1)  # E: routed nowhere here
+    onehot = jax.nn.one_hot(flat, E, dtype=jnp.int32)  # (T*K, E)
+    i32 = jnp.int32  # sums of int32 widen under x64; the counter stays
+    routes = onehot.sum(0, dtype=i32)
+    rank = jnp.sum((jnp.cumsum(onehot, 0, dtype=i32) - 1) * onehot, -1,
+                   dtype=i32)
+    tm = _row_tile(T, K, cfg.n_experts)
+    tiles = (routes + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles, dtype=i32)
+    n_tiles = -(-T * min(K, E) // tm) + E  # no batch can need more
+    M = n_tiles * tm
+    first = (tile_end - tiles) * tm
+    pos = jnp.where(sel.reshape(-1), first[jnp.minimum(flat, E - 1)] + rank,
+                    M)
+    row_tok = jnp.full((M,), T, jnp.int32).at[pos].set(
+        jnp.arange(T * K, dtype=jnp.int32) // K, mode="drop")
+    rows = jnp.concatenate([xt, jnp.zeros((1, d), dt)])[row_tok]
+    group = layer * E + jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"), E - 1)
+    n_active = tile_end[-1]
+    flat_w = lambda a: a.reshape(-1, *a.shape[2:]).astype(dt)
+
+    def mm(a, name):
+        return KO.expert_matmul(a, flat_w(experts[name]), group, n_active,
+                                tm=tm, use_pallas=kernel_mode())
+
+    h = (jax.nn.silu(mm(rows, "wg").astype(jnp.float32))
+         * mm(rows, "wu").astype(jnp.float32)).astype(dt)
+    out = mm(h, "wd")  # (M, d); rows of tiles past n_active are undefined
+    got = out[jnp.minimum(pos, M - 1)].reshape(T, K, d).astype(jnp.float32)
+    y = jnp.sum(jnp.where(sel[..., None], got * w[..., None], 0.0), 1)
+    y = y.astype(dt).reshape(B, S, d)
+    if cfg.shared_expert_d_ff:
+        y = y + mlp(cfg, p["shared"], x.astype(dt))
+    return shard_act(y, "batch", "seq", "embed"), routes

@@ -21,6 +21,14 @@ Public API:
   prefill(cfg, params, tok, cache)     -> (cache, logits at valid_len - 1)
   decode_step(cfg, params, tok, cache) -> (cache, logits)
 
+Latent attention (cfg.kv_lora_rank > 0; the DeepSeek-V3 block of kimi-k2)
+builds its own stack: ``n_dense_layers`` dense layers, then the MoE layers,
+each part a scan, MLA in every layer. Its caches hold one latent row per
+token per layer ('latent'); where the chip holds a share of the experts the
+MoE layers are the dropless held-expert layer, and prefill's cache and the
+paged pools also return the call's route counts ('routes', (n_moe_layers,
+held) int32).
+
 Serving API (the paged-pool twin, driven by src/repro/serve/):
   paged_cache_defs(cfg, max_batch, n_blocks, block_size, n_pages)
   decode_step_paged(cfg, params, tok, pools, table, lengths)
@@ -75,6 +83,22 @@ def _block_defs(cfg: ModelConfig) -> dict:
     return blk
 
 
+def _mla_defs(cfg: ModelConfig) -> dict:
+    """An MLA stack (DeepSeek-V3 block): ``n_dense_layers`` leading dense
+    layers ('dense_blocks') then the MoE layers ('blocks')."""
+    if cfg.family != "moe":
+        raise ValueError("latent attention is served in the moe family only")
+    layer = lambda ffn, defs: {
+        "ln1": L.rms_norm_def(cfg.d_model), "attn": L.mla_defs(cfg),
+        "ln2": L.rms_norm_def(cfg.d_model), ffn: defs,
+    }
+    defs = {"blocks": _stack(layer("moe", L.moe_defs(cfg)), cfg.n_moe_layers)}
+    if cfg.n_dense_layers:
+        defs["dense_blocks"] = _stack(layer("mlp", L.mlp_defs(cfg)),
+                                      cfg.n_dense_layers)
+    return defs
+
+
 def _ssm_block_defs(cfg: ModelConfig) -> dict:
     return {"ln": L.rms_norm_def(cfg.d_model), "ssm": S.ssm_defs(cfg)}
 
@@ -88,7 +112,9 @@ def model_defs(cfg: ModelConfig) -> dict:
     if not cfg.tie_embeddings:
         defs["lm_head"] = ParamDef((d, cfg.vocab_size), ("embed", "vocab"))
 
-    if cfg.family in ("dense", "moe"):
+    if cfg.is_mla:
+        defs.update(_mla_defs(cfg))
+    elif cfg.family in ("dense", "moe"):
         defs["blocks"] = _stack(_block_defs(cfg), cfg.n_layers)
     elif cfg.family == "ssm":
         defs["blocks"] = _stack(_ssm_block_defs(cfg), cfg.n_layers)
@@ -270,6 +296,74 @@ def _scan_ssm_stack(cfg, blocks, x, caches, valid_len=None):
     return x, new_caches
 
 
+def _split_experts(cfg, blocks):
+    """(the per-layer params a scan slices, the held experts' weights).
+
+    Held experts stay stacked over the MoE layers, (n_moe, held, ...), and
+    are read in place by the grouped matmul with the layer's index: a
+    scan's per-layer slice of them would be a copy every call."""
+    if not cfg.n_experts_held or "moe" not in blocks:
+        return blocks, None
+    moe = dict(blocks["moe"])
+    experts = {k: moe.pop(k) for k in ("wg", "wu", "wd")}
+    return {**blocks, "moe": moe}, experts
+
+
+def _mla_ffn(cfg, p, x, experts, layer, valid):
+    """The feed-forward half of an MLA-stack layer -> (x, routes or None)."""
+    inner = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+    if "mlp" in p:
+        return x + L.mlp(cfg, p["mlp"], inner), None
+    if experts is None:
+        return x + L.moe(cfg, p["moe"], inner), None
+    y, routes = L.held_experts(cfg, p["moe"], experts, layer, inner, valid)
+    return x + y, routes
+
+
+def _mla_parts(cfg, params):
+    """(blocks, index of their first layer) of each part of the stack."""
+    parts = [(params["blocks"], cfg.n_dense_layers)]
+    if cfg.n_dense_layers:
+        parts.insert(0, (params["dense_blocks"], 0))
+    return parts
+
+
+def _mla_stack(cfg, params, x, positions, cache, valid):
+    """Full-sequence MLA stack (train, prefill, the contiguous decode
+    cache): the dense layers, then the MoE layers, each a scan. ``cache``
+    is {'latent': (n_layers, B, L, R), 'pos'} or None; ``valid`` (B, S)
+    marks the tokens the held experts route (None: all). Returns (x, new
+    cache), the cache with this call's 'routes' (n_moe, held) where this
+    chip holds a share of the experts."""
+    lats, routes = [], None
+    for blocks, first in _mla_parts(cfg, params):
+        blocks, experts = _split_experts(cfg, blocks)
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        lat = None if cache is None else cache["latent"][first:first + n]
+
+        def body(x, xs, experts=experts):
+            p, i, c = xs
+            cc = None if c is None else {"latent": c, "pos": cache["pos"]}
+            h, nc = L.mla_attention(
+                cfg, p["attn"], L.rms_norm(x, p["ln1"], cfg.norm_eps),
+                positions, cache=cc,
+            )
+            x, r = _mla_ffn(cfg, p, x + h, experts, i, valid)
+            return x, (None if nc is None else nc["latent"], r)
+
+        x, (lat, r) = jax.lax.scan(_remat(cfg, body), x,
+                                   (blocks, jnp.arange(n), lat))
+        lats.append(lat)
+        routes = r if r is not None else routes
+    if cache is None:
+        return x, None
+    new = {"latent": jnp.concatenate(lats) if len(lats) > 1 else lats[0],
+           "pos": cache["pos"] + positions.shape[1]}
+    if routes is not None:
+        new["routes"] = routes
+    return x, new
+
+
 # ---------------------------------------------------------------------------
 # forward (train / scoring): full-sequence logits
 # ---------------------------------------------------------------------------
@@ -290,7 +384,9 @@ def forward(
     x = L.shard_act(x, "batch", "seq", "embed")
     positions = jnp.broadcast_to(jnp.arange(Seq)[None], (B, Seq))
 
-    if cfg.family in ("dense", "moe"):
+    if cfg.is_mla:
+        x, _ = _mla_stack(cfg, params, x, positions, None, None)
+    elif cfg.family in ("dense", "moe"):
         x, _ = _scan_stack(cfg, params["blocks"], x, positions, None)
     elif cfg.family == "ssm":
         x, _ = _scan_ssm_stack(cfg, params["blocks"], x, None)
@@ -405,6 +501,14 @@ def cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
         ),
         "pos": jax.ShapeDtypeStruct((), jnp.int32),
     }
+    if cfg.is_mla:
+        return {
+            "latent": jax.ShapeDtypeStruct(
+                (cfg.n_layers, batch, max_len, cfg.latent_dim),
+                cfg.compute_dtype),
+            "pos": jax.ShapeDtypeStruct((), jnp.int32),
+            **_routes_def(cfg),
+        }
     if cfg.family in ("dense", "moe"):
         return kv(cfg.n_layers)
     if cfg.family == "ssm":
@@ -440,6 +544,21 @@ def cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     raise ValueError(cfg.family)
 
 
+def latent_width(cfg: ModelConfig) -> int:
+    """Width of a latent pool's row: the latent, padded to 128 lanes."""
+    return -(-cfg.latent_dim // 128) * 128
+
+
+def _routes_def(cfg: ModelConfig) -> dict:
+    """The route counter a cache or pool carries where this chip holds a
+    share of the experts: routes to each held expert in each MoE layer by
+    the last call, (n_moe_layers, held) int32."""
+    if not cfg.n_experts_held:
+        return {}
+    return {"routes": jax.ShapeDtypeStruct(
+        (cfg.n_moe_layers, cfg.n_experts_held), jnp.int32)}
+
+
 def cache_pspecs(cfg: ModelConfig) -> dict:
     """PartitionSpecs matching cache_defs: shard batch over 'data', kv heads
     over 'model' (ssm states: heads over 'model')."""
@@ -450,6 +569,9 @@ def cache_pspecs(cfg: ModelConfig) -> dict:
         "v": P(None, "data", None, "model", None),
         "pos": P(),
     }
+    if cfg.is_mla:
+        return {"latent": P(None, "data", None, None), "pos": P(),
+                **{k: P() for k in _routes_def(cfg)}}
     if cfg.family in ("dense", "moe"):
         return kvp()
     if cfg.family == "ssm":
@@ -491,7 +613,11 @@ def _stack_apply(cfg, params, tokens, cache, enc_embeds, valid_len):
     pos0 = _cache_pos(cfg, cache)
     positions = pos0 + jnp.broadcast_to(jnp.arange(Sq)[None], (B, Sq))
 
-    if cfg.family in ("dense", "moe"):
+    if cfg.is_mla:
+        valid = None if valid_len is None else (
+            jnp.arange(Sq)[None] < valid_len[:, None])
+        x, new_cache = _mla_stack(cfg, params, x, positions, cache, valid)
+    elif cfg.family in ("dense", "moe"):
         x, new_cache = _scan_stack(cfg, params["blocks"], x, positions, cache)
     elif cfg.family == "ssm":
         x, new_cache = _scan_ssm_stack(
@@ -640,6 +766,17 @@ def paged_cache_defs(
     family sit behind the same CachePool interface.
     """
     del n_pages  # table shape is scheduler state, not pool state
+    if cfg.is_mla:
+        # one latent row (kv_lora_rank + rope values) a token a layer,
+        # zero-padded to whole 128-lane tiles: a TPU lays an unpadded pool
+        # out with the page axis minor, which every kernel call would have
+        # to copy back to row-major
+        return {
+            "latent": jax.ShapeDtypeStruct(
+                (cfg.n_layers, n_blocks, block_size, latent_width(cfg)),
+                cfg.compute_dtype),
+            **_routes_def(cfg),
+        }
     kv = lambda n: {
         "k": jax.ShapeDtypeStruct(
             (n, n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim),
@@ -715,6 +852,36 @@ def _paged_scan_stack(cfg, blocks, x, positions, pools, table, lengths):
     xs = (blocks, _substack(pools["k"], m), _substack(pools["v"], m))
     x, (nk, nv) = jax.lax.scan(body, x, xs)
     return x, {"k": _unsubstack(nk, m), "v": _unsubstack(nv, m)}
+
+
+def _mla_paged_stack(cfg, params, x, positions, pools, table, lengths):
+    """The paged MLA stack: the whole latent pool rides in the scans'
+    carry and each layer writes its row and reads its pages in place by
+    the layer's index (a per-layer slice would copy the pool). Slots with
+    length 0 (empty) route to no expert."""
+    pool, routes = pools["latent"], None
+    valid = (lengths > 0)[:, None]
+    for blocks, first in _mla_parts(cfg, params):
+        blocks, experts = _split_experts(cfg, blocks)
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+
+        def body(carry, xs, experts=experts, first=first):
+            x, pool = carry
+            p, i = xs
+            h, pool = L.mla_paged_attention(
+                cfg, p["attn"], L.rms_norm(x, p["ln1"], cfg.norm_eps),
+                positions, pool, first + i, table, lengths,
+            )
+            x, r = _mla_ffn(cfg, p, x + h, experts, i, valid)
+            return (x, pool), r
+
+        (x, pool), r = jax.lax.scan(body, (x, pool),
+                                    (blocks, jnp.arange(n)))
+        routes = r if r is not None else routes
+    out = {"latent": pool}
+    if routes is not None:
+        out["routes"] = routes
+    return x, out
 
 
 def _paged_hybrid(cfg, params, x, positions, pools, table, lengths):
@@ -799,7 +966,11 @@ def decode_step_paged(
     """
     x = _embed(cfg, params, tokens)
     positions = lengths[:, None].astype(jnp.int32)  # (B, 1)
-    if cfg.family in ("dense", "moe"):
+    if cfg.is_mla:
+        x, pools = _mla_paged_stack(
+            cfg, params, x, positions, pools, table, lengths
+        )
+    elif cfg.family in ("dense", "moe"):
         x, pools = _paged_scan_stack(
             cfg, params["blocks"], x, positions, pools, table, lengths
         )

@@ -2,7 +2,9 @@
 
 Device memory for attention K/V is one preallocated pool of
 ``(n_blocks, block_size, KV, Dh)`` pages per layer (see
-``transformer.paged_cache_defs``).  A sequence occupies a *slot*
+``transformer.paged_cache_defs``); a latent-attention (MLA) model keeps
+one pool of ``(n_blocks, block_size, kv_lora_rank + rope)`` latent rows
+per layer in their place, written in place (donated).  A sequence occupies a *slot*
 (0..max_batch) and references pages through a host-side
 ``(max_batch, n_pages)`` block table — pool memory scales with live
 tokens across all sequences, not ``max_batch * max_len``.
@@ -150,6 +152,9 @@ class CachePool:
         self._lengths_dev = None
         self._scatter = TracedJit(_scatter_blocks)
         self._set_slot = TracedJit(_set_slot)
+        # the latent pool is written in place: a second pool would not fit
+        # beside the weights of a model that needs one
+        self._scatter_latent = TracedJit(_scatter_blocks, donate_argnums=(0,))
 
     # -- accounting ---------------------------------------------------------
 
@@ -177,7 +182,8 @@ class CachePool:
 
     @property
     def trace_count(self) -> int:
-        return self._scatter.traces + self._set_slot.traces
+        return (self._scatter.traces + self._set_slot.traces
+                + self._scatter_latent.traces)
 
     def pages_needed(self, n_tokens: int) -> int:
         if not self.paged:
@@ -282,7 +288,13 @@ class CachePool:
         """
         fam = self.cfg.family
         slot_dev = jnp.int32(slot)
-        if fam in ("dense", "moe"):
+        if self.cfg.is_mla:
+            lat = cache["latent"][:, 0]
+            pad = self.pools["latent"].shape[-1] - lat.shape[-1]
+            self.pools = {**self.pools, "latent": self._scatter_latent(
+                self.pools["latent"], jnp.pad(lat, ((0, 0), (0, 0), (0, pad))),
+                self._prompt_page_ids(slot))}
+        elif fam in ("dense", "moe"):
             ids = self._prompt_page_ids(slot)
             self.pools = {
                 "k": self._scatter(self.pools["k"], cache["k"][:, 0], ids),
@@ -332,17 +344,20 @@ class CachePool:
 
     def gather_kv(self, slot: int, n_tokens: int) -> dict | None:
         """Read back slot's K/V as contiguous (n, n_tokens, KV, Dh) numpy
-        arrays (dense/moe only) — parity-test convenience, host-side."""
+        arrays, or its latent rows as (n, n_tokens, R) under 'latent'
+        (dense/moe only) — parity-test convenience, host-side."""
         if self.cfg.family not in ("dense", "moe"):
             return None
-        k = np.asarray(self.pools["k"])
-        v = np.asarray(self.pools["v"])
+        names = ("latent",) if self.cfg.is_mla else ("k", "v")
         pages = self._pages_of[slot]
         bs = self.pc.block_size
         out = {}
-        for name, pool in (("k", k), ("v", v)):
+        for name in names:
+            pool = np.asarray(self.pools[name])
             slab = pool[:, pages]  # (n, P, bs, KV, Dh)
             n = slab.shape[0]
             slab = slab.reshape(n, len(pages) * bs, *slab.shape[3:])
             out[name] = slab[:, :n_tokens]
+        if self.cfg.is_mla:  # without the lane padding
+            out["latent"] = out["latent"][..., : self.cfg.latent_dim]
         return out

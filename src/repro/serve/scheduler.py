@@ -18,8 +18,9 @@ preemption (oldest-first fallback among exempt slots) so no request
 thrashes forever.
 
 Per-step counters (queue depth, active slots, pool occupancy,
-admissions/evictions/preemptions, tokens generated) accumulate in a
-``ServeStats`` record, beside one ``RequestRecord`` per request (when it
+admissions/evictions/preemptions, tokens generated and, where the chip
+holds a share of the experts, the routes to each held expert of the step's
+decode and of its prefills) accumulate in a ``ServeStats`` record, beside one ``RequestRecord`` per request (when it
 was submitted, admitted, produced its first token and finished).
 ``step()`` and each admission's prefill are wrapped in profiler spans
 (``repro.obs``; docs/serving.md).
@@ -31,6 +32,7 @@ import functools
 import time
 from collections import deque
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -56,7 +58,12 @@ class Request:
 
 @dataclasses.dataclass
 class StepStats:
-    """Counters for one scheduler step (recorded after admission)."""
+    """Counters for one scheduler step (recorded after admission).
+
+    ``decode_routes`` and ``prefill_routes`` are (n_moe_layers, held)
+    int32 route counts to each held expert, of the step's decode and of
+    its admissions' prefills (their prompt tokens only); None where the
+    model holds no share of experts or nothing of the kind ran."""
 
     step: int
     queue_depth: int
@@ -66,6 +73,8 @@ class StepStats:
     finished: int
     preempted: int
     tokens_generated: int
+    decode_routes: np.ndarray | None = None
+    prefill_routes: np.ndarray | None = None
 
 
 @dataclasses.dataclass
@@ -118,6 +127,20 @@ class ServeStats:
     def preemptions(self) -> int:
         return sum(s.preempted for s in self.steps)
 
+    @property
+    def decode_routes(self) -> np.ndarray | None:
+        """Routes to each held expert over every decode step (summed)."""
+        got = [s.decode_routes for s in self.steps
+               if s.decode_routes is not None]
+        return np.sum(got, axis=0) if got else None
+
+    @property
+    def prefill_routes(self) -> np.ndarray | None:
+        """Routes to each held expert over every prefill (summed)."""
+        got = [s.prefill_routes for s in self.steps
+               if s.prefill_routes is not None]
+        return np.sum(got, axis=0) if got else None
+
 
 @dataclasses.dataclass
 class _Active:
@@ -153,7 +176,11 @@ class Scheduler:
         self.stats = ServeStats()
         self._step_idx = 0
         self._prefill = TracedJit(functools.partial(T.prefill, cfg))
-        self._decode = TracedJit(functools.partial(T.decode_step_paged, cfg))
+        # a latent pool is updated in place (pools donated): it is sized to
+        # fill what the weights leave of the device
+        self._decode = TracedJit(functools.partial(T.decode_step_paged, cfg),
+                                 donate_argnums=(2,) if cfg.is_mla else ())
+        self._prefill_routes = None  # summed over this step's admissions
         self._encode = TracedJit(
             functools.partial(T.encode_cross_cache, cfg, batch=1)
         )
@@ -233,7 +260,13 @@ class Scheduler:
 
             # the prefill logits already yield the first generated token:
             # a decode step per NEW token, not per request token
-            g0 = self._sample(np.asarray(logits)[0])
+            if "routes" in cache:  # fetched with the logits, one transfer
+                logits_np, routes = jax.device_get((logits, cache["routes"]))
+                self._prefill_routes = routes if self._prefill_routes is None \
+                    else self._prefill_routes + routes
+            else:
+                logits_np = np.asarray(logits)
+            g0 = self._sample(logits_np[0])
         if record.first_token_s is None:
             record.first_token_s = time.perf_counter()
         target = min(req.max_new_tokens, pc.max_len - plen + 1)
@@ -315,10 +348,12 @@ class Scheduler:
             return self._step()
 
     def _step(self) -> StepStats:
+        self._prefill_routes = None
         admitted = self._admit()
         preempted = self._ensure_capacity()
         finished = 0
         tokens_generated = 0
+        decode_routes = None
 
         if self.active:
             with obs.span("serve.decode"):
@@ -331,7 +366,11 @@ class Scheduler:
                 )
                 self.pool.pools = pools
             with obs.span("serve.fetch"):
-                logits_np = np.asarray(logits)
+                if "routes" in pools:  # one transfer with the logits
+                    logits_np, decode_routes = jax.device_get(
+                        (logits, pools["routes"]))
+                else:
+                    logits_np = np.asarray(logits)
             with obs.span("serve.sample"):
                 slots = list(self._admit_order)
                 self.pool.bump_lengths(slots)
@@ -354,6 +393,8 @@ class Scheduler:
             finished=finished,
             preempted=preempted,
             tokens_generated=tokens_generated,
+            decode_routes=decode_routes,
+            prefill_routes=self._prefill_routes,
         )
         self.stats.steps.append(stats)
         self._step_idx += 1

@@ -41,6 +41,22 @@ def _example_args(name, key, dtype=jnp.float32, small=True):
             [npg * bs - 3, 0, 1, 5][:B], jnp.int32
         )
         return (q, kp, vp, table, lengths), {"window": 7, "softcap": 30.0}
+    if name == "mla_decode_attention":
+        L, B, H, R, C, bs, nb, npg = (
+            (2, 3, 4, 24, 16, 8, 9, 3) if small else (3, 4, 8, 72, 64, 16, 33, 6)
+        )
+        q = jax.random.normal(ks[0], (B, H, R), dtype)
+        pool = jax.random.normal(ks[1], (L, nb, bs, R), dtype)
+        table = jax.random.randint(ks[3], (B, npg), 1, nb).astype(jnp.int32)
+        lengths = jnp.asarray([npg * bs - 3, 0, 1, 5 * bs + 2][:B], jnp.int32)
+        return (q, pool, jnp.int32(L - 1), table, lengths), {
+            "scale": R**-0.5, "value_dim": C}
+    if name == "expert_matmul":
+        tm, nt, G, K, N = (16, 4, 3, 32, 48) if small else (16, 6, 8, 256, 384)
+        x = jax.random.normal(ks[0], (tm * nt, K), dtype)
+        w = jax.random.normal(ks[1], (G, K, N), dtype) * K**-0.5
+        groups = jax.random.randint(ks[2], (nt,), 0, G).astype(jnp.int32)
+        return (x, w, groups, jnp.int32(nt)), {"tm": tm}
     if name == "ssd_chunk":
         B, nc, Q, nh, hd, ds = (1, 2, 32, 2, 16, 8) if small else (2, 2, 64, 4, 32, 16)
         xdt = jax.random.normal(ks[0], (B, nc, Q, nh, hd), dtype)
@@ -77,8 +93,8 @@ def _example_args(name, key, dtype=jnp.float32, small=True):
 
 def test_registry_is_complete():
     assert ops.registered_kernels() == (
-        "block_topk", "decode_attention", "flash_attention", "sparse_axpy",
-        "sparse_dot", "ssd_chunk",
+        "block_topk", "decode_attention", "expert_matmul", "flash_attention",
+        "mla_decode_attention", "sparse_axpy", "sparse_dot", "ssd_chunk",
     )
 
 
@@ -124,7 +140,8 @@ def test_tolerance_fallback_to_f32():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_interpret_matches_ref_within_declared_tol(name, small, dtype):
     if dtype == jnp.bfloat16 and name not in (
-        "flash_attention", "decode_attention"
+        "flash_attention", "decode_attention", "mla_decode_attention",
+        "expert_matmul",
     ):
         # DSBA/selection kernels are f32/f64 paths; ssd_chunk's oracle
         # accumulates in the input dtype, so bf16 parity is not a kernel

@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file. Where it cannot be described, the fixture skips.
 """
+import functools
 import re
 
 import jax
@@ -21,7 +22,8 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import decode_attention, mla_decode_attention
+from repro.kernels.expert_matmul import expert_matmul
 from repro.kernels.flash_attention import (
     flash_attention_bwd,
     flash_attention_fwd,
@@ -88,6 +90,43 @@ def test_decode_attention_compiles_at_minitron_8b_widths(one_chip):
     )
     assert KERNEL_MARK in text
     assert _kernel_names(text) == {"decode_attention"}
+
+
+def test_mla_decode_attention_compiles_at_kimi_k2_widths(one_chip):
+    # kimi_k2.reasoning: 64 slots of 320 pages of 16 latent rows, each row
+    # 576 wide (kv_lora_rank 512 + rope 64) padded to 640 lanes, 64 heads,
+    # 8 layers in one pool
+    B, H, R, C, pages, layers = 64, 64, 640, 512, 320, 8
+    n_blocks = B * pages + 1
+    fn = functools.partial(mla_decode_attention, scale=0.1, value_dim=C)
+    text = _compiled_text(
+        fn, one_chip,
+        ((B, H, R), jnp.bfloat16),
+        ((layers, n_blocks, 16, R), jnp.bfloat16),
+        ((), jnp.int32),
+        ((B, pages), jnp.int32),
+        ((B,), jnp.int32),
+    )
+    assert _kernel_names(text) == {"mla_decode_attention"}
+
+
+@pytest.mark.parametrize("tm", [16, 128])
+@pytest.mark.parametrize("proj", ["gate", "down"])
+def test_expert_matmul_compiles_at_kimi_k2_widths(one_chip, tm, proj):
+    # 7 MoE layers x 8 held experts of 7168 x 2048 (gate, up) or 2048 x 7168
+    # (down); the decode step's 64 tokens (tm 16) and a 2,048-token
+    # prefill (tm 128), top-8
+    K, N = (7168, 2048) if proj == "gate" else (2048, 7168)
+    T = 64 if tm == 16 else 2048
+    M = (-(-T * 8 // tm) + 8) * tm
+    text = _compiled_text(
+        functools.partial(expert_matmul, tm=tm), one_chip,
+        ((M, K), jnp.bfloat16),
+        ((56, K, N), jnp.bfloat16),
+        ((M // tm,), jnp.int32),
+        ((), jnp.int32),
+    )
+    assert _kernel_names(text) == {"expert_matmul"}
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
