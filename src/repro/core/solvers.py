@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import time
 from typing import Any, Callable, Mapping
 
@@ -683,21 +684,84 @@ def _runner_key(spec: SolverSpec, problem: Problem, hp: Mapping):
     return key, (problem.data,)
 
 
+def _record_scan(run_chunk, z_fn, metrics):
+    """The untraced ``record`` body shared by the dense and sharded runners.
+
+    ``(state, idx_blocks (R, E, N), hp, ref, keep_snapshots) -> (state,
+    dist2 (R,), consensus (R,), zs (R, N, D))``: a scan over R record
+    points, each running one E-iteration chunk and then reducing its
+    iterates on the device with ``_Recorder.push``'s definitions,
+    ``consensus = mean_n ||z_n - zbar||^2`` and ``dist2 = mean_n ||z_n -
+    z*||^2`` (``metrics``: ``_point_metrics`` over the runner's node
+    axis). ``ref`` is None (``dist2`` None) or the root as a (hi, lo) pair
+    in the reduction dtype, hi + lo = z*, so a float64 root keeps its
+    precision against float32 iterates. ``zs`` is None unless
+    ``keep_snapshots``.
+    """
+
+    def record(state, idx_blocks, hp_dyn, ref, keep_snapshots):
+        def point(st, idx_block):
+            st = run_chunk(st, idx_block, hp_dyn)
+            z = z_fn(st, hp_dyn)
+            dist2, cons = metrics(z, ref)
+            return st, (dist2, cons, z if keep_snapshots else None)
+
+        state, (dist2, cons, zs) = jax.lax.scan(point, state, idx_blocks)
+        return state, dist2, cons, zs
+
+    return record
+
+
+def _point_metrics(z, ref, node_sum, n: int):
+    """(dist2 or None, consensus) of one record point's iterates, traced;
+    ``node_sum`` sums over the node axis (a ``psum`` over "node" inside
+    ``shard_map``), ``n`` is the number of nodes."""
+    z = z.astype(_reduce_dtype(z.dtype))
+    zbar = node_sum(z) / n
+    cons = node_sum(jnp.sum((z - zbar) ** 2, -1)) / n
+    if ref is None:
+        return None, cons
+    return node_sum(jnp.sum(((z - ref[0]) - ref[1]) ** 2, -1)) / n, cons
+
+
+def _dense_metrics(z, ref):
+    """``_point_metrics`` of (N, D) iterates on one device."""
+    return _point_metrics(z, ref, lambda x: jnp.sum(x, 0), z.shape[0])
+
+
+def _reduce_dtype(dtype):
+    """The device recorder's dtype: the iterates' own, at least float32
+    (a TPU has no float64 arithmetic; float64 iterates reduce in float64)."""
+    return jnp.promote_types(dtype, jnp.float32)
+
+
+def _record_ref(z_star, dtype):
+    """The root as the (hi, lo) pair ``_record_scan`` takes, or None."""
+    if z_star is None:
+        return None
+    z_star = np.asarray(z_star)
+    hi = z_star.astype(_reduce_dtype(dtype))
+    return jnp.asarray(hi), jnp.asarray((z_star - hi).astype(hi.dtype))
+
+
 @dataclasses.dataclass
 class _DenseRunner:
     """One compiled dense-backend runner: chunked scan + iterate read-out.
 
-    ``chunk``/``z_read`` are the jitted entrypoints; ``run_chunk``/``z_fn``
-    are the untraced callables kept for ``solve_many`` to vmap (the batched
-    variants compile lazily into ``batched``, keyed by vmap signature).
+    ``chunk``/``z_read`` are the jitted entrypoints; ``record`` runs a
+    whole segment of record points in one program (``_record_scan``);
+    ``run_chunk``/``z_fn`` are the untraced callables kept for
+    ``solve_many`` to vmap (the batched variants compile lazily into
+    ``batched``, keyed by vmap signature).
     """
 
-    init: Callable  # (z0) -> state, eager
+    init: Callable  # jitted (z0) -> state
     run_chunk: Callable  # (state, idx_block, hp) -> state, untraced
     z_fn: Callable  # (state, hp) -> (N, D), untraced
     chunk: Callable  # jitted run_chunk (donated carry off-CPU)
     z_read: Callable  # jitted z_fn
-    donates: bool  # whether chunk donates its carry argument
+    record: Callable  # jitted _record_scan (donated carry off-CPU)
+    donates: bool  # whether chunk and record donate their carry argument
     batched: dict = dataclasses.field(default_factory=dict)
 
 
@@ -722,15 +786,19 @@ def _get_dense_runner(spec: SolverSpec, problem: Problem, hp: Mapping):
             runner_cache.DENSE.note_trace()
             return z_fn(state, hp_dyn)
 
+        record = _record_scan(run_chunk, z_fn, _dense_metrics)
         # donating the scan carry lets XLA reuse the state buffers in
         # place; CPU does not implement donation (it would only warn)
         donates = jax.default_backend() != "cpu"
+        donate = (0,) if donates else ()
         return _DenseRunner(
-            init=lambda z0: spec.init(problem, fhp, z0),
+            init=jax.jit(lambda z0: spec.init(problem, fhp, z0)),
             run_chunk=run_chunk,
             z_fn=z_fn,
-            chunk=jax.jit(run_chunk, donate_argnums=(0,) if donates else ()),
+            chunk=jax.jit(run_chunk, donate_argnums=donate),
             z_read=jax.jit(read),
+            record=jax.jit(record, static_argnames=("keep_snapshots",),
+                           donate_argnums=donate),
             donates=donates,
         )
 
@@ -741,16 +809,18 @@ def _get_dense_runner(spec: SolverSpec, problem: Problem, hp: Mapping):
 class _ShardedRunner:
     """One compiled sharded-backend runner: shard_mapped scan + read-out.
 
-    ``chunk``/``z_read`` are jitted ``shard_map`` wrappers over the same
-    chunked scan the dense runner compiles — the solver step itself is
-    shared; only the comm primitive differs. ``measured`` caches the
-    HLO-derived per-iteration collective traffic, keyed by chunk length
-    (each distinct length is its own compiled program).
+    ``chunk``/``z_read``/``record`` are jitted ``shard_map`` wrappers over
+    the same programs the dense runner compiles — the solver step itself
+    is shared; only the comm primitive differs (and ``record`` reduces
+    its metrics with ``psum``). ``measured`` caches the HLO-derived
+    per-iteration collective traffic of the chunk program, keyed by chunk
+    length (each distinct length is its own compiled program).
     """
 
-    init: Callable  # (z0) -> state, eager (global (N, ...) leaves)
+    init: Callable  # jitted (z0) -> state (global (N, ...) leaves)
     chunk: Callable  # jitted shard_map'd (state, idx_block, hp) -> state
     z_read: Callable  # jitted shard_map'd (state, hp) -> (N, D)
+    record: Callable  # jitted shard_map'd _record_scan, psum reductions
     mesh: Any
     measured: dict = dataclasses.field(default_factory=dict)
 
@@ -843,10 +913,31 @@ def _get_sharded_runner(
                 out_specs=P("node", None),
             )
         )
+        body = _record_scan(
+            run_chunk, z_fn,
+            lambda z, ref: _point_metrics(
+                z, ref, lambda x: jax.lax.psum(jnp.sum(x, 0), "node"), n
+            ),
+        )
+
+        def record(state, idx_blocks, hp_dyn, ref, keep_snapshots):
+            # the reductions are psums, so dist2 and consensus come out
+            # replicated; the root is replicated too
+            rec_spec = None if ref is None else P()
+            return jax.shard_map(
+                functools.partial(body, keep_snapshots=keep_snapshots),
+                mesh=mesh,
+                in_specs=(state_specs, P(None, None, "node"), hp_specs,
+                          rec_spec),
+                out_specs=(state_specs, rec_spec, P(),
+                           P(None, "node", None) if keep_snapshots else None),
+            )(state, idx_blocks, hp_dyn, ref)
+
         return _ShardedRunner(
-            init=lambda z0: spec.init(problem, fhp, z0),
+            init=jax.jit(lambda z0: spec.init(problem, fhp, z0)),
             chunk=chunk,
             z_read=z_read,
+            record=jax.jit(record, static_argnames=("keep_snapshots",)),
             mesh=mesh,
         )
 
@@ -1048,7 +1139,8 @@ def _get_sharded_fault_runner(
 
 
 def _get_batched_fns(runner: _DenseRunner, dyn_names) -> tuple:
-    """(chunk, z_read) vmapped over a leading (grid/seed) axis, cached.
+    """(chunk, z_read, metrics) vmapped over a leading (grid/seed) axis,
+    cached (``metrics`` is the solve() recorder's device reduction).
 
     hp entries map over axis 0 except ``lam`` (problem-level, shared);
     state and the index stream always carry the batch axis.
@@ -1059,6 +1151,7 @@ def _get_batched_fns(runner: _DenseRunner, dyn_names) -> tuple:
         runner.batched[sig] = (
             jax.jit(jax.vmap(runner.run_chunk, in_axes=(0, 0, hp_axes))),
             jax.jit(jax.vmap(runner.z_fn, in_axes=(0, hp_axes))),
+            jax.jit(jax.vmap(_dense_metrics, in_axes=(0, None))),
         )
     return runner.batched[sig]
 
@@ -1126,6 +1219,62 @@ def _record_points(steps: int, record_every: int) -> list[int]:
     return pts
 
 
+def _record_segments(pts: list[int], start: int, every: int | None):
+    """The record points after ``start``, split after each multiple of
+    ``every`` (checkpoint boundaries); one segment when ``every`` is None."""
+    segments, seg = [], []
+    for pt in pts:
+        if pt <= start:
+            continue  # already covered by a restored checkpoint
+        seg.append(pt)
+        if every is not None and pt % every == 0:
+            segments.append(seg)
+            seg = []
+    if seg:
+        segments.append(seg)
+    return segments
+
+
+def _run_recorded(runner, state, indices, hp_dyn, ref, segments, start, rec,
+                  keep_snapshots, save=None):
+    """Run ``segments`` of record points through ``runner.record``.
+
+    Each segment is one ``record`` call over its chunks of equal length
+    (one more, with R = 1, for a shorter final chunk), then ONE read of its
+    records to the host; the last segment's read brings the final iterates
+    too. ``indices`` is the host's (steps, N) int32 stream; ``save(pt,
+    state)`` runs after each segment. Returns ``(state, z, host_reads)``.
+    """
+    prev, z_final = start, None
+    for k, seg in enumerate(segments):
+        calls = []
+        with obs.span("solve.run"):
+            i = 0
+            for length, group in itertools.groupby(np.diff([prev, *seg])):
+                r = len(list(group))
+                blocks = indices[prev:prev + r * length].reshape(r, length, -1)
+                state, *out = runner.record(
+                    state, blocks, hp_dyn, ref, keep_snapshots=keep_snapshots
+                )
+                calls.append((seg[i:i + r], out))
+                i += r
+                prev += r * length
+            z_dev = None
+            if k == len(segments) - 1:
+                z_dev = runner.z_read(state, hp_dyn)
+        with obs.span("solve.readout"):
+            outs, z_final = jax.device_get(([o for _, o in calls], z_dev))
+            for (its, _), (dist2, cons, zs) in zip(calls, outs):
+                rec.extend(its, dist2, cons, zs)
+        if save is not None:
+            save(seg[-1], state)
+    if not segments:
+        # resumed at (or past) the final record point: nothing to re-run
+        with obs.span("solve.readout"):
+            z_final = jax.device_get(runner.z_read(state, hp_dyn))
+    return state, z_final, max(len(segments), 1)
+
+
 class _Recorder:
     """The one metrics recorder shared by every method and comm backend.
 
@@ -1163,6 +1312,17 @@ class _Recorder:
             )
         if self.zs is not None:
             self.zs.append(z)
+
+    def extend(self, iters, dist2, consensus, zs) -> None:
+        """Append records the device already reduced (``_record_scan``):
+        (R,) ``dist2`` (None without a root) and ``consensus``, and the
+        (R, N, D) snapshots ``zs`` when the recorder keeps them."""
+        self.iters.extend(int(it) for it in iters)
+        self.consensus.extend(consensus)
+        if self.z_star is not None:
+            self.dist2.extend(dist2)
+        if self.zs is not None:
+            self.zs.extend(zs)
 
     def arrays(self) -> tuple[np.ndarray, np.ndarray, np.ndarray, Any]:
         """(iters, dist2, consensus, zs) as numpy arrays.
@@ -1815,13 +1975,15 @@ def solve(
 
             mesh = make_node_mesh(n)
         hp_dyn = _dynamic_hp(spec, problem, hp)
-        idx_j = jnp.asarray(indices[:steps], jnp.int32)
+        idx = np.asarray(indices[:steps], np.int32)
         link_mask, _ = _static_fault_masks(plan, problem.graph, steps)
         fault_x = None
+        host_reads = None
         if link_mask is not None:
             # link-fault runner: every edge-color ppermute still executes
             # (measured bytes are identical); receivers drop masked edges
             # and redirect the lost mixing mass to their own iterate
+            idx_j = jnp.asarray(idx)
             frunner = _get_sharded_fault_runner(spec, problem, hp, mesh)
             lm = jnp.asarray(link_mask)
             state = frunner.init(jnp.asarray(z0))
@@ -1845,16 +2007,11 @@ def solve(
         else:
             runner = _get_sharded_runner(spec, problem, hp, mesh)
             state = runner.init(jnp.asarray(z0))
-            costs = runner.collective_costs(state, idx_j[: pts[0]], hp_dyn)
-            prev = 0
-            z_final = None
-            for pt in pts:
-                with obs.span("solve.run"):
-                    state = runner.chunk(state, idx_j[prev:pt], hp_dyn)
-                prev = pt
-                with obs.span("solve.readout"):
-                    z_final = runner.z_read(state, hp_dyn)
-                    rec.push(pt, z_final)
+            costs = runner.collective_costs(state, idx[: pts[0]], hp_dyn)
+            state, z_final, host_reads = _run_recorded(
+                runner, state, idx, hp_dyn, _record_ref(rec.z_star, dt),
+                _record_segments(pts, 0, None), 0, rec, keep_snapshots,
+            )
             wall = time.perf_counter() - t0
             iters, dist2, cons, zs = rec.arrays()
             per_node = dense_doubles_per_iter(problem.graph, D)  # (N,)
@@ -1868,6 +2025,8 @@ def solve(
             "collectives": costs,
             "mesh_devices": int(mesh.shape["node"]),
         }
+        if host_reads is not None:
+            extras["host_reads"] = host_reads
         if fault_x is not None:
             extras["faults"] = fault_x
         if sched_x is not None:
@@ -1895,12 +2054,13 @@ def solve(
     # ---- dense backend: cached compiled runner, hp as traced arguments ----
     t0 = time.perf_counter()
     hp_dyn = _dynamic_hp(spec, problem, hp)
-    idx_j = jnp.asarray(indices[:steps], jnp.int32)
+    idx = np.asarray(indices[:steps], np.int32)
     link_mask, strag_mask = _static_fault_masks(plan, problem.graph, steps)
 
     if link_mask is not None or strag_mask is not None:
         # fault-injecting runner: the per-iteration masks ride as scan
         # inputs; one compiled program per active-family STRUCTURE
+        idx_j = jnp.asarray(idx)
         frunner = _get_dense_fault_runner(
             spec, problem, hp,
             has_link=link_mask is not None,
@@ -1973,34 +2133,34 @@ def solve(
             state = jax.tree_util.tree_map(
                 lambda x: jnp.array(x, copy=True), state
             )
-    prev = start
-    z_final = None
-    for pt in pts:
-        if pt <= start:
-            continue  # already covered by the restored checkpoint
-        with obs.span("solve.run"):
-            state = runner.chunk(state, idx_j[prev:pt], hp_dyn)
-        prev = pt
-        with obs.span("solve.readout"):
-            z_final = runner.z_read(state, hp_dyn)
-            rec.push(pt, z_final)
-        if mgr is not None and pt % checkpoint.every == 0:
-            mgr.save(
-                pt, {"state": state},
-                metadata=_ckpt_meta(method, comm, record_every, rec),
-            )
+    save = None
+    if mgr is not None:
+
+        def save(pt, state):
+            if pt % checkpoint.every == 0:
+                mgr.save(
+                    pt, {"state": state},
+                    metadata=_ckpt_meta(method, comm, record_every, rec),
+                )
+
+    segments = _record_segments(
+        pts, start, None if mgr is None else checkpoint.every
+    )
+    state, z_final, host_reads = _run_recorded(
+        runner, state, idx, hp_dyn, _record_ref(rec.z_star, dt), segments,
+        start, rec, keep_snapshots, save,
+    )
     if mgr is not None:
         mgr.wait()
-    if z_final is None:
-        # resumed at (or past) the final record point: nothing to re-run
-        z_final = runner.z_read(state, hp_dyn)
     wall = time.perf_counter() - t0
 
     iters, dist2, cons, zs = rec.arrays()
     per_node = dense_doubles_per_iter(problem.graph, D)  # (N,)
     rounds = _cumulative_rounds(spec, hp, iters)
     doubles = rounds[:, None] * per_node[None, :]
-    extras = {} if sched_x is None else {"schedule": sched_x}
+    extras = {"host_reads": host_reads}
+    if sched_x is not None:
+        extras["schedule"] = sched_x
     if plan is not None and (want_link or want_strag):
         # p=0 plan: masks collapsed to the plain runner (bit-equal by
         # routing), but the delivered-vs-injected record is still reported
@@ -2552,7 +2712,7 @@ def solve_many(
     base_hp = dict(spec.defaults, **common_hp)
     runner = _get_dense_runner(spec, problem, base_hp)
     dyn_names = tuple(_dynamic_hp(spec, problem, base_hp))
-    chunk_b, z_read_b = _get_batched_fns(runner, dyn_names)
+    chunk_b, z_read_b, metrics_b = _get_batched_fns(runner, dyn_names)
 
     # hp arrays in the DATA dtype so batched arithmetic promotes exactly
     # like the sequential path's weak-typed python-float scalars
@@ -2574,13 +2734,19 @@ def solve_many(
     idx_j = jnp.asarray(idx_b[:, :steps], jnp.int32)
     pts = _record_points(steps, record_every)
     rec = _Recorder(problem.z_star, keep_snapshots)
+    ref = _record_ref(rec.z_star, dt)
     prev = 0
     z_final = None
     for pt in pts:
         state = chunk_b(state, idx_j[:, prev:pt], hp_dyn)
         prev = pt
         z_final = z_read_b(state, hp_dyn)
-        rec.push(pt, z_final)
+        # reduced on the device as solve() reduces, so grid entries stay
+        # bit-equal to their sequential solves
+        z_final, dist2, cons = jax.device_get(
+            (z_final, *metrics_b(z_final, ref))
+        )
+        rec.extend([pt], [dist2], [cons], [z_final])
     wall = time.perf_counter() - t0
 
     iters, dist2, cons, zs = rec.arrays()

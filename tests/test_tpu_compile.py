@@ -259,32 +259,23 @@ def test_relay_scan_keeps_its_ring_in_place_at_rcv1_shape(one_chip):
     assert not bad, bad
 
 
-def test_sharded_solver_chunk_measures_permutes_on_four_chips(topo):
-    """comm="sharded" on a v5e:2x2 node mesh: a 4-node ring's DSBA chunk
-    compiles, and the collective bytes measured from the TPU's HLO (whose
-    tiled layouts the parser must read) are 4 permutes of one iterate row
-    per iteration, as on the CPU."""
-    from repro.core import mixing, solvers as S
-    from repro.data.synthetic import make_regression
-    from repro.launch.hlo_analysis import compiled_collective_costs
+def _placed_dsba(runner_of, problem, sharding_of):
+    """(runner, state, hp_dyn, placed) for a DSBA runner (alpha 0.5) on
+    described devices: shapes only, each placed by ``sharding_of`` a
+    partition spec."""
+    from repro.core import solvers as S
 
-    n, d, iters = 4, 47236, 10
-    mesh = Mesh(np.array(topo.devices), ("node",),
-                axis_types=(AxisType.Auto,))
-    problem = S.make_problem(
-        "ridge", make_regression(n, 8, d, 74, seed=0, dtype=np.float32),
-        mixing.ring_graph(n),
-    )
     spec = S.get_solver("dsba")
     hp = {**spec.defaults, "alpha": 0.5}
-    runner = S._get_sharded_runner(spec, problem, hp, mesh)
+    runner = runner_of(spec, problem, hp)
+    n, d = problem.graph.n, problem.dim
     proto = jax.eval_shape(
         runner.init, jax.ShapeDtypeStruct((n, d), jnp.float32)
     )
 
-    def placed(shape, dtype, spec, weak=False):
+    def placed(shape, dtype, pspec, weak=False):
         return jax.ShapeDtypeStruct(
-            shape, dtype, sharding=NamedSharding(mesh, spec), weak_type=weak
+            shape, dtype, sharding=sharding_of(pspec), weak_type=weak
         )
 
     state = jax.tree.map(
@@ -296,8 +287,87 @@ def test_sharded_solver_chunk_measures_permutes_on_four_chips(topo):
                   weak=isinstance(v, float))
         for k, v in S._dynamic_hp(spec, problem, hp).items()
     }
+    return runner, state, hp_dyn, placed
+
+
+def _ring4(topo):
+    """The ridge_ring4 deployment's DSBA runner on a v5e:2x2 node mesh."""
+    from repro.core import mixing, solvers as S
+    from repro.data.synthetic import make_regression
+
+    n, d = 4, 47236
+    mesh = Mesh(np.array(topo.devices), ("node",),
+                axis_types=(AxisType.Auto,))
+    problem = S.make_problem(
+        "ridge", make_regression(n, 8, d, 74, seed=0, dtype=np.float32),
+        mixing.ring_graph(n),
+    )
+    return _placed_dsba(
+        lambda spec, pr, hp: S._get_sharded_runner(spec, pr, hp, mesh),
+        problem, lambda pspec: NamedSharding(mesh, pspec),
+    )
+
+
+def test_sharded_solver_chunk_measures_permutes_on_four_chips(topo):
+    """comm="sharded" on a v5e:2x2 node mesh: a 4-node ring's DSBA chunk
+    compiles, and the collective bytes measured from the TPU's HLO (whose
+    tiled layouts the parser must read) are 4 permutes of one iterate row
+    per iteration, as on the CPU."""
+    from repro.launch.hlo_analysis import compiled_collective_costs
+
+    n, d, iters = 4, 47236, 10
+    runner, state, hp_dyn, placed = _ring4(topo)
     idx = placed((iters, n), jnp.int32, P(None, "node"))
     compiled = runner.chunk.lower(state, idx, hp_dyn).compile()
     costs = compiled_collective_costs(compiled, iterations=iters)
     assert costs["count_by_op"] == {"collective-permute": 4.0}
     assert costs["bytes_per_iter"] == 4 * d * 4
+
+
+def test_sharded_record_program_on_four_chips(topo):
+    """ridge_ring4's whole solve as one record program (47 record points
+    of 6 iterations) on a v5e:2x2 node mesh: the chunk's 4 permutes an
+    iteration, and besides them only the record reductions, all-reduces
+    of at most one iterate row and two scalars a record point."""
+    from repro.launch.hlo_analysis import collective_stats
+
+    n, d, rec, every = 4, 47236, 47, 6
+    runner, state, hp_dyn, placed = _ring4(topo)
+    idx = placed((rec, every, n), jnp.int32, P(None, None, "node"))
+    ref = (placed((d,), jnp.float32, P()), placed((d,), jnp.float32, P()))
+    lowered = runner.record.lower(state, idx, hp_dyn, ref,
+                                  keep_snapshots=False)
+    _, dist2, cons, zs = lowered.out_info
+    assert dist2.shape == cons.shape == (rec,) and zs is None
+    stats = collective_stats(lowered.compile().as_text())
+    assert set(stats.count_by_op) == {"collective-permute", "all-reduce"}
+    assert stats.count_by_op["collective-permute"] == 4 * rec * every
+    assert stats.bytes_by_op["collective-permute"] == 4 * rec * every * d * 4
+    assert 1 <= stats.count_by_op["all-reduce"] / rec <= 3
+    assert stats.bytes_by_op["all-reduce"] / rec <= (d + 2) * 4
+
+
+def test_dense_record_program_on_one_chip(one_chip):
+    """ridge_rcv1's whole dense solve as one record program (120 record
+    points of 6 iterations, N = 10 on ER(0.4)) compiles for one v5e chip
+    and holds no collective."""
+    from repro.core import mixing, solvers as S
+    from repro.data.synthetic import make_regression
+    from repro.launch.hlo_analysis import collective_stats
+
+    n, d, rec, every = 10, 47236, 120, 6
+    problem = S.make_problem(
+        "ridge", make_regression(n, 100, d, 74, seed=0, dtype=np.float32),
+        mixing.erdos_renyi_graph(n, 0.4, seed=0), lam=1e-4,
+    )
+    runner, state, hp_dyn, placed = _placed_dsba(
+        S._get_dense_runner, problem, lambda pspec: one_chip
+    )
+    idx = placed((rec, every, n), jnp.int32, P())
+    ref = (placed((d,), jnp.float32, P()), placed((d,), jnp.float32, P()))
+    lowered = runner.record.lower(state, idx, hp_dyn, ref,
+                                  keep_snapshots=False)
+    _, dist2, cons, zs = lowered.out_info
+    assert dist2.shape == cons.shape == (rec,) and zs is None
+    assert dist2.dtype == cons.dtype == jnp.float32
+    assert not collective_stats(lowered.compile().as_text()).count_by_op
