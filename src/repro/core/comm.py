@@ -18,6 +18,14 @@ greedily edge-colored into matchings and each matching becomes ONE
 ``lax.ppermute`` carrying both directions, so a step moves O(deg) blocks
 per node — never O(N) — and the emitted ``collective-permute`` ops are
 measurable from HLO (``launch.hlo_analysis.collective_stats``).
+
+``FaultyDenseComm`` and ``FaultyShardedComm`` are the same two exchanges
+under a fault plan: link drops renormalized at the receiver, and (dense
+only) stragglers read from last-delivered buffers. Each backend has one
+runner builder (``core.solvers._get_runner``); it picks the faulty comm
+object only when a fault family is on and then feeds the per-iteration
+delivery masks in as scan inputs. Every dense product, faulty or not,
+runs at ``Precision.HIGHEST``.
 """
 from __future__ import annotations
 
@@ -181,11 +189,13 @@ class ShardedComm:
 class FaultyDenseComm(DenseComm):
     """DenseComm with link-drop masks and straggler delivery buffers.
 
-    The fault runner in ``core.solvers`` drives the trace-time context:
-    inside the scan body it calls ``begin_step(mask_t, deliv_t, bufs)``
-    before the solver step and ``end_step()`` after, so the ``mix``
-    closures (created once at factory time) read the CURRENT iteration's
-    masks and buffers as captured tracers.
+    A faulty runner in ``core.solvers`` (``_get_runner`` with a fault
+    family on) drives the trace-time context: inside the scan body it
+    calls ``begin_step(mask_t, deliv_t, bufs)`` before the solver step
+    and ``end_step()`` after, so the ``mix`` closures (created once at
+    factory time) read the CURRENT iteration's masks and buffers as
+    captured tracers. The masks are scan inputs and the buffers ride in
+    the scan carry beside the solver state.
 
     Link faults (``has_link``): ``mix`` becomes a masked matvec with
     row-renormalization — dropped neighbor entries are zeroed and their
@@ -218,7 +228,7 @@ class FaultyDenseComm(DenseComm):
         self._new_bufs: list = []
         self._slot = 0
 
-    # -- trace-time context driven by the fault runner ----------------------
+    # -- trace-time context driven by a faulty runner -----------------------
 
     def begin_probe(self) -> None:
         """Enter shape-probe mode: ``mix`` runs plain, ``_use`` records."""
@@ -261,16 +271,23 @@ class FaultyDenseComm(DenseComm):
         return x_used
 
     def matvec(self, m: np.ndarray, dtype) -> Callable[[jax.Array], jax.Array]:
-        """``mix(X) = M_eff(t) @ X_used(t)``: masked rows, buffered senders."""
+        """``mix(X) = M_eff(t) @ X_used(t)``: masked rows, buffered senders.
+
+        Every product runs at full precision, as ``DenseComm.matvec``'s
+        does: a fault run mixes at the same precision as a plain one.
+        """
         m_j = jnp.asarray(m, dtype)
         diag_j = jnp.asarray(np.diag(np.asarray(m)).copy(), dtype)
 
         def col(v, x):
             return v.reshape((-1,) + (1,) * (x.ndim - 1))
 
+        def mm(a, x):
+            return jnp.matmul(a, x, precision=lax.Precision.HIGHEST)
+
         def mix(x):
             if self._probing:
-                return m_j @ self._use(x)
+                return mm(m_j, self._use(x))
             x_used = self._use(x)
             if self.has_link:
                 mask = self._mask
@@ -278,9 +295,9 @@ class FaultyDenseComm(DenseComm):
                 kept = jnp.where(mask, m_j, zero)
                 dropped = jnp.where(mask, zero, m_j).sum(axis=1)
                 # dropped neighbor mass redirects to self — always fresh
-                out = kept @ x_used + col(dropped, x) * x
+                out = mm(kept, x_used) + col(dropped, x) * x
             else:
-                out = m_j @ x_used
+                out = mm(m_j, x_used)
             if self.has_straggler:
                 # the self term must read the fresh value, not the buffer
                 out = out + col(diag_j, x) * (x - x_used)
@@ -315,13 +332,16 @@ class FaultyShardedComm(ShardedComm):
             self.srcs.append(jnp.asarray(src, jnp.int32))
         self._mask = None
 
-    def begin_step(self, mask) -> None:
-        """Install this iteration's (N, N) delivery mask (scan-body call)."""
+    def begin_step(self, mask, deliv=None, bufs=()) -> None:
+        """Install this iteration's (N, N) delivery mask (scan-body call);
+        ``deliv``/``bufs`` are ``FaultyDenseComm``'s straggler inputs,
+        which this backend does not take."""
         self._mask = mask
 
-    def end_step(self) -> None:
-        """Clear the per-step mask (no carried buffers on this backend)."""
+    def end_step(self) -> tuple:
+        """Clear the per-step mask; no buffers are carried here."""
         self._mask = None
+        return ()
 
     def matvec(self, m: np.ndarray, dtype) -> Callable[[jax.Array], jax.Array]:
         """Masked, renormalized ``mix``: ppermute everything, keep delivered."""

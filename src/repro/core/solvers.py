@@ -20,9 +20,14 @@ keyed cache of compiled runners (``core.runner_cache``): the jitted chunked
 scan is compiled once per (method, comm, problem shape, static-hp
 structure) with hyperparameter *values* passed as traced arguments, so
 sweep-shaped experiments (bench_table1's lam grid, seed replications) pay
-XLA compilation once. ``core.dsba.run`` and
-``core.baselines.run_extra/run_dlm/run_ssda`` are thin deprecated shims
-delegating here, pinned trace-identical by ``tests/test_solvers.py``.
+XLA compilation once. The dense and sharded backends share one runner type
+and one builder (``_get_runner``); a fault plan's delivery masks are scan
+inputs of that runner, and every dense or sharded run — static, faulty,
+phased or batched — records on the device (``_record_scan``) and reads
+its records to the host once a segment (``_run_recorded``).
+``core.dsba.run`` and ``core.baselines.run_extra/run_dlm/run_ssda`` are
+thin deprecated shims delegating here, pinned trace-identical by
+``tests/test_solvers.py``.
 
 Every run returns the same ``SolveResult`` schema, including cumulative
 communicated DOUBLEs/ints per node: measured by the relay's closed-form
@@ -37,6 +42,7 @@ import dataclasses
 import functools
 import itertools
 import time
+import types
 from typing import Any, Callable, Mapping
 
 import jax
@@ -745,98 +751,62 @@ def _record_ref(z_star, dtype):
 
 
 @dataclasses.dataclass
-class _DenseRunner:
-    """One compiled dense-backend runner: chunked scan + iterate read-out.
+class _Runner:
+    """One compiled runner of the dense or the sharded backend.
 
-    ``chunk``/``z_read`` are the jitted entrypoints; ``record`` runs a
-    whole segment of record points in one program (``_record_scan``);
-    ``run_chunk``/``z_fn`` are the untraced callables kept for
-    ``solve_many`` to vmap (the batched variants compile lazily into
-    ``batched``, keyed by vmap signature).
+    Its programs take a carry, the per-iteration scan inputs ``xs`` and
+    the traced hyperparameters. The carry is the solver state, or
+    ``(state, bufs)`` when stragglers are on: ``bufs`` holds each ``mix``
+    call's last-delivered values (``comm.FaultyDenseComm``). ``xs`` is the
+    (T, N) index stream alone, or a tuple of it and the delivery masks of
+    the fault families that are on (``_scan_inputs``). Masks are runtime
+    inputs, like hyperparameter values, so one runner serves every drop
+    rate, seed and staleness bound of one fault structure.
+
+    ``chunk``, ``z_read`` and ``record`` are jitted, and on the sharded
+    backend wrapped in ``shard_map`` (``_shard_programs``); ``record`` runs
+    a whole segment of record points in one program (``_record_scan``).
+    ``record_fn``/``read_fn`` are the untraced dense bodies that
+    ``solve_many`` vmaps (compiled lazily into ``batched``). ``measured``
+    caches the chunk program's collective traffic by chunk length.
     """
 
-    init: Callable  # jitted (z0) -> state
-    run_chunk: Callable  # (state, idx_block, hp) -> state, untraced
-    z_fn: Callable  # (state, hp) -> (N, D), untraced
-    chunk: Callable  # jitted run_chunk (donated carry off-CPU)
-    z_read: Callable  # jitted z_fn
-    record: Callable  # jitted _record_scan (donated carry off-CPU)
+    init: Callable  # jitted (z0) -> carry
+    chunk: Callable  # jitted (carry, xs, hp) -> carry
+    z_read: Callable  # jitted (carry, hp) -> (N, D)
+    record: Callable  # jitted _record_scan
+    record_fn: Callable  # untraced record body
+    read_fn: Callable  # untraced z_read body
     donates: bool  # whether chunk and record donate their carry argument
+    bufs: Callable | None  # () -> zero straggler buffers; None without
+    measured: dict = dataclasses.field(default_factory=dict)
     batched: dict = dataclasses.field(default_factory=dict)
 
+    def carry_of(self, state):
+        """The carry that starts a phase at ``state``. Straggler buffers
+        start at zero: the delivery masks force a fresh send at a
+        phase's first iteration, so the zeros are never read."""
+        return state if self.bufs is None else (state, self.bufs())
 
-def _get_dense_runner(spec: SolverSpec, problem: Problem, hp: Mapping):
-    """Fetch (or compile) the dense runner for this (spec, problem, hp)."""
-    key, guards = _runner_key(spec, problem, hp)
+    def state_of(self, carry):
+        """The solver state inside ``carry``."""
+        return carry if self.bufs is None else carry[0]
 
-    def build() -> _DenseRunner:
-        comm = DenseComm(problem.graph)
-        fhp = _FactoryHP(hp, spec.static_hp)
-        step_fn = spec.step(problem, fhp, comm)
-        z_fn = spec.z_of(problem, fhp, comm)
-
-        def run_chunk(state, idx_block, hp_dyn):
-            runner_cache.DENSE.note_trace()  # trace-time only
-            st, _ = jax.lax.scan(
-                lambda s, i: (step_fn(s, i, hp_dyn), None), state, idx_block
-            )
-            return st
-
-        def read(state, hp_dyn):
-            runner_cache.DENSE.note_trace()
-            return z_fn(state, hp_dyn)
-
-        record = _record_scan(run_chunk, z_fn, _dense_metrics)
-        # donating the scan carry lets XLA reuse the state buffers in
-        # place; CPU does not implement donation (it would only warn)
-        donates = jax.default_backend() != "cpu"
-        donate = (0,) if donates else ()
-        return _DenseRunner(
-            init=jax.jit(lambda z0: spec.init(problem, fhp, z0)),
-            run_chunk=run_chunk,
-            z_fn=z_fn,
-            chunk=jax.jit(run_chunk, donate_argnums=donate),
-            z_read=jax.jit(read),
-            record=jax.jit(record, static_argnames=("keep_snapshots",),
-                           donate_argnums=donate),
-            donates=donates,
-        )
-
-    return runner_cache.DENSE.get_or_build(key, guards, build)
-
-
-@dataclasses.dataclass
-class _ShardedRunner:
-    """One compiled sharded-backend runner: shard_mapped scan + read-out.
-
-    ``chunk``/``z_read``/``record`` are jitted ``shard_map`` wrappers over
-    the same programs the dense runner compiles — the solver step itself
-    is shared; only the comm primitive differs (and ``record`` reduces
-    its metrics with ``psum``). ``measured`` caches the HLO-derived
-    per-iteration collective traffic of the chunk program, keyed by chunk
-    length (each distinct length is its own compiled program).
-    """
-
-    init: Callable  # jitted (z0) -> state (global (N, ...) leaves)
-    chunk: Callable  # jitted shard_map'd (state, idx_block, hp) -> state
-    z_read: Callable  # jitted shard_map'd (state, hp) -> (N, D)
-    record: Callable  # jitted shard_map'd _record_scan, psum reductions
-    mesh: Any
-    measured: dict = dataclasses.field(default_factory=dict)
-
-    def collective_costs(self, state, idx_block, hp_dyn) -> dict:
+    def collective_costs(self, carry, xs, hp_dyn) -> dict:
         """Per-iteration collective bytes/counts of this chunk's program.
 
         Lowers and compiles the chunk AOT once per chunk length and parses
         the optimized HLO (``launch.hlo_analysis``). The duplicate compile
         is absorbed by jax's persistent compilation cache
         (``launch.compile_cache``), enabled on ``import repro.core``.
+        A link-fault chunk still runs every edge-color ``ppermute`` (drops
+        happen at the receiver), so it measures what the plain one does.
         """
         from repro.launch.hlo_analysis import compiled_collective_costs
 
-        length = int(idx_block.shape[0])
+        length = int(jax.tree.leaves(xs)[0].shape[0])
         if length not in self.measured:
-            compiled = self.chunk.lower(state, idx_block, hp_dyn).compile()
+            compiled = self.chunk.lower(carry, xs, hp_dyn).compile()
             self.measured[length] = compiled_collective_costs(
                 compiled, iterations=length
             )
@@ -866,292 +836,185 @@ def _node_partition_specs(state_proto, n: int):
     return jax.tree_util.tree_map(spec_of, state_proto)
 
 
-def _get_sharded_runner(
-    spec: SolverSpec, problem: Problem, hp: Mapping, mesh
-):
-    """Fetch (or compile) the shard_map runner for (spec, problem, hp, mesh)."""
-    base_key, guards = _runner_key(spec, problem, hp)
-    key = base_key + (runner_cache.mesh_fingerprint(mesh),)
-
-    def build() -> _ShardedRunner:
-        comm = ShardedComm(problem.graph, mesh)
-        fhp = _FactoryHP(hp, spec.static_hp)
-        step_fn = spec.step(problem, fhp, comm)
-        z_fn = spec.z_of(problem, fhp, comm)
-        n, D = problem.graph.n, problem.dim
-        dt = problem.data.val.dtype
-
-        state_proto = jax.eval_shape(
-            lambda z: spec.init(problem, fhp, z),
-            jax.ShapeDtypeStruct((n, D), dt),
-        )
-        state_specs = _node_partition_specs(state_proto, n)
-        hp_specs = {k: P() for k in _dynamic_hp(spec, problem, hp)}
-
-        def run_chunk(state, idx_block, hp_dyn):
-            runner_cache.SHARDED.note_trace()  # trace-time only
-            st, _ = jax.lax.scan(
-                lambda s, i: (step_fn(s, i, hp_dyn), None), state, idx_block
-            )
-            return st
-
-        def read(state, hp_dyn):
-            runner_cache.SHARDED.note_trace()
-            return z_fn(state, hp_dyn)
-
-        chunk = jax.jit(
-            jax.shard_map(
-                run_chunk, mesh=mesh,
-                in_specs=(state_specs, P(None, "node"), hp_specs),
-                out_specs=state_specs,
-            )
-        )
-        z_read = jax.jit(
-            jax.shard_map(
-                read, mesh=mesh,
-                in_specs=(state_specs, hp_specs),
-                out_specs=P("node", None),
-            )
-        )
-        body = _record_scan(
-            run_chunk, z_fn,
-            lambda z, ref: _point_metrics(
-                z, ref, lambda x: jax.lax.psum(jnp.sum(x, 0), "node"), n
-            ),
-        )
-
-        def record(state, idx_blocks, hp_dyn, ref, keep_snapshots):
-            # the reductions are psums, so dist2 and consensus come out
-            # replicated; the root is replicated too
-            rec_spec = None if ref is None else P()
-            return jax.shard_map(
-                functools.partial(body, keep_snapshots=keep_snapshots),
-                mesh=mesh,
-                in_specs=(state_specs, P(None, None, "node"), hp_specs,
-                          rec_spec),
-                out_specs=(state_specs, rec_spec, P(),
-                           P(None, "node", None) if keep_snapshots else None),
-            )(state, idx_blocks, hp_dyn, ref)
-
-        return _ShardedRunner(
-            init=jax.jit(lambda z0: spec.init(problem, fhp, z0)),
-            chunk=chunk,
-            z_read=z_read,
-            record=jax.jit(record, static_argnames=("keep_snapshots",)),
-            mesh=mesh,
-        )
-
-    return runner_cache.SHARDED.get_or_build(key, (*guards, mesh), build)
+def _scan_inputs(idx, link_mask, strag_mask):
+    """A runner's per-iteration scan inputs: the (T, N) int32 index
+    stream alone, or a tuple of it, the (T, N, N) link mask and the
+    (T, N) straggler mask, each mask only when its family is on."""
+    idx = np.asarray(idx, np.int32)
+    masks = tuple(m for m in (link_mask, strag_mask) if m is not None)
+    return (idx, *masks) if masks else idx
 
 
-@dataclasses.dataclass
-class _DenseFaultRunner:
-    """One compiled fault-injecting dense runner.
+def _shard_programs(mesh, state_proto, hp_names, n_masks, chunk, read,
+                    record):
+    """``chunk``, ``z_read`` and ``record`` as ``shard_map`` programs.
 
-    The per-iteration delivery masks ride as scan inputs (like the
-    hyperparameter values ride as traced arguments), so ONE compiled
-    runner serves every drop rate, seed, and staleness bound of the same
-    fault STRUCTURE — only which families are active enters the cache
-    key (``runner_cache.fault_fingerprint``). Straggler last-delivered
-    buffers thread through the scan carry next to the solver state.
+    Leading-N state leaves and the index stream's node axis are split on
+    "node"; hyperparameters, masks and the root are replicated (each
+    device reads its own mask rows, ``comm.FaultyShardedComm``).
+    ``record``'s reductions are psums, so its records come out replicated.
     """
+    state_specs = _node_partition_specs(state_proto, mesh.shape["node"])
+    hp_specs = {k: P() for k in hp_names}
 
-    init: Callable  # (z0) -> (state, bufs), eager
-    chunk: Callable  # jitted (state, bufs, idx, mask, deliv, hp)
-    z_read: Callable  # jitted (state, hp) -> (N, D)
-    n_slots: int  # straggler buffer slots per iteration
-    make_bufs: Callable = None  # () -> fresh zero buffers (phase entry)
+    def xs_specs(lead):
+        idx = P(*([None] * lead), "node")
+        return (idx, *([P()] * n_masks)) if n_masks else idx
 
-
-def _get_dense_fault_runner(
-    spec: SolverSpec, problem: Problem, hp: Mapping,
-    *, has_link: bool, has_straggler: bool,
-):
-    """Fetch (or compile) the fault-injecting dense runner."""
-    base_key, guards = _runner_key(spec, problem, hp)
-    key = base_key + (
-        runner_cache.fault_fingerprint(has_link, has_straggler),
+    chunk = jax.shard_map(
+        chunk, mesh=mesh, in_specs=(state_specs, xs_specs(1), hp_specs),
+        out_specs=state_specs,
+    )
+    read = jax.shard_map(
+        read, mesh=mesh, in_specs=(state_specs, hp_specs),
+        out_specs=P("node", None),
     )
 
-    def build() -> _DenseFaultRunner:
-        comm = FaultyDenseComm(problem.graph, has_link, has_straggler)
+    def sharded_record(state, blocks, hp_dyn, ref, keep_snapshots):
+        rec_spec = None if ref is None else P()
+        return jax.shard_map(
+            functools.partial(record, keep_snapshots=keep_snapshots),
+            mesh=mesh,
+            in_specs=(state_specs, xs_specs(2), hp_specs, rec_spec),
+            out_specs=(state_specs, rec_spec, P(),
+                       P(None, "node", None) if keep_snapshots else None),
+        )(state, blocks, hp_dyn, ref)
+
+    return chunk, read, sharded_record
+
+
+def _get_runner(spec: SolverSpec, problem: Problem, hp: Mapping, *,
+                mesh=None, link: bool = False, straggler: bool = False):
+    """Fetch (or compile) the runner for (spec, problem, hp) on a backend.
+
+    ``mesh`` None is the dense backend, else the sharded one over that
+    ``"node"`` mesh. ``link``/``straggler`` say which fault families'
+    masks the runner takes (stragglers are dense-only). A runner with
+    neither is the plain program: the plain comm object, no mask inputs,
+    the bare state as carry, and the plan-free cache key.
+    """
+    key, guards = _runner_key(spec, problem, hp)
+    cache = runner_cache.DENSE
+    if mesh is not None:
+        cache = runner_cache.SHARDED
+        key += (runner_cache.mesh_fingerprint(mesh),)
+        guards = (*guards, mesh)
+    faulty = link or straggler
+    if faulty:
+        key += (runner_cache.fault_fingerprint(link, straggler),)
+
+    def build() -> _Runner:
+        g = problem.graph
+        if mesh is None:
+            comm = FaultyDenseComm(g, link, straggler) if faulty else DenseComm(g)
+        else:
+            comm = FaultyShardedComm(g, mesh) if faulty else ShardedComm(g, mesh)
         fhp = _FactoryHP(hp, spec.static_hp)
         step_fn = spec.step(problem, fhp, comm)
         z_fn = spec.z_of(problem, fhp, comm)
-        n, D = problem.graph.n, problem.dim
-        dt = problem.data.val.dtype
-
-        # abstract probe: discover the straggler buffer slot shapes (one
-        # per matvec invocation in the step) before assembling the carry
-        comm.begin_probe()
-        hp_probe = _dynamic_hp(spec, problem, hp)
+        hp0 = _dynamic_hp(spec, problem, hp)
         state_proto = jax.eval_shape(
             lambda z: spec.init(problem, fhp, z),
-            jax.ShapeDtypeStruct((n, D), dt),
+            jax.ShapeDtypeStruct((g.n, problem.dim), problem.data.val.dtype),
         )
-        jax.eval_shape(
-            lambda s, i: step_fn(s, i, hp_probe),
-            state_proto,
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-        )
-        slots = comm.end_probe()
+        bufs = None
+        if straggler:
+            # an abstract step finds the buffer slots, one a mix call
+            comm.begin_probe()
+            jax.eval_shape(
+                lambda s, i: step_fn(s, i, hp0),
+                state_proto, jax.ShapeDtypeStruct((g.n,), jnp.int32),
+            )
+            slots = comm.end_probe()
 
-        def make_bufs():
-            # buffers start at the t=0 "last delivered" convention: the
-            # delivery masks force a fresh send on each node's first
-            # iteration, so these zeros are never read
-            return tuple(jnp.zeros(s.shape, s.dtype) for s in slots)
+            def bufs():
+                return tuple(jnp.zeros(s.shape, s.dtype) for s in slots)
+
+        def step(carry, x, hp_dyn):
+            if not faulty:
+                return step_fn(carry, x, hp_dyn)
+            i_t, *masks = x
+            st, bf = carry if straggler else (carry, ())
+            comm.begin_step(masks[0] if link else None,
+                            masks[-1] if straggler else None, bf)
+            st = step_fn(st, i_t, hp_dyn)
+            bf = comm.end_step()
+            return (st, bf) if straggler else st
+
+        def run_chunk(carry, xs, hp_dyn):
+            cache.note_trace()  # trace-time only
+            carry, _ = jax.lax.scan(
+                lambda c, x: (step(c, x, hp_dyn), None), carry, xs
+            )
+            return carry
+
+        def z_of(carry, hp_dyn):
+            return z_fn(carry[0] if straggler else carry, hp_dyn)
+
+        def read(carry, hp_dyn):
+            cache.note_trace()
+            return z_of(carry, hp_dyn)
+
+        if mesh is None:
+            metrics = _dense_metrics
+        else:
+            def metrics(z, ref):
+                return _point_metrics(
+                    z, ref, lambda x: jax.lax.psum(jnp.sum(x, 0), "node"), g.n
+                )
+        record_fn = _record_scan(run_chunk, z_of, metrics)
+        chunk, z_read, record = run_chunk, read, record_fn
+        if mesh is not None:
+            chunk, z_read, record = _shard_programs(
+                mesh, state_proto, tuple(hp0), int(link), chunk, z_read, record
+            )
+        # donating the carry lets XLA reuse the state buffers in place;
+        # CPU does not implement donation (it would only warn)
+        donates = jax.default_backend() != "cpu"
+        donate = (0,) if donates else ()
 
         def init(z0):
-            return spec.init(problem, fhp, z0), make_bufs()
+            state = spec.init(problem, fhp, z0)
+            return state if bufs is None else (state, bufs())
 
-        def run_chunk(state, bufs, idx_block, mask_block, deliv_block,
-                      hp_dyn):
-            runner_cache.DENSE.note_trace()  # trace-time only
-
-            def body(carry, xs):
-                st, bf = carry
-                i_t, mask_t, deliv_t = xs
-                comm.begin_step(mask_t, deliv_t, bf)
-                st2 = step_fn(st, i_t, hp_dyn)
-                return (st2, comm.end_step()), None
-
-            (st, bf), _ = jax.lax.scan(
-                body, (state, bufs), (idx_block, mask_block, deliv_block)
-            )
-            return st, bf
-
-        def read(state, hp_dyn):
-            runner_cache.DENSE.note_trace()
-            return z_fn(state, hp_dyn)
-
-        return _DenseFaultRunner(
-            init=init,
-            chunk=jax.jit(run_chunk),
-            z_read=jax.jit(read),
-            n_slots=len(slots),
-            make_bufs=make_bufs,
+        return _Runner(
+            init=jax.jit(init),
+            chunk=jax.jit(chunk, donate_argnums=donate),
+            z_read=jax.jit(z_read),
+            record=jax.jit(record, static_argnames=("keep_snapshots",),
+                           donate_argnums=donate),
+            record_fn=record_fn,
+            read_fn=read,
+            donates=donates,
+            bufs=bufs,
         )
 
-    return runner_cache.DENSE.get_or_build(key, guards, build)
+    return cache.get_or_build(key, guards, build)
 
 
-@dataclasses.dataclass
-class _ShardedFaultRunner:
-    """Sharded runner with a per-iteration link-delivery mask scan input.
+def _batched(runner: _Runner, dyn_names):
+    """The dense runner's ``record`` and ``z_read`` vmapped over a leading
+    (grid/seed) axis of the carry, cached by hp signature.
 
-    Every edge-color ``ppermute`` still executes physically (dropping at
-    the receiver), so the HLO-measured collective traffic is identical to
-    the fault-free program; only the modeled ``doubles_received`` counts
-    delivered messages (see ``comm.FaultyShardedComm``).
-    """
-
-    init: Callable
-    chunk: Callable  # jitted shard_map'd (state, idx, mask, hp) -> state
-    z_read: Callable
-    mesh: Any
-    measured: dict = dataclasses.field(default_factory=dict)
-
-    def collective_costs(self, state, idx_block, mask_block, hp_dyn) -> dict:
-        """Per-iteration collective bytes/counts (same as fault-free)."""
-        from repro.launch.hlo_analysis import compiled_collective_costs
-
-        length = int(idx_block.shape[0])
-        if length not in self.measured:
-            compiled = self.chunk.lower(
-                state, idx_block, mask_block, hp_dyn
-            ).compile()
-            self.measured[length] = compiled_collective_costs(
-                compiled, iterations=length
-            )
-        return self.measured[length]
-
-
-def _get_sharded_fault_runner(
-    spec: SolverSpec, problem: Problem, hp: Mapping, mesh
-):
-    """Fetch (or compile) the link-fault shard_map runner."""
-    base_key, guards = _runner_key(spec, problem, hp)
-    key = base_key + (
-        runner_cache.mesh_fingerprint(mesh),
-        runner_cache.fault_fingerprint(True, False),
-    )
-
-    def build() -> _ShardedFaultRunner:
-        comm = FaultyShardedComm(problem.graph, mesh)
-        fhp = _FactoryHP(hp, spec.static_hp)
-        step_fn = spec.step(problem, fhp, comm)
-        z_fn = spec.z_of(problem, fhp, comm)
-        n, D = problem.graph.n, problem.dim
-        dt = problem.data.val.dtype
-
-        state_proto = jax.eval_shape(
-            lambda z: spec.init(problem, fhp, z),
-            jax.ShapeDtypeStruct((n, D), dt),
-        )
-        state_specs = _node_partition_specs(state_proto, n)
-        hp_specs = {k: P() for k in _dynamic_hp(spec, problem, hp)}
-
-        def run_chunk(state, idx_block, mask_block, hp_dyn):
-            runner_cache.SHARDED.note_trace()  # trace-time only
-
-            def body(st, xs):
-                i_t, mask_t = xs
-                comm.begin_step(mask_t)
-                st2 = step_fn(st, i_t, hp_dyn)
-                comm.end_step()
-                return st2, None
-
-            st, _ = jax.lax.scan(body, state, (idx_block, mask_block))
-            return st
-
-        def read(state, hp_dyn):
-            runner_cache.SHARDED.note_trace()
-            return z_fn(state, hp_dyn)
-
-        # the mask is replicated: each device reads its own row inside
-        # the matvec via comm.local (see FaultyShardedComm)
-        chunk = jax.jit(
-            jax.shard_map(
-                run_chunk, mesh=mesh,
-                in_specs=(
-                    state_specs, P(None, "node"), P(None, None, None),
-                    hp_specs,
-                ),
-                out_specs=state_specs,
-            )
-        )
-        z_read = jax.jit(
-            jax.shard_map(
-                read, mesh=mesh,
-                in_specs=(state_specs, hp_specs),
-                out_specs=P("node", None),
-            )
-        )
-        return _ShardedFaultRunner(
-            init=lambda z0: spec.init(problem, fhp, z0),
-            chunk=chunk,
-            z_read=z_read,
-            mesh=mesh,
-        )
-
-    return runner_cache.SHARDED.get_or_build(key, (*guards, mesh), build)
-
-
-def _get_batched_fns(runner: _DenseRunner, dyn_names) -> tuple:
-    """(chunk, z_read, metrics) vmapped over a leading (grid/seed) axis,
-    cached (``metrics`` is the solve() recorder's device reduction).
-
-    hp entries map over axis 0 except ``lam`` (problem-level, shared);
-    state and the index stream always carry the batch axis.
+    hp entries map over axis 0 except ``lam`` (problem-level, shared). The
+    scan inputs are time-major, (T, B, N), so a block of them maps over
+    axis 2; the records come out record-major, (R, B), as
+    ``_Recorder.extend`` takes them.
     """
     sig = tuple(sorted(dyn_names))
     if sig not in runner.batched:
         hp_axes = {k: (None if k == "lam" else 0) for k in sig}
-        runner.batched[sig] = (
-            jax.jit(jax.vmap(runner.run_chunk, in_axes=(0, 0, hp_axes))),
-            jax.jit(jax.vmap(runner.z_fn, in_axes=(0, hp_axes))),
-            jax.jit(jax.vmap(_dense_metrics, in_axes=(0, None))),
+
+        def record(state, blocks, hp_dyn, ref, keep_snapshots):
+            return jax.vmap(
+                functools.partial(runner.record_fn,
+                                  keep_snapshots=keep_snapshots),
+                in_axes=(0, 2, hp_axes, None), out_axes=(0, 1, 1, 1),
+            )(state, blocks, hp_dyn, ref)
+
+        runner.batched[sig] = types.SimpleNamespace(
+            record=jax.jit(record, static_argnames=("keep_snapshots",)),
+            z_read=jax.jit(jax.vmap(runner.read_fn, in_axes=(0, hp_axes))),
         )
     return runner.batched[sig]
 
@@ -1235,15 +1098,25 @@ def _record_segments(pts: list[int], start: int, every: int | None):
     return segments
 
 
-def _run_recorded(runner, state, indices, hp_dyn, ref, segments, start, rec,
+def _blocks(xs, lo: int, r: int, length: int):
+    """Iterations [lo, lo + r * length) of every scan input, as r blocks
+    of ``length`` iterations."""
+    return jax.tree.map(
+        lambda a: a[lo:lo + r * length].reshape(r, length, *a.shape[1:]), xs
+    )
+
+
+def _run_recorded(runner, carry, xs, hp_dyn, ref, segments, start, rec,
                   keep_snapshots, save=None):
     """Run ``segments`` of record points through ``runner.record``.
 
     Each segment is one ``record`` call over its chunks of equal length
     (one more, with R = 1, for a shorter final chunk), then ONE read of its
     records to the host; the last segment's read brings the final iterates
-    too. ``indices`` is the host's (steps, N) int32 stream; ``save(pt,
-    state)`` runs after each segment. Returns ``(state, z, host_reads)``.
+    too. ``xs`` holds the host's scan inputs (``_scan_inputs``) from
+    iteration ``start`` on; each call takes its blocks of every input.
+    ``save(pt, carry)`` runs after each segment. Returns ``(carry, z,
+    host_reads)``.
     """
     prev, z_final = start, None
     for k, seg in enumerate(segments):
@@ -1252,36 +1125,48 @@ def _run_recorded(runner, state, indices, hp_dyn, ref, segments, start, rec,
             i = 0
             for length, group in itertools.groupby(np.diff([prev, *seg])):
                 r = len(list(group))
-                blocks = indices[prev:prev + r * length].reshape(r, length, -1)
-                state, *out = runner.record(
-                    state, blocks, hp_dyn, ref, keep_snapshots=keep_snapshots
+                blocks = _blocks(xs, prev - start, r, length)
+                carry, *out = runner.record(
+                    carry, blocks, hp_dyn, ref, keep_snapshots=keep_snapshots
                 )
                 calls.append((seg[i:i + r], out))
                 i += r
                 prev += r * length
             z_dev = None
             if k == len(segments) - 1:
-                z_dev = runner.z_read(state, hp_dyn)
+                z_dev = runner.z_read(carry, hp_dyn)
         with obs.span("solve.readout"):
             outs, z_final = jax.device_get(([o for _, o in calls], z_dev))
             for (its, _), (dist2, cons, zs) in zip(calls, outs):
                 rec.extend(its, dist2, cons, zs)
         if save is not None:
-            save(seg[-1], state)
+            save(seg[-1], carry)
     if not segments:
         # resumed at (or past) the final record point: nothing to re-run
         with obs.span("solve.readout"):
-            z_final = jax.device_get(runner.z_read(state, hp_dyn))
-    return state, z_final, max(len(segments), 1)
+            z_final = jax.device_get(runner.z_read(carry, hp_dyn))
+    return carry, z_final, max(len(segments), 1)
+
+
+def _owned(runner: _Runner, carry):
+    """``carry`` with every leaf copied when the runner donates its carry.
+
+    An init may alias leaves (dsba's z and z_prev are one array at t=0),
+    and so may an elastic remap; donation rejects duplicate buffers. The
+    carries a runner returns are distinct scan outputs.
+    """
+    if not runner.donates:
+        return carry
+    return jax.tree_util.tree_map(lambda x: jnp.array(x, copy=True), carry)
 
 
 class _Recorder:
     """The one metrics recorder shared by every method and comm backend.
 
-    Replaces the per-method metric loops the legacy entrypoints each
-    reimplemented (``core.dsba.run``'s chunked loop, ``baselines``'
-    ``_metrics_loop``): push (iteration, iterates) pairs, read back the
-    uniform record arrays.
+    The dense and sharded runners reduce their records on the device and
+    ``extend`` it with them; the relay paths, which return every iterate
+    (``z_trace``), ``push`` (iteration, iterates) pairs for it to reduce
+    in NumPy. Either way it reads back the uniform record arrays.
     """
 
     def __init__(self, z_star: np.ndarray | None, keep_snapshots: bool):
@@ -1323,6 +1208,12 @@ class _Recorder:
             self.dist2.extend(dist2)
         if self.zs is not None:
             self.zs.extend(zs)
+
+    def drop_last(self) -> None:
+        """Forget the newest record (a phase end that is no record point)."""
+        for vals in (self.iters, self.dist2, self.consensus, self.zs):
+            if vals:
+                vals.pop()
 
     def arrays(self) -> tuple[np.ndarray, np.ndarray, np.ndarray, Any]:
         """(iters, dist2, consensus, zs) as numpy arrays.
@@ -1966,188 +1857,58 @@ def solve(
             opts, sched_x, plan,
         )
 
+    # ---- dense and sharded: one cached runner, hp and masks as inputs ----
+    t0 = time.perf_counter()
+    mesh = None
     if comm == "sharded":
-        # ---- sharded backend: shard_map runner, measured collectives -----
         mesh = opts.pop("mesh", None)
-        t0 = time.perf_counter()
         if mesh is None:
             from repro.launch.mesh import make_node_mesh
 
             mesh = make_node_mesh(n)
-        hp_dyn = _dynamic_hp(spec, problem, hp)
-        idx = np.asarray(indices[:steps], np.int32)
-        link_mask, _ = _static_fault_masks(plan, problem.graph, steps)
-        fault_x = None
-        host_reads = None
-        if link_mask is not None:
-            # link-fault runner: every edge-color ppermute still executes
-            # (measured bytes are identical); receivers drop masked edges
-            # and redirect the lost mixing mass to their own iterate
-            idx_j = jnp.asarray(idx)
-            frunner = _get_sharded_fault_runner(spec, problem, hp, mesh)
-            lm = jnp.asarray(link_mask)
-            state = frunner.init(jnp.asarray(z0))
-            costs = frunner.collective_costs(
-                state, idx_j[: pts[0]], lm[: pts[0]], hp_dyn
-            )
-            prev = 0
-            z_final = None
-            for pt in pts:
-                state = frunner.chunk(
-                    state, idx_j[prev:pt], lm[prev:pt], hp_dyn
-                )
-                prev = pt
-                z_final = frunner.z_read(state, hp_dyn)
-                rec.push(pt, z_final)
-            wall = time.perf_counter() - t0
-            iters, dist2, cons, zs = rec.arrays()
-            doubles, fault_x = _fault_accounting(
-                spec, hp, problem, link_mask, None, steps, iters
-            )
-        else:
-            runner = _get_sharded_runner(spec, problem, hp, mesh)
-            state = runner.init(jnp.asarray(z0))
-            costs = runner.collective_costs(state, idx[: pts[0]], hp_dyn)
-            state, z_final, host_reads = _run_recorded(
-                runner, state, idx, hp_dyn, _record_ref(rec.z_star, dt),
-                _record_segments(pts, 0, None), 0, rec, keep_snapshots,
-            )
-            wall = time.perf_counter() - t0
-            iters, dist2, cons, zs = rec.arrays()
-            per_node = dense_doubles_per_iter(problem.graph, D)  # (N,)
-            rounds = _cumulative_rounds(spec, hp, iters)
-            doubles = rounds[:, None] * per_node[None, :]
-            if plan is not None and want_link:
-                _, fault_x = _fault_accounting(
-                    spec, hp, problem, None, None, steps, iters
-                )
-        extras = {
-            "collectives": costs,
-            "mesh_devices": int(mesh.shape["node"]),
-        }
-        if host_reads is not None:
-            extras["host_reads"] = host_reads
-        if fault_x is not None:
-            extras["faults"] = fault_x
-        if sched_x is not None:
-            extras["schedule"] = sched_x
-        return SolveResult(
-            method=method,
-            comm=comm,
-            iters=iters,
-            dist2=dist2,
-            consensus=cons,
-            doubles_received=doubles,
-            ints_received=np.zeros_like(doubles),
-            wall_time=wall,
-            z=np.asarray(z_final),
-            state=state,
-            zs=zs,
-            extras=extras,
-            # per-program measurement: collectives inside a traced-bound
-            # inner loop (mudag's K gossip rounds) are counted once per
-            # outer iteration — the modeled `doubles_received` carries the
-            # K-aware accounting (docs/solvers.md)
-            measured_collective_bytes=iters * costs["bytes_per_iter"],
-        )
-
-    # ---- dense backend: cached compiled runner, hp as traced arguments ----
-    t0 = time.perf_counter()
     hp_dyn = _dynamic_hp(spec, problem, hp)
-    idx = np.asarray(indices[:steps], np.int32)
+    # all-delivered masks collapse to None: a p=0 plan runs the plain
+    # program, bit-equal to a plan-free run by construction
     link_mask, strag_mask = _static_fault_masks(plan, problem.graph, steps)
-
-    if link_mask is not None or strag_mask is not None:
-        # fault-injecting runner: the per-iteration masks ride as scan
-        # inputs; one compiled program per active-family STRUCTURE
-        idx_j = jnp.asarray(idx)
-        frunner = _get_dense_fault_runner(
-            spec, problem, hp,
-            has_link=link_mask is not None,
-            has_straggler=strag_mask is not None,
-        )
-        lm = (
-            jnp.asarray(link_mask)
-            if link_mask is not None
-            else jnp.ones((steps, 1, 1), bool)  # inert placeholder xs
-        )
-        sm = (
-            jnp.asarray(strag_mask)
-            if strag_mask is not None
-            else jnp.ones((steps, 1), bool)
-        )
-        state, bufs = frunner.init(jnp.asarray(z0))
-        prev = 0
-        z_final = None
-        for pt in pts:
-            state, bufs = frunner.chunk(
-                state, bufs, idx_j[prev:pt], lm[prev:pt], sm[prev:pt],
-                hp_dyn,
-            )
-            prev = pt
-            z_final = frunner.z_read(state, hp_dyn)
-            rec.push(pt, z_final)
-        wall = time.perf_counter() - t0
-        iters, dist2, cons, zs = rec.arrays()
-        doubles, fault_x = _fault_accounting(
-            spec, hp, problem, link_mask, strag_mask, steps, iters
-        )
-        extras = {"faults": fault_x}
-        if sched_x is not None:
-            extras["schedule"] = sched_x
-        return SolveResult(
-            method=method,
-            comm=comm,
-            iters=iters,
-            dist2=dist2,
-            consensus=cons,
-            doubles_received=doubles,
-            ints_received=np.zeros_like(doubles),
-            wall_time=wall,
-            z=np.asarray(z_final),
-            state=state,
-            zs=zs,
-            extras=extras,
-        )
-
-    runner = _get_dense_runner(spec, problem, hp)
+    runner = _get_runner(
+        spec, problem, hp, mesh=mesh,
+        link=link_mask is not None, straggler=strag_mask is not None,
+    )
     mgr = None
     if checkpoint is not None:
         mgr = CheckpointManager(
             checkpoint.directory, keep_last=checkpoint.keep_last
         )
     start = 0
-    state = None
+    carry = None
     if resume is not None:
-        state, start = _restore_dense(
+        carry, start = _restore_dense(
             resume, runner, rec, method=method, comm=comm,
             record_every=record_every, steps=steps, z0=z0,
         )
-    if state is None:
-        state = runner.init(jnp.asarray(z0))
-        if runner.donates:
-            # init factories may alias leaves (dsba's z/z_prev are the same
-            # array at t=0); donation rejects duplicate buffers, so de-alias
-            # the initial carry once — later carries are distinct scan
-            # outputs
-            state = jax.tree_util.tree_map(
-                lambda x: jnp.array(x, copy=True), state
-            )
+    if carry is None:
+        carry = _owned(runner, runner.init(jnp.asarray(z0)))
+    xs = _scan_inputs(indices[start:steps], link_mask, strag_mask)
+    costs = None
+    if mesh is not None:
+        costs = runner.collective_costs(
+            carry, jax.tree.map(lambda a: a[:pts[0]], xs), hp_dyn
+        )
     save = None
     if mgr is not None:
 
-        def save(pt, state):
+        def save(pt, carry):
             if pt % checkpoint.every == 0:
                 mgr.save(
-                    pt, {"state": state},
+                    pt, {"state": carry},
                     metadata=_ckpt_meta(method, comm, record_every, rec),
                 )
 
     segments = _record_segments(
         pts, start, None if mgr is None else checkpoint.every
     )
-    state, z_final, host_reads = _run_recorded(
-        runner, state, idx, hp_dyn, _record_ref(rec.z_star, dt), segments,
+    carry, z_final, host_reads = _run_recorded(
+        runner, carry, xs, hp_dyn, _record_ref(rec.z_star, dt), segments,
         start, rec, keep_snapshots, save,
     )
     if mgr is not None:
@@ -2155,19 +1916,31 @@ def solve(
     wall = time.perf_counter() - t0
 
     iters, dist2, cons, zs = rec.arrays()
-    per_node = dense_doubles_per_iter(problem.graph, D)  # (N,)
-    rounds = _cumulative_rounds(spec, hp, iters)
-    doubles = rounds[:, None] * per_node[None, :]
     extras = {"host_reads": host_reads}
+    if link_mask is not None or strag_mask is not None:
+        doubles, extras["faults"] = _fault_accounting(
+            spec, hp, problem, link_mask, strag_mask, steps, iters
+        )
+    else:
+        per_node = dense_doubles_per_iter(problem.graph, D)  # (N,)
+        rounds = _cumulative_rounds(spec, hp, iters)
+        doubles = rounds[:, None] * per_node[None, :]
+        if want_link or want_strag:
+            # a p=0 plan still reports its delivered-vs-injected record
+            _, extras["faults"] = _fault_accounting(
+                spec, hp, problem, None, None, steps, iters
+            )
     if sched_x is not None:
         extras["schedule"] = sched_x
-    if plan is not None and (want_link or want_strag):
-        # p=0 plan: masks collapsed to the plain runner (bit-equal by
-        # routing), but the delivered-vs-injected record is still reported
-        _, fault_x = _fault_accounting(
-            spec, hp, problem, None, None, steps, iters
-        )
-        extras["faults"] = fault_x
+    measured = None
+    if mesh is not None:
+        extras["collectives"] = costs
+        extras["mesh_devices"] = int(mesh.shape["node"])
+        # per-program measurement: collectives inside a traced-bound inner
+        # loop (mudag's K gossip rounds) are counted once per outer
+        # iteration — the modeled `doubles_received` carries the K-aware
+        # accounting (docs/solvers.md)
+        measured = iters * costs["bytes_per_iter"]
     return SolveResult(
         method=method,
         comm=comm,
@@ -2178,9 +1951,10 @@ def solve(
         ints_received=np.zeros_like(doubles),
         wall_time=wall,
         z=np.asarray(z_final),
-        state=state,
+        state=runner.state_of(carry),
         zs=zs,
         extras=extras,
+        measured_collective_bytes=measured,
     )
 
 
@@ -2223,24 +1997,29 @@ def _solve_phased(
     """Dense/sharded execution of a multi-phase (dynamic-network) run.
 
     Each phase runs through its own cached runner (edge colorings /
-    meshes re-derived per phase); the solver state is carried across
-    boundaries — as-is for a W switch (restart-on-new-W,
-    docs/algorithm.md), elastically remapped for churn. Communication
-    accounting folds per-phase increments into global per-row cumulative
-    counts: rows are the N0 original nodes plus one row per joined node
-    (``extras["churn_rows"]`` when membership changed).
+    meshes re-derived per phase) and records on the device: one
+    ``_run_recorded`` segment over the phase's record points, against the
+    phase's own root, and one host read. A phase end that is no record
+    point closes the segment and its record is dropped. The solver state
+    is carried across boundaries — as-is for a W switch
+    (restart-on-new-W, docs/algorithm.md), elastically remapped for
+    churn. Communication accounting folds per-phase increments into
+    global per-row cumulative counts: rows are the N0 original nodes plus
+    one row per joined node (``extras["churn_rows"]`` when membership
+    changed).
 
     ``plan``: an optional ``FaultPlan`` whose link/straggler families
     compose with the churn phases — each phase resolves its own delivery
     masks against the phase graph (seeds fold the phase's global start
     iteration, so the mask stream is one continuous draw), straggler
-    buffers re-zero at membership boundaries (the first post-churn
-    iteration always delivers fresh), and the delivered-only accounting
-    folds into the same per-row cumulative counts.
+    buffers re-zero at each phase entry (``_Runner.carry_of``), and the
+    delivered-only accounting folds into the same per-row cumulative
+    counts.
     """
     t0 = time.perf_counter()
     base = phases[0].problem
     D = base.dim
+    dt = base.data.val.dtype
     total_rows = max(int(ph.row_map.max()) for ph in phases) + 1
     record_set = set(pts)
     cum = np.zeros(total_rows)
@@ -2249,25 +2028,18 @@ def _solve_phased(
     measured_base = 0.0
     costs0 = None
     mesh_opt = opts.get("mesh")
-    mesh_devices = None
+    mesh = None
     state = None
-    bufs = None
     z_final = None
     n_prev = base.graph.n
     injected_tot = delivered_tot = 0
-    want_fault = plan is not None and (
-        plan.link is not None or plan.straggler is not None
-    )
     for ph in phases:
         p = ph.problem
         n_ph = p.graph.n
         seg = ph.end - ph.start
-        if state is not None:
-            state = _elastic_remap(state, ph, n_prev, spec)
         link_mask, strag_mask = _static_fault_masks(
             plan, p.graph, seg, start=ph.start
         )
-        faulty = link_mask is not None or strag_mask is not None
         if comm == "sharded":
             if mesh_opt is not None and mesh_opt.shape["node"] == n_ph:
                 mesh = mesh_opt
@@ -2275,46 +2047,39 @@ def _solve_phased(
                 from repro.launch.mesh import make_node_mesh
 
                 mesh = make_node_mesh(n_ph)
-            if faulty:
-                runner = _get_sharded_fault_runner(spec, p, hp, mesh)
-            else:
-                runner = _get_sharded_runner(spec, p, hp, mesh)
-            if mesh_devices is None:
-                mesh_devices = int(mesh.shape["node"])
-        elif faulty:
-            runner = _get_dense_fault_runner(
-                spec, p, hp,
-                has_link=link_mask is not None,
-                has_straggler=strag_mask is not None,
-            )
-        else:
-            runner = _get_dense_runner(spec, p, hp)
+        runner = _get_runner(
+            spec, p, hp, mesh=mesh,
+            link=link_mask is not None, straggler=strag_mask is not None,
+        )
         hp_dyn = _dynamic_hp(spec, p, hp)
         if state is None:
-            if comm == "dense" and faulty:
-                state, bufs = runner.init(jnp.asarray(z0))
-            else:
-                state = runner.init(jnp.asarray(z0))
-            if comm == "dense" and not faulty and runner.donates:
-                state = jax.tree_util.tree_map(
-                    lambda x: jnp.array(x, copy=True), state
-                )
-        elif comm == "dense" and faulty:
-            # straggler buffers do not survive membership remaps; the
-            # phase's delivery masks force fresh sends at its first
-            # iteration, so re-zeroed buffers are never read
-            bufs = runner.make_bufs()
-        if faulty:
-            lm_ph = (
-                jnp.asarray(link_mask)
-                if link_mask is not None
-                else jnp.ones((seg, 1, 1), bool)
+            carry = runner.init(jnp.asarray(z0))
+        else:
+            carry = runner.carry_of(_elastic_remap(state, ph, n_prev, spec))
+        carry = _owned(runner, carry)
+        xs = _scan_inputs(
+            indices[ph.start:ph.end][:, ph.cols], link_mask, strag_mask
+        )
+        marks = [pt for pt in pts if ph.start < pt <= ph.end]
+        if ph.end not in record_set:
+            marks.append(ph.end)
+        costs = None
+        if mesh is not None:
+            costs = runner.collective_costs(
+                carry,
+                jax.tree.map(lambda a: a[:marks[0] - ph.start], xs),
+                hp_dyn,
             )
-            sm_ph = (
-                jnp.asarray(strag_mask)
-                if strag_mask is not None
-                else jnp.ones((seg, 1), bool)
-            )
+            if costs0 is None:
+                costs0 = costs
+        root = p.z_star if p.z_star is not None else rec.z_star
+        carry, z_final, _ = _run_recorded(
+            runner, carry, xs, hp_dyn, _record_ref(root, dt), [marks],
+            ph.start, rec, rec.zs is not None,
+        )
+        if ph.end not in record_set:
+            rec.drop_last()
+        state = runner.state_of(carry)
         rdiff_ph = np.diff(
             _cumulative_rounds(spec, hp, np.arange(ph.start, ph.end + 1))
         )
@@ -2323,63 +2088,31 @@ def _solve_phased(
         deg_ph = np.asarray(p.graph.degrees, dtype=np.int64)
         injected_tot += int(rdiff_ph.sum() * deg_ph.sum())
         delivered_tot += int((rdiff_ph * d_in_ph.sum(axis=1)).sum())
-        costs = None
-        marks = sorted(
-            {pt for pt in pts if ph.start < pt <= ph.end} | {ph.end}
-        )
-        prev = ph.start
         for mk in marks:
-            idx_blk = jnp.asarray(
-                indices[prev:mk][:, ph.cols], jnp.int32
-            )
-            if comm == "sharded" and costs is None:
-                if faulty:
-                    costs = runner.collective_costs(
-                        state, idx_blk, lm_ph[prev - ph.start:mk - ph.start],
-                        hp_dyn,
-                    )
-                else:
-                    costs = runner.collective_costs(state, idx_blk, hp_dyn)
-                if costs0 is None:
-                    costs0 = costs
-            if not faulty:
-                state = runner.chunk(state, idx_blk, hp_dyn)
-            elif comm == "sharded":
-                state = runner.chunk(
-                    state, idx_blk,
-                    lm_ph[prev - ph.start:mk - ph.start], hp_dyn,
-                )
-            else:
-                state, bufs = runner.chunk(
-                    state, bufs, idx_blk,
-                    lm_ph[prev - ph.start:mk - ph.start],
-                    sm_ph[prev - ph.start:mk - ph.start], hp_dyn,
-                )
-            prev = mk
             if mk in record_set:
-                z_final = runner.z_read(state, hp_dyn)
-                rec.push(mk, z_final, z_star=p.z_star)
                 snap = cum.copy()
                 snap[ph.row_map] += cum_ph[mk - ph.start - 1]
                 doubles_rows.append(snap)
-                if comm == "sharded":
+                if costs is not None:
                     measured.append(
                         measured_base
                         + (mk - ph.start) * costs["bytes_per_iter"]
                     )
         cum[ph.row_map] += cum_ph[-1]
-        if comm == "sharded":
-            measured_base += (ph.end - ph.start) * costs["bytes_per_iter"]
+        if costs is not None:
+            measured_base += seg * costs["bytes_per_iter"]
         n_prev = n_ph
     wall = time.perf_counter() - t0
     iters, dist2, cons, zs = rec.arrays()
     doubles = np.stack(doubles_rows)
-    extras: dict = {"schedule": sched_x}
+    extras: dict = {"schedule": sched_x, "host_reads": len(phases)}
     if total_rows != base.graph.n or any(
         ph.entry in ("kill", "join") for ph in phases
     ):
         extras["churn_rows"] = total_rows
-    if want_fault:
+    if plan is not None and (
+        plan.link is not None or plan.straggler is not None
+    ):
         extras["faults"] = {
             "injected_messages": injected_tot,
             "delivered_messages": delivered_tot,
@@ -2390,7 +2123,7 @@ def _solve_phased(
         }
     if comm == "sharded":
         extras["collectives"] = costs0
-        extras["mesh_devices"] = mesh_devices
+        extras["mesh_devices"] = base.graph.n  # one device a node
     return SolveResult(
         method=method,
         comm=comm,
@@ -2710,9 +2443,8 @@ def solve_many(
 
     t0 = time.perf_counter()
     base_hp = dict(spec.defaults, **common_hp)
-    runner = _get_dense_runner(spec, problem, base_hp)
+    runner = _get_runner(spec, problem, base_hp)
     dyn_names = tuple(_dynamic_hp(spec, problem, base_hp))
-    chunk_b, z_read_b, metrics_b = _get_batched_fns(runner, dyn_names)
 
     # hp arrays in the DATA dtype so batched arithmetic promotes exactly
     # like the sequential path's weak-typed python-float scalars
@@ -2731,22 +2463,16 @@ def solve_many(
     state = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (n_runs,) + x.shape), state0
     )
-    idx_j = jnp.asarray(idx_b[:, :steps], jnp.int32)
     pts = _record_points(steps, record_every)
     rec = _Recorder(problem.z_star, keep_snapshots)
-    ref = _record_ref(rec.z_star, dt)
-    prev = 0
-    z_final = None
-    for pt in pts:
-        state = chunk_b(state, idx_j[:, prev:pt], hp_dyn)
-        prev = pt
-        z_final = z_read_b(state, hp_dyn)
-        # reduced on the device as solve() reduces, so grid entries stay
-        # bit-equal to their sequential solves
-        z_final, dist2, cons = jax.device_get(
-            (z_final, *metrics_b(z_final, ref))
-        )
-        rec.extend([pt], [dist2], [cons], [z_final])
+    # the whole sweep is one segment: one vmapped record program, one read;
+    # reduced on the device as solve() reduces, so grid entries stay
+    # bit-equal to their sequential solves
+    state, z_final, host_reads = _run_recorded(
+        _batched(runner, dyn_names), state,
+        np.asarray(idx_b[:, :steps], np.int32).transpose(1, 0, 2), hp_dyn,
+        _record_ref(rec.z_star, dt), [pts], 0, rec, keep_snapshots,
+    )
     wall = time.perf_counter() - t0
 
     iters, dist2, cons, zs = rec.arrays()
@@ -2766,7 +2492,8 @@ def solve_many(
         z=np.asarray(z_final),
         state=state,
         zs=zs,
-        extras={"batched": True, "grid": entries, "seeds": seeds_list},
+        extras={"batched": True, "grid": entries, "seeds": seeds_list,
+                "host_reads": host_reads},
     )
 
 

@@ -162,6 +162,35 @@ def test_dense_stragglers_and_composition():
     assert both < r_l.extras["faults"]["delivered_messages"]
 
 
+@pytest.mark.parametrize("link,straggler",
+                         [(True, False), (False, True), (True, True)])
+def test_fault_mix_runs_at_highest_precision(link, straggler):
+    """Every product of the faulty dense mix runs at full precision, as
+    the plain mix does: the TPU's default would round f32 operands to
+    bf16 (the CPU ignores the setting, so only the program shows it)."""
+    import jax
+    from jax import lax
+
+    from repro.core.comm import FaultyDenseComm
+
+    g = mixing.ring_graph(4)
+    comm = FaultyDenseComm(g, link, straggler)
+    mix = comm.matvec(mixing.laplacian_mixing(g), np.float32)
+
+    def run(x, mask, deliv, buf):
+        comm.begin_step(mask, deliv, (buf,))
+        out = mix(x)
+        comm.end_step()
+        return out
+
+    x = np.ones((4, 3), np.float32)
+    jaxpr = jax.make_jaxpr(run)(x, np.ones((4, 4), bool), np.ones(4, bool), x)
+    dots = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert dots
+    for e in dots:
+        assert e.params["precision"] == (lax.Precision.HIGHEST,) * 2
+
+
 def test_staleness_bound_is_enforced():
     """Even at p=0.95 no node goes more than max_staleness iterations
     without a delivery (the forced catch-up), and the first iteration

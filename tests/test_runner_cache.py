@@ -107,6 +107,34 @@ def test_static_hp_change_recompiles_but_value_sweep_does_not():
 # ---------------------------------------------------------------------------
 
 
+def test_fault_plans_share_runners_by_fault_structure():
+    """A p=0 plan runs the plan-free runner; a p>0 plan builds one fault
+    runner, and a second p>0 plan of another rate and seed reuses it with
+    no new trace (the masks are scan inputs, not cache-key material)."""
+    from repro.core.solvers import FaultPlan, LinkFault
+
+    problem = _problem()
+    kw = dict(steps=STEPS, record_every=REC, alpha=0.3)
+
+    def plan(p, seed):
+        return {"fault_plan": FaultPlan(link=LinkFault(p=p, seed=seed))}
+
+    solve(problem, "dsba", **kw)
+    s0 = runner_cache_stats()["dense"]
+    solve(problem, "dsba", comm_options=plan(0.0, 1), **kw)
+    s1 = runner_cache_stats()["dense"]
+    assert s1["hits"] == s0["hits"] + 1 and s1["misses"] == s0["misses"]
+    assert s1["traces"] == s0["traces"]
+    faulty = solve(problem, "dsba", comm_options=plan(0.2, 1), **kw)
+    assert faulty.extras["faults"]["drop_rate"] > 0
+    s2 = runner_cache_stats()["dense"]
+    assert s2["misses"] == s1["misses"] + 1 and s2["size"] == 2
+    solve(problem, "dsba", comm_options=plan(0.4, 9), **kw)
+    s3 = runner_cache_stats()["dense"]
+    assert s3["hits"] == s2["hits"] + 1 and s3["misses"] == s2["misses"]
+    assert s3["traces"] == s2["traces"], "a new rate must not recompile"
+
+
 def test_distinct_problems_do_not_collide():
     problems = [
         _problem(),                      # base

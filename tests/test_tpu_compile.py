@@ -303,7 +303,7 @@ def _ring4(topo):
         mixing.ring_graph(n),
     )
     return _placed_dsba(
-        lambda spec, pr, hp: S._get_sharded_runner(spec, pr, hp, mesh),
+        lambda spec, pr, hp: S._get_runner(spec, pr, hp, mesh=mesh),
         problem, lambda pspec: NamedSharding(mesh, pspec),
     )
 
@@ -361,7 +361,7 @@ def test_dense_record_program_on_one_chip(one_chip):
         mixing.erdos_renyi_graph(n, 0.4, seed=0), lam=1e-4,
     )
     runner, state, hp_dyn, placed = _placed_dsba(
-        S._get_dense_runner, problem, lambda pspec: one_chip
+        S._get_runner, problem, lambda pspec: one_chip
     )
     idx = placed((rec, every, n), jnp.int32, P())
     ref = (placed((d,), jnp.float32, P()), placed((d,), jnp.float32, P()))
